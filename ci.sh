@@ -155,13 +155,6 @@
 #                      crash case (SIGKILL between ACK and materialization
 #                      with GC running -> replay exactly once, duplicate
 #                      re-uploads absorbed, decoy proves GC live).
-#   ./ci.sh benchdiff  bench-trajectory regression gate (ISSUE 12): runs
-#                      tools/bench_compare.py over the checked-in
-#                      BENCH_r*.json rows (newest run vs best prior per
-#                      config, 10% band; structured skips and environmental
-#                      failures are NEUTRAL — the r05 mode) and then proves
-#                      the gate BITES by synthesizing a -20% fixture row
-#                      that must fail.
 #   ./ci.sh fleet      fleet control plane gate (ISSUE 16): rendezvous
 #                      routing units, fleet_members row plumbing,
 #                      ownership-filtered acquisition, migration behind the
@@ -366,46 +359,6 @@ case "$tier" in
     RUN_SLOW=1 exec python -m pytest tests/test_crash_chaos.py -q \
       -k journaled_ingest
     ;;
-  benchdiff)
-    # Bench-trajectory regression gate (ISSUE 12).  Two halves: (1) the
-    # checked-in trajectory must pass (neutral rows — structured skips,
-    # environmental failures — never fail it); (2) the gate must actually
-    # bite: a synthetic newest row 20% below the best prior datapoint for
-    # histogram1024 must exit non-zero, or the gate is decorative.
-    python tools/bench_compare.py --dir .
-    tmpdir="$(mktemp -d /tmp/janus-benchdiff.XXXXXX)"
-    trap 'rm -rf "$tmpdir"' EXIT
-    cp BENCH_r*.json "$tmpdir"/
-    python - "$tmpdir" <<'EOF'
-import json, glob, os, sys
-from tools.bench_compare import load_runs, row_value
-d = sys.argv[1]
-runs = load_runs(sorted(glob.glob(os.path.join(d, "BENCH_r*.json"))))
-best = None
-for run in runs:
-    for key, row in (run["rows"] or {}).items():
-        vu = row_value(row)
-        if vu and key == "histogram1024":
-            best = max(best or 0.0, vu[0])
-assert best, "no histogram1024 datapoint to regress against"
-n = runs[-1]["n"] + 1
-synthetic = {"n": n, "cmd": "synthetic-regression-fixture", "rc": 0, "tail": "",
-             "parsed": {"metric": "prepare_throughput_histogram1024",
-                        "value": round(best * 0.8, 1), "unit": "reports/s",
-                        "configs": {"histogram1024": {
-                            "config": "synthetic -20%", "unit": "reports/s",
-                            "value": round(best * 0.8, 1)}}}}
-with open(os.path.join(d, "BENCH_r%02d.json" % n), "w") as f:
-    json.dump(synthetic, f)
-print("synthesized r%02d at 0.8x best prior (%s reports/s)" % (n, best))
-EOF
-    if python tools/bench_compare.py --dir "$tmpdir"; then
-      echo "benchdiff: synthetic -20% fixture was NOT caught" >&2
-      exit 1
-    fi
-    echo "benchdiff: trajectory gate passes and bites"
-    exit 0
-    ;;
   fleet)
     # Fleet control plane gate (ISSUE 16).  RUN_SLOW pulls in the
     # binary-level SIGKILL-migration acceptance case (~3 min: two driver
@@ -423,7 +376,7 @@ print("entry() compile ok")
 EOF
     ;;
   *)
-    echo "usage: ./ci.sh [fast|heavy|slow|all|tier1|mxu|mesh|poplar|chaos|chaos crash|chaos partition|chaos brownout|chaos poison|canary|coldstart|fpvec|obs|load|load fast|ingest|benchdiff|fleet|postgres|dryrun]" >&2
+    echo "usage: ./ci.sh [fast|heavy|slow|all|tier1|mxu|mesh|poplar|chaos|chaos crash|chaos partition|chaos brownout|chaos poison|canary|coldstart|fpvec|obs|load|load fast|ingest|fleet|postgres|dryrun]" >&2
     exit 2
     ;;
 esac

@@ -1,4 +1,4 @@
-"""Aggregator error taxonomy → DAP problem documents.
+"""Aggregator error classes → DAP problem documents.
 
 The analog of the reference's error enum + report rejection reasons
 (reference: aggregator/src/aggregator/error.rs:220, problem_details.rs).

@@ -212,15 +212,14 @@ class CommonConfig:
     #: collection_e2e, first_flush (or any raw janus_* histogram name via
     #: ``signal:``).  Empty = no SLO evaluation.
     slos: dict = field(default_factory=dict)
-    #: Fleet-wide persistent XLA compile cache ROOT (utils/jax_setup.py):
-    #: when set, every binary points jax's compilation cache at
-    #: ``<dir>/<config-digest>`` at startup, so a restarted replica (crash
-    #: recovery, rollout) replays its executables instead of re-paying
-    #: 37-286 s of compile per VDAF shape.  The digest subdirectory keys
-    #: on (JAX_PLATFORMS, XLA_FLAGS, host CPU fingerprint) — a shared
-    #: volume is safe across heterogeneous hosts — and the no-cache-on-CPU
-    #: guard still applies (XLA:CPU AOT loads are poisoned; see
-    #: enable_compile_cache).  Empty = no persistent cache.
+    #: Fleet-wide persistent XLA compile cache directory
+    #: (utils/jax_setup.py): every binary points jax's compilation cache
+    #: at it at startup, so a restarted replica (crash recovery, rollout)
+    #: replays its executables instead of re-paying minutes of compile
+    #: per VDAF shape.  Used as given.  ``JAX_COMPILATION_CACHE_DIR`` in
+    #: the environment wins over this; empty = ``<repo>/.jax_cache``.
+    #: No cache when the elected backend is the CPU (XLA:CPU AOT loads
+    #: are poisoned; see enable_compile_cache).
     compile_cache_dir: str = ""
     #: Fleet control plane (core/fleet.py): replica membership +
     #: rendezvous task routing for the job drivers; fully off by default.
@@ -296,7 +295,11 @@ class DeviceExecutorConfig:
     #: per-submission deadline; queued past it -> retryable rejection
     #: (<= 0 disables deadline rejection)
     submit_timeout_s: float = 30.0
-    #: mega-batch size to precompile per backend at startup (0 = off)
+    #: mega-batch size to precompile per backend at startup (0 = off);
+    #: flushes of a warm shape up to this many rows pad up to it and run
+    #: on the precompiled executable instead of compiling a smaller one.
+    #: Set it on a TPU host (README "Running on a TPU host"): a cold
+    #: prepare shape compiles for minutes there.
     warmup_rows: int = 0
     #: run warmup compiles on a background thread (default): backend
     #: resolution and binary startup never block behind XLA, and submits

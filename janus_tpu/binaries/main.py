@@ -41,7 +41,9 @@ from .config import (
 logger = logging.getLogger("janus_tpu.binaries")
 
 
-def _bootstrap(config_common):
+def _bootstrap(config_common, device: bool = False):
+    """``device``: this binary prepares on the JAX device (its
+    ``vdaf_backend`` is not the oracle)."""
     from ..core.trace import (
         TraceConfiguration,
         configure_chrome_trace,
@@ -136,24 +138,23 @@ def _bootstrap(config_common):
     if getattr(config_common, "profiler_port", 0):
         if start_profiler_server(config_common.profiler_port):
             logger.info("jax profiler server on :%d", config_common.profiler_port)
-    if getattr(config_common, "compile_cache_dir", ""):
-        # Fleet-wide persistent compile cache (ISSUE 8): a restarted
-        # replica replays its XLA executables from the shared cache root
-        # instead of re-paying every shape's compile.  enable_compile_cache
-        # keeps the config/host-fingerprint scoping and the
-        # no-cache-on-CPU guard (poisoned AOT loads) even for an explicit
-        # root, so this is safe to set unconditionally in fleet config.
-        from ..utils.jax_setup import enable_compile_cache, resolve_cache_dir
+    if device:
+        # Persistent compile cache (ISSUE 8): a restarted replica replays
+        # its XLA executables instead of re-paying every shape's compile.
+        # Only the binaries that launch on the device turn it on — asking
+        # which backend was elected initializes it, and on a one-chip host
+        # the chip belongs to ONE process (README "Running on a TPU host").
+        # JAX_COMPILATION_CACHE_DIR places the cache from outside and wins
+        # over common.compile_cache_dir (utils/jax_setup.py).
+        from ..utils.jax_setup import enable_compile_cache
 
-        if enable_compile_cache(config_common.compile_cache_dir):
-            logger.info(
-                "persistent compile cache -> %s",
-                resolve_cache_dir(config_common.compile_cache_dir),
-            )
+        cache_dir = enable_compile_cache(config_common.compile_cache_dir or None)
+        if cache_dir:
+            logger.info("persistent compile cache -> %s", cache_dir)
         else:
             logger.info(
-                "persistent compile cache disabled on this platform "
-                "(CPU AOT loads are poisoned; cold compiles are cheaper)"
+                "persistent compile cache off: the elected JAX backend is "
+                "the CPU (its AOT loads are poisoned; cold compiles are cheaper)"
             )
     clock = RealClock()
     if fault_cfg is not None and fault_cfg.enabled:
@@ -395,7 +396,7 @@ def run_aggregator(config_path: Optional[str]) -> None:
     """DAP HTTP server + optional GC loop
     (reference: binaries/aggregator.rs:31-150)."""
     cfg = load_config(AggregatorConfig, config_path)
-    clock, datastore = _bootstrap(cfg.common)
+    clock, datastore = _bootstrap(cfg.common, device=cfg.vdaf_backend != "oracle")
 
     from aiohttp import web
 
@@ -692,7 +693,9 @@ def _run_job_driver_binary(config_path: Optional[str], kind: str) -> None:
     """Shared wiring for the two lease-driven drivers
     (reference: binaries/aggregation_job_driver.rs:12-66)."""
     cfg = load_config(JobDriverBinaryConfig, config_path)
-    clock, datastore = _bootstrap(cfg.common)
+    clock, datastore = _bootstrap(
+        cfg.common, device=kind == "aggregation" and cfg.vdaf_backend != "oracle"
+    )
 
     # Peer-health gating thresholds are applied ONCE here (the tracker
     # is process-wide; driver constructors deliberately don't touch it).

@@ -10,7 +10,7 @@ canary tasks (one per VDAF family) against the real upload → aggregate
 against the exact expected sum.  A replica can hold leases, heartbeat,
 and serve 200s while producing garbage; only a known answer catches it.
 
-Outcome taxonomy (the ``janus_canary_verdict_total{task,outcome}``
+Outcome classes (the ``janus_canary_verdict_total{task,outcome}``
 counter):
 
     ok       upload + collection succeeded AND the aggregate matched
@@ -613,7 +613,7 @@ class CanaryPlane:
         )
 
     def _classify_503(self, task: _CanaryTask, body: str) -> ProbeResult:
-        """503 taxonomy: an intentional shed (admission control, brownout
+        """503 classes: an intentional shed (admission control, brownout
         suppression) means STAND DOWN — the fleet is refusing work on
         purpose and canary pressure would make it worse.  But the
         datastore-unavailable 503 (tx retries exhausted behind the

@@ -21,15 +21,19 @@ BATCH of concurrent uploads' opens as:
 Suites the wide kernel does not cover (AES-256-GCM, ChaCha20-Poly1305)
 open per-report through core/hpke.py inside the same batch call, so the
 caller's contract is uniform.  Robustness contract: a malformed
-ciphertext rejects ONLY its own report (per-item error slots), and any
-batch-LEVEL failure falls back to per-report inline opens — the batched
-path can never reject a report the inline path would accept.
+ciphertext rejects ONLY its own report (per-item error slots).  Which of
+the two AES-128-GCM paths serves a process is decided ONCE
+(``vector_pass_preferred``) and logged; a failure of the elected path
+RAISES out of ``open_batch`` — the callers' bisection (which isolates
+poison rows, loudly, into the quarantine ledger) is the only net, and
+nothing here quietly drops to another implementation.
 Bit-exactness is anchored by running the vendored RFC 9180 vectors and a
-batched-vs-inline fuzz (tests/test_hpke_batch.py) through this path.
+batched-vs-inline fuzz (tests/test_upload_frontdoor.py) through this path.
 """
 
 from __future__ import annotations
 
+import logging
 import struct
 from typing import List, Optional, Sequence, Tuple
 
@@ -48,6 +52,8 @@ from .hpke import (
 
 __all__ = ["OpenRequest", "open_batch", "aesgcm_open_batch", "vector_pass_preferred"]
 
+logger = logging.getLogger("janus_tpu.hpke_batch")
+
 #: An open request: (recipient keypair, application info, ciphertext, aad).
 OpenRequest = Tuple[HpkeKeypair, HpkeApplicationInfo, object, bytes]
 
@@ -62,15 +68,20 @@ _VECTOR_PREFERRED: Optional[bool] = None
 def vector_pass_preferred() -> bool:
     """Should AES-128-GCM bodies take the wide table-AES kernel?
 
-    The vectorized pass is the right tool exactly where it was built for:
-    hosts whose jax backend is a real accelerator (table gathers on
-    TPU are data-independent wide vector ops), and hosts with NO
-    functional `cryptography` (nothing constant-time exists to prefer).
-    On a plain-CPU host WITH a working `cryptography`, per-report AES-NI
-    is both constant-time and faster than table lookups — the soft
-    kernels must never be a production preference there (the
-    utils/gcm.py invariant).  ``JANUS_TPU_UPLOAD_VECTOR_GCM=1|0``
-    overrides (tests force both paths)."""
+    Only on a host with NO functional `cryptography`, where nothing
+    constant-time exists to prefer.  With a working `cryptography`,
+    per-report AES-NI is constant-time and is the path — on a CPU host
+    and on a TPU host alike.  The kernel was once also elected whenever
+    the JAX backend was an accelerator; that did not survive the chip
+    (PERF.md, PR 21).  On a v5e one open batch of 64 Histogram(1024)
+    leader shares (64 x 2,048 AES blocks) took 51 s on its first call —
+    the u8 S-box gathers compile to tens of MB of code, once per pow2
+    (rows, blocks) shape, against a 2 s shed deadline — and 0.47 s on
+    every later one, holding the chip that prepare needs; `cryptography`
+    opened all 2,304 uploads of the same run in 0.66 s.  The choice is
+    made once per process and logged.
+    ``JANUS_TPU_UPLOAD_VECTOR_GCM=1|0`` overrides (tests force both
+    paths)."""
     global _VECTOR_PREFERRED
     import os
 
@@ -80,16 +91,15 @@ def vector_pass_preferred() -> bool:
     if _VECTOR_PREFERRED is None:
         from ..utils.gcm import HAVE_FUNCTIONAL_CRYPTOGRAPHY
 
-        if not HAVE_FUNCTIONAL_CRYPTOGRAPHY:
-            _VECTOR_PREFERRED = True
-        else:
-            try:
-                import jax
-
-                _VECTOR_PREFERRED = jax.default_backend() != "cpu"
-            except Exception:  # pragma: no cover - jax-less host
-                _VECTOR_PREFERRED = False
+        _VECTOR_PREFERRED = not HAVE_FUNCTIONAL_CRYPTOGRAPHY
+        logger.info(
+            "AES-128-GCM open path: %s",
+            "vectorized table-AES kernel (no functional `cryptography`)"
+            if _VECTOR_PREFERRED
+            else "per-report AES-GCM (`cryptography`)",
+        )
     return _VECTOR_PREFERRED
+
 
 _R_HI = np.uint64(0xE100000000000000)  # GCM reduction poly, high u64
 
@@ -162,22 +172,6 @@ def _ghash_batch(h_blocks: np.ndarray, datas: Sequence[bytes]) -> np.ndarray:
 # -- vectorized AES-128-GCM open ---------------------------------------------
 
 
-def _encrypt_blocks_multikey(round_keys: np.ndarray, blocks: np.ndarray) -> np.ndarray:
-    """(B, K, 16) AES blocks under per-report (B, 11, 16) round keys: the
-    jitted multikey kernel (pow2-padded) when the jax stack is up, a
-    per-report numpy soft-AES loop otherwise."""
-    try:
-        from ..ops.aes_jax import encrypt_blocks_multikey_padded
-
-        return np.asarray(encrypt_blocks_multikey_padded(round_keys, blocks))
-    except Exception:  # pragma: no cover - jax-less host
-        from ..utils.softaes import encrypt_blocks
-
-        return np.stack(
-            [encrypt_blocks(rk, blk) for rk, blk in zip(round_keys, blocks)]
-        )
-
-
 def aesgcm_open_batch(
     keys: Sequence[bytes],
     nonces: Sequence[bytes],
@@ -190,6 +184,7 @@ def aesgcm_open_batch(
     (tag mismatch / truncated input) — per-report isolation is the
     contract.  All nonces must be 12 bytes (the only length RFC 9180
     produces)."""
+    from ..ops.aes_jax import encrypt_blocks_multikey_padded
     from ..utils.softaes import _expand_key
 
     b = len(keys)
@@ -213,7 +208,7 @@ def aesgcm_open_batch(
         for c in range(nblocks[i]):
             ctr = nonces[i] + struct.pack(">I", 2 + c)
             blocks[i, 2 + c] = np.frombuffer(ctr, dtype=np.uint8)
-    out = _encrypt_blocks_multikey(round_keys, blocks)
+    out = np.asarray(encrypt_blocks_multikey_padded(round_keys, blocks))
     h = np.ascontiguousarray(out[:, 0])  # E(K, 0): the GHASH key
     tag_mask = out[:, 1]  # E(K, J0)
     ghash_in = [
@@ -261,9 +256,10 @@ def open_batch(requests: Sequence[OpenRequest]) -> List[object]:
     (never raised: a malformed row must reject only itself).
 
     Per-report KEM decap + key schedule run here (the caller is expected
-    to be on a worker thread); all AES-128-GCM bodies then open as ONE
-    vectorized pass, other suites per-report.  Any batch-level error in
-    the vectorized pass falls back to per-report inline opens."""
+    to be on a worker thread); all AES-128-GCM bodies then open on the
+    elected path (``vector_pass_preferred``) — ONE vectorized pass, or
+    per-report AEAD with the already-derived keys — other suites
+    per-report.  A failure of the vectorized pass itself raises."""
     results: List[object] = [None] * len(requests)
     gcm_idx: List[int] = []
     gcm_keys: List[bytes] = []
@@ -317,16 +313,9 @@ def open_batch(requests: Sequence[OpenRequest]) -> List[object]:
                 except Exception as e:
                     results[i] = HpkeError(f"HPKE open failed: {type(e).__name__}")
         else:
-            try:
-                opened = aesgcm_open_batch(gcm_keys, gcm_nonces, gcm_cts, gcm_aads)
-                for i, pt in zip(gcm_idx, opened):
-                    results[i] = (
-                        pt if pt is not None else HpkeError("HPKE open failed: InvalidTag")
-                    )
-            except Exception:
-                # batch-LEVEL failure (kernel import, shape bug): per-report
-                # fallback so one pass's trouble can never reject the batch
-                for i in gcm_idx:
-                    keypair, info, ciphertext, aad = requests[i]
-                    results[i] = _open_one(keypair, info, ciphertext, aad)
+            opened = aesgcm_open_batch(gcm_keys, gcm_nonces, gcm_cts, gcm_aads)
+            for i, pt in zip(gcm_idx, opened):
+                results[i] = (
+                    pt if pt is not None else HpkeError("HPKE open failed: InvalidTag")
+                )
     return results

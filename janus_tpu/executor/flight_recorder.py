@@ -78,6 +78,7 @@ class FlightRecorder:
         breaker_state: Optional[str],
         fault: bool,
         error: Optional[str] = None,
+        layout: Optional[str] = None,
     ) -> Optional[dict]:
         """Append one flush record; returns the record.  Runs the
         slow-flush detector against the bucket's rolling p95 BEFORE this
@@ -102,6 +103,10 @@ class FlightRecorder:
             }
             if error:
                 rec["error"] = str(error)[:200]
+            if layout:
+                # which device layout the launch ran in (planar |
+                # row-major | canonical-row-major), as the backend chose it
+                rec["layout"] = layout
             self._ring.append(rec)
             self.recorded_total += 1
             window = self._launch_window.get(bucket)

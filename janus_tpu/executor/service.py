@@ -112,7 +112,9 @@ class ExecutorConfig:
     #: default per-submission deadline (queued past it -> rejected);
     #: <= 0 disables deadline rejection
     submit_timeout_s: float = 30.0
-    #: pow2 mega-batch size warmup compiles per (backend, agg_id); 0 = off
+    #: pow2 mega-batch size warmup compiles per (backend, agg_id); 0 = off.
+    #: Once a shape is warm, every flush of up to this many rows pads up
+    #: to it and runs on the warmed executable (see _warm_pad).
     warmup_rows: int = 0
     #: run warmup compiles on a dedicated background thread (default) so
     #: backend_for — and therefore the submit path and binary startup —
@@ -611,6 +613,20 @@ class DeviceExecutor:
                 GLOBAL_METRICS.executor_compile_seconds.labels(shape=label).observe(dt)
         return outcome == "ok"
 
+    def _warm_pad(self, shape_key: tuple) -> Optional[int]:
+        """Rows a prep_init flush of a WARM shape pads up to: the warmed
+        mega-batch size, so every flush that fits runs on the executable
+        warmup already compiled.  A smaller pow2 pad would be a new shape,
+        and on the chip a new prepare shape is a compile of minutes on
+        the launch thread — longer than the submit deadline and the peer's
+        HTTP timeout, so everything queued behind it is rejected.  Flushes
+        larger than the warmed size (and shapes never warmed) keep the
+        plain pow2 pad."""
+        st = self._warmup_state.get(shape_key)
+        if self.config.warmup_rows and st is not None and st["state"] == "warm":
+            return self.config.warmup_rows
+        return None
+
     def warming(self, shape_key: tuple) -> bool:
         """True while the shape's warmup compile is still in flight —
         producers route its submissions to the CPU oracle meanwhile (the
@@ -1073,6 +1089,7 @@ class DeviceExecutor:
             model.observe_queue_delay(s.task, delay)
         stage_s = 0.0
         padded_rows = 0
+        layout = None
         t_launch = t_dispatch
         #: set the moment the launch is known-good (record_success):
         #: an exception AFTER it (resolve bookkeeping, ref release) must
@@ -1108,10 +1125,11 @@ class DeviceExecutor:
                     ):
                         retain = self.accumulator
                     t_stage = time.monotonic()
+                    warm_pad = self._warm_pad(bucket.key[0])
                     staged = await loop.run_in_executor(
                         stage_pool,
                         lambda: bucket.backend.stage_prep_init_multi(
-                            bucket.agg_id, requests
+                            bucket.agg_id, requests, pad_to=warm_pad
                         ),
                     )
                     t_launch = time.monotonic()
@@ -1122,6 +1140,10 @@ class DeviceExecutor:
                     pad_to = getattr(staged, "pad_to", None)
                     if pad_to is not None:
                         padded_rows = max(0, pad_to - rows)
+                        if hasattr(bucket.backend, "launch_layout"):
+                            layout = bucket.backend.launch_layout(
+                                bucket.agg_id, pad_to
+                            )
 
                     def launch():
                         # Deadline re-check AFTER the launch-queue wait —
@@ -1297,6 +1319,7 @@ class DeviceExecutor:
                 outcome="ok",
                 breaker_state=self._breaker_state_name(bucket),
                 fault=False,
+                layout=layout,
             )
         except Exception as e:  # surface the launch failure to every job
             done = time.monotonic()
@@ -1356,6 +1379,7 @@ class DeviceExecutor:
                     breaker_state=self._breaker_state_name(bucket),
                     fault=isinstance(e, faults.FaultInjectedError),
                     error=e,
+                    layout=layout,
                 )
                 self._record_flush_failure(bucket, e)
             else:

@@ -2,9 +2,11 @@
 
 Builds and loads ``native/turboshake.cpp`` — the C++ TurboSHAKE128 sponge
 and VDAF XOF field expansion the CPU oracle uses for its hot loops.  The
-build is one ``g++ -O3 -shared`` invocation, cached next to the source; if
+build is one ``g++ -O3 -shared`` invocation, cached next to the source
+(the ``.so`` is git-ignored: a fresh checkout builds it on first use); if
 the toolchain or the build is unavailable, callers fall back to the pure
-Python sponge (bit-exact either way, asserted in tests/test_native.py).
+Python sponge (bit-exact either way, asserted in tests/test_native.py) and
+a warning says so.
 
 Disable explicitly with JANUS_TPU_NATIVE=0.
 """
@@ -40,8 +42,17 @@ def _build() -> bool:
         )
         os.replace(tmp, _LIB)
         return True
-    except Exception as e:
-        logger.debug("native build failed: %s", e)
+    except (OSError, subprocess.SubprocessError) as e:
+        # Loud: without the library every XOF expansion of the oracle and
+        # of clients runs the pure-Python sponge, ~100x slower, and
+        # nothing else would say why.
+        detail = getattr(e, "stderr", b"") or b""
+        logger.warning(
+            "native build of %s failed (%s); the pure-Python sponge serves: %s",
+            _SRC,
+            e,
+            detail.decode(errors="replace")[-2000:],
+        )
         try:
             os.unlink(tmp)
         except OSError:
@@ -64,7 +75,8 @@ def load() -> Optional[ctypes.CDLL]:
             return None
     try:
         lib = ctypes.CDLL(_LIB)
-    except OSError:
+    except OSError as e:
+        logger.warning("native library %s did not load: %s", _LIB, e)
         return None
     lib.ts128_hash.argtypes = [
         ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint8,

@@ -1171,6 +1171,28 @@ class BatchedPrio3:
         jf = self.jf
         return [jnp.broadcast_to(jf.mont_one()[l], (R, 128)) for l in range(jf.n)]
 
+    def _geom_planes(self, first, ratio, count):
+        """first * ratio^i for i < count on limb-list planes: n arrays
+        (R, 128) Montgomery -> n arrays (R, count, 128).  Planar twin of
+        JField.geom_mont: a sequential chain run as a scan, so the graph
+        holds one multiply however long the chain is."""
+        jf = self.jf
+
+        def step(acc, _):
+            return tuple(jf.mont_mul_limbs(list(acc), ratio)), acc
+
+        _, out = lax.scan(step, tuple(first), None, length=count)
+        return [jnp.moveaxis(o, 0, 1) for o in _scan_fence(out)]
+
+    def _sum_planes(self, x, axis: int = 1):
+        """Modular sum of limb-list planes over one (short) axis."""
+        acc = [lax.index_in_dim(l_, 0, axis, keepdims=False) for l_ in x]
+        for j in range(1, x[0].shape[axis]):
+            acc = self.jf.add_limbs(
+                acc, [lax.index_in_dim(l_, j, axis, keepdims=False) for l_ in x]
+            )
+        return acc
+
     def _pow_range_planes(self, x_pl, count):
         """x^1..x^count on limb-list planes via baby-step/giant-step.
 
@@ -1179,18 +1201,13 @@ class BatchedPrio3:
         import math
 
         jf = self.jf
-        n = jf.n
         R = x_pl[0].shape[0]
         bs = max(1, math.isqrt(count))
         gs = -(-count // bs)
-        baby = [x_pl]
-        for _ in range(bs - 1):
-            baby.append(jf.mont_mul_limbs(baby[-1], x_pl))
-        giant = [self._ones_planes(R)]
-        for _ in range(gs - 1):
-            giant.append(jf.mont_mul_limbs(giant[-1], baby[-1]))
-        baby_t = [jnp.stack([b[l] for b in baby], axis=1) for l in range(n)]
-        giant_t = [jnp.stack([g[l] for g in giant], axis=1) for l in range(n)]
+        baby_t = self._geom_planes(x_pl, x_pl, bs)  # x^(i+1)
+        giant_t = self._geom_planes(
+            self._ones_planes(R), [b[:, -1] for b in baby_t], gs
+        )  # x^(bs*g)
         outer = jf.mont_mul_limbs(
             [g[:, :, None, :] for g in giant_t], [b[:, None, :, :] for b in baby_t]
         )
@@ -1209,41 +1226,34 @@ class BatchedPrio3:
         bs = max(1, math.isqrt(glen))
         gs = -(-glen // bs)
         one = self._ones_planes(R)
-        baby = [one]  # t^j for j in 0..bs-1
-        for _ in range(bs - 1):
-            baby.append(jf.mont_mul_limbs(baby[-1], t_pl))
-        tbs = jf.mont_mul_limbs(baby[-1], t_pl)  # t^bs
-        giant = [one]
-        for _ in range(gs - 1):
-            giant.append(jf.mont_mul_limbs(giant[-1], tbs))
-        gpt = None
-        for g in range(gs):
-            inner = None
-            for j in range(bs):
-                idx = g * bs + j
-                if idx >= glen:
-                    break
-                term = jf.mont_mul_limbs([x[:, idx] for x in gp], baby[j])
-                inner = term if inner is None else jf.add_limbs(inner, term)
-            outer = jf.mont_mul_limbs(inner, giant[g])
-            gpt = outer if gpt is None else jf.add_limbs(gpt, outer)
-        return gpt
+        baby_t = self._geom_planes(one, t_pl, bs)  # t^j, j < bs
+        tbs = jf.mont_mul_limbs([b[:, -1] for b in baby_t], t_pl)  # t^bs
+        giant_t = self._geom_planes(one, tbs, gs)  # t^(bs*g)
+        cg = [
+            jnp.pad(c, ((0, 0), (0, gs * bs - glen), (0, 0))).reshape(R, gs, bs, 128)
+            for c in gp
+        ]
+        # c_j * t^(j % bs), summed over the baby axis, then * t^(bs*g)
+        terms = jf.mont_mul_limbs(cg, [b[:, None] for b in baby_t])
+        inner = self._sum_planes(terms, axis=2)  # (R, gs, 128)
+        return self._sum_planes(jf.mont_mul_limbs(inner, giant_t))
 
     def _lagrange_planes(self, t_pl):
         """Planar twin of _lagrange_coeffs.
 
         t_pl: limb list of (R, 128) Montgomery -> (lag_pl (R, n, K, 128)
         Montgomery, t_ok (R, 128) bool).  Same inversion-free barycentric
-        construction (z/(t - w^k) = prod_{j != k} (t - w^j)); prefix/suffix
-        chains are lane-wide multiplies instead of T(1,128) row passes.
-        Byte parity follows from exact Montgomery identities.
+        construction (z/(t - w^k) = prod_{j != k} (t - w^j)); the exclusive
+        prefix and suffix products are two scans of lane-wide multiplies
+        instead of T(1,128) row passes.  Byte parity follows from exact
+        Montgomery identities.
         """
         jf, circ = self.jf, self.circ
         n = jf.n
         R = t_pl[0].shape[0]
         P = circ.P
         K = circ.calls + 1
-        one = [jnp.broadcast_to(jf.mont_one()[l], (R, 128)) for l in range(n)]
+        one = self._ones_planes(R)
 
         tp = t_pl
         for _ in range(self._log2_P):
@@ -1255,33 +1265,24 @@ class BatchedPrio3:
         t_ok = nz != 0
 
         roots = self.roots_all_m  # (P, n) Montgomery
-        denom = [
-            jf.sub_limbs(
-                t_pl,
-                [jnp.broadcast_to(roots[k, l], (R, 128)) for l in range(n)],
-            )
-            for k in range(P)
-        ]
-        prefix = [one]
-        for k in range(1, P):
-            prefix.append(jf.mont_mul_limbs(prefix[-1], denom[k - 1]))
-        suffix = [one] * P
-        for k in range(P - 2, -1, -1):
-            suffix[k] = jf.mont_mul_limbs(suffix[k + 1], denom[k + 1])
+        denom = jf.sub_limbs(
+            [jnp.broadcast_to(t[None], (P, R, 128)) for t in t_pl],
+            [jnp.broadcast_to(roots[:, l][:, None, None], (P, R, 128)) for l in range(n)],
+        )  # t - w^k, stacked on the leading axis
+
+        def step(acc, d):
+            return tuple(jf.mont_mul_limbs(list(acc), list(d))), acc
+
+        _, prefix = lax.scan(step, tuple(one), tuple(denom))
+        _, suffix = lax.scan(step, tuple(one), tuple(denom), reverse=True)
+        prefix, suffix = _scan_fence((prefix, suffix))
+        others = jf.mont_mul_limbs([x[:K] for x in prefix], [x[:K] for x in suffix])
         bary = self.bary_c_m  # (K, n) Montgomery
-        lag_cols = []
-        for k in range(K):
-            others = jf.mont_mul_limbs(prefix[k], suffix[k])
-            lag_cols.append(
-                jf.mont_mul_limbs(
-                    others,
-                    [jnp.broadcast_to(bary[k, l], (R, 128)) for l in range(n)],
-                )
-            )
-        lag_pl = jnp.stack(
-            [jnp.stack([col[l] for col in lag_cols], axis=1) for l in range(n)],
-            axis=1,
-        )  # (R, n, K, 128)
+        lag = jf.mont_mul_limbs(
+            others,
+            [jnp.broadcast_to(bary[:, l][:, None, None], (K, R, 128)) for l in range(n)],
+        )
+        lag_pl = jnp.stack(lag, axis=0).transpose(2, 0, 1, 3)  # (R, n, K, 128)
         return lag_pl, t_ok
 
     def _alpha_mat_m(self, gi: int = 0):
@@ -1310,41 +1311,23 @@ class BatchedPrio3:
             self._alpha_mat_cache[gi] = mat
         return mat
 
-    def _gadget_planes(self, gp_pl, t_pl):
-        """Planar gadget-polynomial evaluations.
+    def _gadget_outputs_planes(self, gp):
+        """gk[k] = gpoly(alpha^k), k = 1..calls, on limb-list planes.
 
-        gp_pl (R, n, glen, 128) canonical coefficient planes, t_pl limb list
-        of (R, 128) Montgomery -> (gk planes (R, n, calls, 128) canonical,
-        gpoly(t) limb list of (R, 128) canonical).  gk[k] = gpoly(alpha^k)
-        as the DIRECT sum over coefficients times constant w^{kj} powers —
-        the same residue the row path's Horner chain produces, and canonical
-        limbs are unique, so byte parity holds while the glen-step serial
-        chain over T(1,128) row tensors disappears.
+        gp: n arrays (R, glen, 128) canonical coefficients -> n arrays
+        (R, calls, 128) canonical.  The DIRECT sum over coefficients times
+        constant w^{kj} powers — the same residue the row path's Horner
+        chain produces, and canonical limbs are unique, so byte parity
+        holds while the glen-step serial chain over T(1,128) row tensors
+        disappears.  One wide multiply serves every call.
         """
-        import math
-
-        jf, circ = self.jf, self.circ
-        n = jf.n
-        R = gp_pl.shape[0]
-        glen = gp_pl.shape[2]
-        gp = [gp_pl[:, l] for l in range(n)]  # (R, glen, 128)
+        jf = self.jf
         amat = self._alpha_mat_m()  # (calls, glen, n)
-        gk_cols = []
-        for k in range(circ.calls):
-            c = [
-                jnp.broadcast_to(amat[k, :, l][None, :, None], (R, glen, 128))
-                for l in range(n)
-            ]
-            terms = jf.mont_mul_limbs(gp, c)
-            acc = [t[:, 0] for t in terms]
-            for j in range(1, glen):
-                acc = jf.add_limbs(acc, [t[:, j] for t in terms])
-            gk_cols.append(acc)
-        gk_pl = jnp.stack(
-            [jnp.stack([col[l] for col in gk_cols], axis=1) for l in range(n)],
-            axis=1,
-        )  # (R, n, calls, 128)
-        return gk_pl, self._gpoly_at_planes(gp, t_pl)
+        terms = jf.mont_mul_limbs(
+            [c[:, None] for c in gp],
+            [jnp.asarray(amat[:, :, l])[None, :, :, None] for l in range(jf.n)],
+        )  # (R, calls, glen, 128)
+        return self._sum_planes(terms, axis=2)
 
     def _histogram_coeff_planes(self, jr_m, lag_pl, cp):
         """Planar twin of _DHistogram.planar_coeffs.
@@ -1365,8 +1348,6 @@ class BatchedPrio3:
         columns are zero, so those wire outputs are garbage either way and
         the consumers mask/slice them.
         """
-        import math
-
         jf, circ = self.jf, self.circ
         n = jf.n
         calls = circ.calls
@@ -1376,16 +1357,11 @@ class BatchedPrio3:
         r = [jr_pl[:, l, 0] for l in range(n)]
         rch = self._pow_range_planes(r, cp)
         rc = [l_[:, circ.chunk - 1] for l_ in rch]  # r^chunk
-        r_call = [one]
-        for _ in range(calls - 1):
-            r_call.append(jf.mont_mul_limbs(r_call[-1], rc))
-        r_call_t = [jnp.stack([c[l] for c in r_call], axis=1) for l in range(n)]
+        r_call_t = self._geom_planes(one, rc, calls)  # r^(chunk*k)
         lagk_t = [lag_pl[:, l, 1 : 1 + calls] for l in range(n)]
         kl = jf.mont_mul_limbs(r_call_t, lagk_t)
 
-        lag_sum = [lagk_t[l][:, 0] for l in range(n)]
-        for k in range(1, calls):
-            lag_sum = jf.add_limbs(lag_sum, [lagk_t[l][:, k] for l in range(n)])
+        lag_sum = self._sum_planes(lagk_t)
         c = self.consts["shares_inv_c"]
         c_pl = [jnp.broadcast_to(c[l], (R, 128)) for l in range(n)]
         ccorr = jf.mont_mul_limbs(c_pl, lag_sum)
@@ -1615,8 +1591,13 @@ class BatchedPrio3:
             # Gadget polynomial: planar direct-sum evaluation (no glen-step
             # row-major Horner chain); gk back to rows only for the tiny
             # (B, calls, n) v computation.
-            gk_pl, gpt_limbs = self._gadget_planes(p_lp[:, :, circ.arity :], t_pl)
-            gk = gk_pl.transpose(0, 3, 2, 1).reshape(B, circ.calls, n)
+            gp = [p_lp[:, l, circ.arity :] for l in range(n)]  # (R, glen, 128)
+            gk = (
+                jnp.stack(self._gadget_outputs_planes(gp), axis=1)
+                .transpose(0, 3, 2, 1)
+                .reshape(B, circ.calls, n)
+            )
+            gpt_limbs = self._gpoly_at_planes(gp, t_pl)
             gp_t = (
                 jnp.stack(gpt_limbs, axis=1).transpose(0, 2, 1).reshape(B, n)
             )
@@ -1806,23 +1787,7 @@ class BatchedPrio3:
             evals = jf.ntt_eval_mont_limbs(folded, *self._ntt)
             gk = [e[:, 1 : circ.calls + 1] for e in evals]
         else:
-            amat = self._alpha_mat_m()  # (calls, glen, n)
-            gk_cols = []
-            for k in range(circ.calls):
-                c = [
-                    jnp.broadcast_to(
-                        amat[k, :, l][None, :, None], (R, circ.glen, 128)
-                    )
-                    for l in range(n)
-                ]
-                terms = jf.mont_mul_limbs(gp, c)
-                acc = [t[:, 0] for t in terms]
-                for j in range(1, circ.glen):
-                    acc = jf.add_limbs(acc, [t[:, j] for t in terms])
-                gk_cols.append(acc)
-            gk = [
-                jnp.stack([col[l] for col in gk_cols], axis=1) for l in range(n)
-            ]  # (R, calls, 128)
+            gk = self._gadget_outputs_planes(gp)  # (R, calls, 128)
 
         if isinstance(circ, _DCount):
             # v = gk[0] - m[0]; wires w0 = w1 = sw_i*lag0 + m0*lag1
@@ -1839,17 +1804,12 @@ class BatchedPrio3:
         else:  # _DSum
             # v = sum_k r^(k+1) * gk[k]
             r_pows = self._pow_range_planes(jr_pl, circ.calls)  # (R, calls, 128)
-            vk_terms = jf.mont_mul_limbs(r_pows, gk)
-            v = [t[:, 0] for t in vk_terms]
-            for k in range(1, circ.calls):
-                v = jf.add_limbs(v, [t[:, k] for t in vk_terms])
+            v = self._sum_planes(jf.mont_mul_limbs(r_pows, gk))
             # single wire: sw0*lag0 + sum_k m[k]*lag_{k+1}
-            mk = jf.mont_mul_limbs(m, lagk)
-            s = [t[:, 0] for t in mk]
-            for k in range(1, circ.calls):
-                s = jf.add_limbs(s, [t[:, k] for t in mk])
             se = jf.mont_mul_limbs([x[:, 0] for x in sw], lag0)
-            wires = [jf.add_limbs(se, s)]
+            wires = [
+                jf.add_limbs(se, self._sum_planes(jf.mont_mul_limbs(m, lagk)))
+            ]
 
         gpt = self._gpoly_at_planes(gp, t_pl)
 
@@ -1873,10 +1833,7 @@ class BatchedPrio3:
                     for l in range(n)
                 ],
             )
-            acc = [t[:, 0] for t in terms]
-            for k in range(1, circ.calls):
-                acc = jf.add_limbs(acc, [t[:, k] for t in terms])
-            osh = [a[:, None, :] for a in acc]
+            osh = [a[:, None, :] for a in self._sum_planes(terms)]
         out["out_share"] = jnp.stack(osh, axis=1)  # (R, n, OUT, 128)
         out["ok"] = ok
         return out

@@ -1,93 +1,59 @@
-"""Shared JAX runtime configuration for tests, bench, and driver entries."""
+"""Shared JAX runtime configuration for the binaries, bench, tools and smoke."""
 
 from __future__ import annotations
 
-import hashlib
 import os
+from typing import Optional
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-
-def _host_fingerprint() -> str:
-    """Identify the host microarchitecture for the cache key.
-
-    Persisted executables embed AOT-compiled machine code; an entry built on
-    a host with a different CPU feature set can hang or SIGILL when loaded
-    (observed: a cache populated on an avx512fp16 host made a 12-second
-    Field128 graph hang its *execution* for 9+ minutes on this one).  Keying
-    the cache directory by the CPU flags makes foreign entries invisible
-    instead of trusting XLA's partial feature check.
-    """
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith("flags"):
-                    return hashlib.sha256(line.encode()).hexdigest()[:12]
-    except OSError:
-        pass
-    import platform
-
-    return platform.machine()
+#: Where the persistent compilation cache goes when nobody says otherwise:
+#: a FIXED path inside the checkout.  The path is part of how a deployment
+#: finds its cache again, so it carries no host-, pid- or time-derived
+#: component — a restarted replica, or the next run on a fresh machine
+#: that was handed the same directory, hits what the last one compiled.
+DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 
-def resolve_cache_dir(cache_dir: str = None) -> str:
-    """The configuration-scoped cache path: ``<root>/<config-digest>``.
+def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
+    """Turn on XLA's persistent compilation cache; returns the directory
+    in use, or None when the cache stays off.
 
-    ``cache_dir`` overrides only the ROOT (the fleet-shared location, e.g.
-    a persistent volume every replica mounts) — the per-(JAX_PLATFORMS,
-    XLA_FLAGS, host-fingerprint) subdirectory is kept even then, so a
-    replica restarting on a different host or platform config never loads
-    a foreign executable (see _host_fingerprint)."""
-    config_key = (
-        os.environ.get("JAX_PLATFORMS", "default")
-        + "|"
-        + os.environ.get("XLA_FLAGS", "")
-        + "|"
-        + _host_fingerprint()
-    )
-    sub = hashlib.sha256(config_key.encode()).hexdigest()[:12]
-    return os.path.join(cache_dir or os.path.join(_REPO_ROOT, ".jax_cache"), sub)
+    The limb-arithmetic graphs are large (minutes of compile per VDAF
+    shape); with the cache a re-run of the same (circuit, batch) shape —
+    a restarted replica, the next bench row, the next smoke — loads its
+    executable instead of compiling it.  Every entry point goes through
+    this one function.
 
+    Where the directory comes from, in order:
 
-def enable_compile_cache(cache_dir: str = None) -> bool:
-    """Point XLA's persistent compilation cache at the config-scoped dir.
+    1. ``JAX_COMPILATION_CACHE_DIR`` — JAX reads it itself, and when it is
+       set this function sets NO directory in code: whoever placed the
+       cache from outside (an operator's volume, the machine a run was
+       handed) wins over everything below.
+    2. ``cache_dir`` — the binaries' ``common.compile_cache_dir``.
+    3. :data:`DEFAULT_CACHE_DIR`, ``<repo>/.jax_cache``.
 
-    The limb-arithmetic graphs are large; caching makes every re-run of the
-    same (circuit, batch) shape start in milliseconds instead of minutes —
-    a RESTARTED replica (crash recovery, rollout) recovers warm instead of
-    re-paying every shape's compile.  Wired into every binary's startup
-    behind ``common.compile_cache_dir`` (binaries/main._bootstrap) and
-    into bench.py.  Returns True when the cache was enabled.
-
-    The cache is scoped per (JAX_PLATFORMS, XLA_FLAGS, host fingerprint)
-    configuration: executables AOT-compiled under one configuration (e.g.
-    the real TPU platform, or a different host-feature set) must never be
-    loaded under another — XLA logs machine-feature mismatches and can
-    hang or SIGILL executing them.  XLA-internal AOT kernel caches are
-    disabled for the same reason; only the JAX-level executable cache is
-    persisted.
+    No cache on XLA:CPU: it persists executables as AOT objects whose
+    recorded target machine includes compile-time pseudo-features
+    (+prefer-no-scatter, +prefer-no-gather) that never appear in the
+    loader's host-feature probe, so every cross-process load fails the
+    feature check and falls into a pathological slow path (observed: a
+    68 s cold-compile test became a 26+ minute hang).  Cold compiles are
+    cheaper than poisoned loads.  The guard asks the backend that was
+    actually elected, so calling this initializes JAX's backends —
+    ``jax.distributed.initialize`` must already have run where it is used.
     """
     import jax
 
-    platforms = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS", "")
-    if platforms.split(",")[0] == "cpu" or platforms == "":
-        # XLA:CPU persists executables as AOT objects whose recorded target
-        # machine includes compile-time pseudo-features (+prefer-no-scatter,
-        # +prefer-no-gather) that never appear in the loader's host-feature
-        # probe.  Every cross-process load then fails the feature check
-        # (cpu_aot_loader: "Machine type used for XLA:CPU compilation
-        # doesn't match...") and falls into a pathological slow path —
-        # observed turning a 68 s cold-compile test into a 26+ minute hang.
-        # Cold compiles are cheaper than poisoned loads: no persistent
-        # cache on CPU.  This guard applies even to an explicitly
-        # configured cache_dir.
-        return False
-
-    jax.config.update("jax_compilation_cache_dir", resolve_cache_dir(cache_dir))
+    if jax.default_backend() == "cpu":
+        return None
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", cache_dir or DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-    except AttributeError:
-        pass
-    return True
+    # Persist only JAX's executable cache: XLA's own per-kernel AOT caches
+    # embed host machine code and have hung when loaded on another host.
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    return env_dir or jax.config.jax_compilation_cache_dir

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -230,45 +231,57 @@ class TpuBackend:
     #: it remains a seam for environments whose compiler lacks the kernels.
     _planar_capable = True
 
+    def _layout(self, agg_id: int, rows: int) -> str:
+        """The device layout one device's ``rows``-row launch runs in."""
+        if self.canonical:
+            # canonical batches carry the per-row mask input and run
+            # row-major only (the planar kernels take no masks)
+            return "canonical-row-major"
+        if self._planar_capable and self.bp.planar_eligible(agg_id, rows):
+            return "planar"
+        return "row-major"
+
+    def launch_layout(self, agg_id: int, pad_to: int) -> str:
+        """"planar" | "row-major" | "canonical-row-major": the layout a
+        launch padded to ``pad_to`` rows runs in — what the flight
+        recorder (and chip_smoke.py) report per flush."""
+        return self._layout(agg_id, pad_to)
+
+    def _prep(self, agg_id: int, kw):
+        """One device's prepare program (traced).  verify_key flows as a
+        traced input (it is per-task data), so one compilation per agg_id
+        serves every task."""
+        vk = kw.pop("verify_key_u8")
+        B = kw["nonces_u8"].shape[0]
+        if self._layout(agg_id, B) != "planar":
+            return self.bp.prep_init(agg_id, verify_key=vk, **kw)
+        # Limb-planar fast path (the bench pipeline), both sides: helpers
+        # expand share seeds through the planar XOF, the leader transposes
+        # its explicit shares in.  Outputs are identical; out_share
+        # transposes back to row-major for the unmarshal/aggregate
+        # interfaces.
+        out = self.bp.prep_init_planar(
+            agg_id,
+            vk,
+            kw["nonces_u8"],
+            share_seeds_u8=kw.get("share_seeds_u8"),
+            meas_limbs=kw.get("meas_limbs"),
+            proofs_limbs=kw.get("proofs_limbs"),
+            blinds_u8=kw.get("blinds_u8"),
+            public_parts_u8=kw.get("public_parts_u8"),
+        )
+        return dict(
+            out, out_share=self.bp.planar_out_share_to_rows(out["out_share"])
+        )
+
+    def _jit_per_device(self, per_device):
+        """Compile a per-device program; the mesh backend shard_maps it."""
+        return self._jax.jit(per_device)
+
     def _prep_fn(self, agg_id: int):
-        # verify_key flows as a traced input (it is per-task data), so one
-        # compilation per agg_id serves every task.
         fn = self._prep_fns.get(agg_id)
         if fn is None:
-
-            def prep(kw):
-                vk = kw.pop("verify_key_u8")
-                B = kw["nonces_u8"].shape[0]
-                # Canonical-mode batches carry the per-row mask input and
-                # run row-major only (the planar kernels take no masks).
-                if (
-                    self._planar_capable
-                    and "meas_len_u32" not in kw
-                    and self.bp.planar_eligible(agg_id, B)
-                ):
-                    # Limb-planar fast path (the bench pipeline), both
-                    # sides: helpers expand share seeds through the planar
-                    # XOF, the leader transposes its explicit shares in.
-                    # Outputs are identical; out_share transposes back to
-                    # row-major for the unmarshal/aggregate interfaces.
-                    out = self.bp.prep_init_planar(
-                        agg_id,
-                        vk,
-                        kw["nonces_u8"],
-                        share_seeds_u8=kw.get("share_seeds_u8"),
-                        meas_limbs=kw.get("meas_limbs"),
-                        proofs_limbs=kw.get("proofs_limbs"),
-                        blinds_u8=kw.get("blinds_u8"),
-                        public_parts_u8=kw.get("public_parts_u8"),
-                    )
-                    out = dict(
-                        out,
-                        out_share=self.bp.planar_out_share_to_rows(out["out_share"]),
-                    )
-                    return out
-                return self.bp.prep_init(agg_id, verify_key=vk, **kw)
-
-            fn = self._jax.jit(prep)
+            fn = self._jit_per_device(partial(self._prep, agg_id))
             self._prep_fns[agg_id] = fn
         return fn
 
@@ -757,52 +770,22 @@ class MeshBackend(TpuBackend):
     # the LOCAL (per-shard) batch during tracing, so planar engages exactly
     # when each chip's shard satisfies the kernels' tiling.
 
-    def _shard_wrap(self, per_shard):
+    def _jit_per_device(self, per_shard, n_args: int = 1):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 per_shard,
                 mesh=self.mesh,
-                in_specs=(PartitionSpec("batch"),),
+                in_specs=(PartitionSpec("batch"),) * n_args,
                 out_specs=PartitionSpec("batch"),
-                check_rep=False,
+                check_vma=False,
             )
         )
 
-    def _prep_fn(self, agg_id: int):
-        fn = self._prep_fns.get(agg_id)
-        if fn is None:
-
-            def per_shard(kw):
-                vk = kw.pop("verify_key_u8")
-                B = kw["nonces_u8"].shape[0]
-                if (
-                    self._planar_capable
-                    and "meas_len_u32" not in kw
-                    and self.bp.planar_eligible(agg_id, B)
-                ):
-                    out = self.bp.prep_init_planar(
-                        agg_id,
-                        vk,
-                        kw["nonces_u8"],
-                        share_seeds_u8=kw.get("share_seeds_u8"),
-                        meas_limbs=kw.get("meas_limbs"),
-                        proofs_limbs=kw.get("proofs_limbs"),
-                        blinds_u8=kw.get("blinds_u8"),
-                        public_parts_u8=kw.get("public_parts_u8"),
-                    )
-                    return dict(
-                        out,
-                        out_share=self.bp.planar_out_share_to_rows(out["out_share"]),
-                    )
-                return self.bp.prep_init(agg_id, verify_key=vk, **kw)
-
-            fn = self._shard_wrap(per_shard)
-            self._prep_fns[agg_id] = fn
-        return fn
+    def launch_layout(self, agg_id: int, pad_to: int) -> str:
+        return self._layout(agg_id, pad_to // len(self.mesh.devices))
 
     def _combine(self):
         if self._combine_fn is None:
@@ -812,7 +795,7 @@ class MeshBackend(TpuBackend):
                 vs, parts = args
                 return self.bp.prep_shares_to_prep(vs, parts if has_jr else None)
 
-            wrapped = self._shard_wrap(per_shard)
+            wrapped = self._jit_per_device(per_shard)
             self._combine_fn = lambda vs, parts: wrapped((vs, parts))
         return self._combine_fn
 
@@ -837,7 +820,7 @@ class MeshBackend(TpuBackend):
 
         Every marshaled array — including verify_key_u8, which
         prep_init_multi expands to one row per report — has the batch as
-        its leading axis, matching _shard_wrap's in_specs."""
+        its leading axis, matching _jit_per_device's in_specs."""
         return {
             k: self._jax.device_put(v, self._batch_sharding) for k, v in kw.items()
         }
@@ -871,7 +854,7 @@ class MeshBackend(TpuBackend):
                 delta = jf.sum(masked, axis=0)
                 return jf.add(buf, delta[None])
 
-            self._accum_fn = self._shard_wrap3(per_shard)
+            self._accum_fn = self._jit_per_device(per_shard, n_args=3)
         if buffer is None:
             jf = self.bp.jf
             buffer = self._jax.device_put(
@@ -882,25 +865,6 @@ class MeshBackend(TpuBackend):
                 self._batch_sharding,
             )
         return self._accum_fn(buffer, matrix, np.asarray(mask))
-
-    def _shard_wrap3(self, per_shard):
-        import jax
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec
-
-        return jax.jit(
-            shard_map(
-                per_shard,
-                mesh=self.mesh,
-                in_specs=(
-                    PartitionSpec("batch"),
-                    PartitionSpec("batch"),
-                    PartitionSpec("batch"),
-                ),
-                out_specs=PartitionSpec("batch"),
-                check_rep=False,
-            )
-        )
 
     def read_accum_buffer(self, buffer) -> List[int]:
         """Spill readback: the one point where the accumulated shards

@@ -9,7 +9,7 @@ field arithmetic fails tests/test_golden_vectors.py loudly.
 These are SELF-GENERATED vectors: they lock the implementation against
 drift, and the loader doubles as the harness for official
 draft-irtf-cfrg-vdaf test vectors once those JSON files can be vendored
-(no network access in this environment; see VERDICT.md item 4).
+(no network access in this environment).
 """
 
 import json

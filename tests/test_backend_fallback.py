@@ -133,6 +133,49 @@ def test_driver_fallback_is_logged(caplog):
     eds.cleanup()
 
 
+def test_driver_counts_a_backend_that_refuses_to_build(monkeypatch, caplog):
+    """A device backend that refuses a VDAF at BUILD time (make_backend
+    raising NotImplementedError) used to become the oracle without a
+    word; it must be logged and counted in janus_vdaf_backend_fallback
+    like the unsupported-circuit branch, or a chip run cannot tell the
+    oracle's answer from the device's."""
+    from janus_tpu.aggregator import aggregation_job_driver as drv
+    from janus_tpu.core.metrics import GLOBAL_METRICS
+    from janus_tpu.vdaf.backend import OracleBackend
+    from janus_tpu.vdaf.instances import prio3_count
+    from tests.test_datastore import make_task
+
+    real = drv.make_backend
+
+    def refusing(vdaf, backend="oracle", **kw):
+        if backend != "oracle":
+            raise NotImplementedError("no kernel for this circuit on this chip")
+        return real(vdaf, backend, **kw)
+
+    monkeypatch.setattr(drv, "make_backend", refusing)
+    labels = {
+        "vdaf_type": "Prio3",
+        "reason": "NotImplementedError: no kernel for this circuit on this chip",
+    }
+    before = (
+        GLOBAL_METRICS.get_sample_value("janus_vdaf_backend_fallback_total", labels) or 0
+    )
+    eds = EphemeralDatastore()
+    driver = drv.AggregationJobDriver(
+        eds.datastore,
+        session_factory=lambda: None,
+        config=drv.DriverConfig(vdaf_backend="tpu"),
+    )
+    task = make_task(vdaf={"type": "Prio3Count"})
+    with caplog.at_level(logging.WARNING, logger="janus_tpu.aggregation_job_driver"):
+        backend = driver._backend_for(task, prio3_count())
+    assert isinstance(backend, OracleBackend)
+    assert any("falls back to the CPU oracle" in r.message for r in caplog.records)
+    after = GLOBAL_METRICS.get_sample_value("janus_vdaf_backend_fallback_total", labels)
+    assert after == before + 1
+    eds.cleanup()
+
+
 def test_provisioning_warns_for_oracle_only_vdaf():
     from janus_tpu.core.hpke import HpkeKeypair
 

@@ -129,6 +129,7 @@ def test_every_known_point_is_wired():
         "accumulator.spill": "janus_tpu/executor/accumulator.py",
         "accumulator.evict": "janus_tpu/executor/accumulator.py",
         "accumulator.replay": "janus_tpu/aggregator/collection_job_driver.py",
+        "collection.aggregate_share": "janus_tpu/aggregator/collection_job_driver.py",
         "ingest.journal": "janus_tpu/core/ingest.py",
         "journal.corrupt": "janus_tpu/datastore/datastore.py",
     }

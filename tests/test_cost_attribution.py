@@ -661,21 +661,54 @@ def test_bench_compare_baseline_and_unit_mismatch():
     }
 
 
-def test_bench_compare_loads_real_checked_in_trajectory():
-    """The repo's own BENCH rows must parse and PASS (the ./ci.sh
-    benchdiff contract: the current trajectory gates green)."""
-    import glob
-    import pathlib
+def test_bench_compare_loads_a_trajectory_from_record_files(tmp_path):
+    """load_runs reads the driver's record-file shape ({n, cmd, rc, tail,
+    parsed}) from disk: an empty parse and a failed run (rc != 0, parsed
+    null) load as row-less, multi-config payloads load per config, and
+    the runs come back ordered by ``n`` whatever order the files sort
+    in.  (The repo's own record files went with the installation they
+    were taken on — PR 21 — so the trajectory here is written to disk by
+    the test.)"""
+    import json
 
     from tools.bench_compare import compare, load_runs
 
-    repo = pathlib.Path(__file__).resolve().parents[1]
-    paths = sorted(glob.glob(str(repo / "BENCH_r*.json")))
-    assert len(paths) >= 5
-    runs = load_runs(paths)
-    assert [r["n"] for r in runs] == sorted(r["n"] for r in runs)
+    def record(n, rc, parsed):
+        doc = {"n": n, "cmd": "python bench.py", "rc": rc, "tail": "", "parsed": parsed}
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
+
+    def payload(value):
+        return {
+            "metric": "prepare_throughput_histogram1024",
+            "value": value,
+            "unit": "reports/s",
+            "configs": {
+                "histogram1024": {"value": value, "unit": "reports/s"},
+                "count": {"value": 10 * value, "unit": "reports/s"},
+            },
+        }
+
+    record(1, 0, None)
+    record(2, 0, {"metric": "prepare_throughput", "value": 50.0, "unit": "reports/s"})
+    record(3, 0, payload(100.0))
+    record(4, 0, payload(120.0))
+    record(5, 1, None)
+    paths = sorted(str(p) for p in tmp_path.glob("BENCH_r*.json"))
+    runs = load_runs(list(reversed(paths)))
+    assert [r["n"] for r in runs] == [1, 2, 3, 4, 5]
+    assert runs[0]["rows"] is None and runs[4]["rows"] is None and runs[4]["rc"] == 1
+    assert set(runs[1]["rows"]) == {"prepare_throughput"}
+    assert set(runs[3]["rows"]) == {"histogram1024", "count"}
+    # the newest run failed outright: neutral, never a regression
     v = compare(runs, tolerance=0.10)
-    assert v["ok"], v
+    assert v["ok"] and v["neutral"], v
+    # and a real -20% newest run on the same files is caught
+    record(6, 0, payload(96.0))
+    v = compare(load_runs(paths + [str(tmp_path / "BENCH_r06.json")]), tolerance=0.10)
+    assert not v["ok"] and {r["config"] for r in v["regressions"]} == {
+        "histogram1024",
+        "count",
+    }
 
 
 # ---------------------------------------------------------------------------

@@ -58,7 +58,6 @@ TIME_PRECISION = Duration(3600)
 _BOOT = (
     "import os, sys;"
     "os.environ['JAX_PLATFORMS'] = 'cpu';"
-    "import jax; jax.config.update('jax_platforms', 'cpu');"
     "from janus_tpu.binaries.main import main;"
     "sys.exit(main(sys.argv[1:]))"
 )

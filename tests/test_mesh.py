@@ -100,7 +100,6 @@ def test_mesh_aggregate_psum_matches_oracle():
     batch axis (XLA inserts the all-reduce) must equal both the oracle
     aggregate and an explicit shard_map+psum formulation."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     vdaf = prio3_count()
     rng = det_rng("mesh-agg")
@@ -136,10 +135,10 @@ def test_mesh_aggregate_psum_matches_oracle():
         gathered = jax.lax.all_gather(partial, "batch")  # (8, OUT, n)
         return jf.sum(gathered, axis=0)  # (OUT, n) mod p, replicated
 
-    # check_rep=False: the all_gather + local reduce IS replicated, but the
+    # check_vma=False: the all_gather + local reduce IS replicated, but the
     # rewrite rules can't statically prove it through the limb tree-sum.
-    fn = shard_map(
-        per_shard, mesh=mesh, in_specs=P("batch"), out_specs=P(), check_rep=False
+    fn = jax.shard_map(
+        per_shard, mesh=mesh, in_specs=P("batch"), out_specs=P(), check_vma=False
     )
     placed = jax.device_put(np.asarray(limbs), NamedSharding(mesh, P("batch")))
     collective_res = jf.from_limbs(np.asarray(jax.jit(fn)(placed)))

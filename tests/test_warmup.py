@@ -3,9 +3,10 @@
 Scheduling/ledger/metric machinery runs against stubbed warmup compiles
 (no jax); one real-backend case proves the background thread actually
 compiles executables.  The compile-cache tests pin enable_compile_cache's
-contract — host-fingerprint scoping under an explicit root, deterministic
-resolution across a restart, and the no-cache-on-CPU guard — against a
-recording stand-in for jax.config (this container has no TPU)."""
+contract — JAX_COMPILATION_CACHE_DIR wins and sets no directory in code,
+a fixed <repo>/.jax_cache otherwise, and the no-cache-on-CPU guard decided
+from the elected backend — against a recording stand-in for jax.config
+(this container has no TPU)."""
 
 import asyncio
 import threading
@@ -135,6 +136,51 @@ def test_warmup_sync_mode_preserves_legacy_inline_behavior(monkeypatch):
     ex.shutdown()
 
 
+def test_warm_shape_pads_every_flush_up_to_the_warmed_executable(monkeypatch):
+    """Once a shape is warm, a flush that fits pads up to warmup_rows and
+    runs on the executable warmup compiled; a smaller pow2 pad would be a
+    new shape — on the chip, minutes of compile on the launch thread.  A
+    larger flush, and a shape that was never warmed, keep the pow2 pad;
+    the flight record says which layout the backend chose."""
+    ex = DeviceExecutor(
+        ExecutorConfig(warmup_rows=16, warmup_async=False, flush_window_s=0.01)
+    )
+    monkeypatch.setattr(
+        ex, "warmup_backend", lambda b, agg_ids=(0, 1), pad_to=None: 2
+    )
+    staged_pads = []
+
+    class _Backend(_FakeBackend):
+        def stage_prep_init_multi(self, agg_id, requests, pad_to=None):
+            staged = super().stage_prep_init_multi(agg_id, requests, pad_to=pad_to)
+            staged_pads.append(staged.pad_to)
+            return staged
+
+        def launch_layout(self, agg_id, pad_to):
+            return "planar" if pad_to % 16 == 0 else "row-major"
+
+    warm = ex.backend_for(("warm-shape",), _Backend)
+    assert ex.compile_stats() and not ex.warming(("warm-shape",))
+    _run(ex.submit(("warm-shape",), "prep_init", (b"k", [1, 2, 3]), backend=warm))
+    _run(ex.submit(("warm-shape",), "prep_init", (b"k", list(range(20))), backend=warm))
+    assert staged_pads == [16, 32]  # padded up to the warm size; above it, pow2
+    flights = ex.flight_recorder.snapshot(2)
+    assert [f["padded_rows"] for f in reversed(flights)] == [13, 12]
+    assert [f["layout"] for f in reversed(flights)] == ["planar", "planar"]
+
+    # never warmed (warmup of THIS shape failed): plain pow2 pad
+    monkeypatch.setattr(
+        ex,
+        "warmup_backend",
+        lambda b, agg_ids=(0, 1), pad_to=None: (_ for _ in ()).throw(RuntimeError("x")),
+    )
+    cold = ex.backend_for(("cold-shape",), _Backend)
+    _run(ex.submit(("cold-shape",), "prep_init", (b"k", [1, 2, 3]), backend=cold))
+    assert staged_pads[-1] == 4
+    assert ex.flight_recorder.snapshot(1)[0]["layout"] == "row-major"
+    ex.shutdown()
+
+
 def test_cold_state_tracked_without_warmup():
     ex = DeviceExecutor(ExecutorConfig(warmup_rows=0))
     ex.backend_for(("shape",), _FakeBackend)
@@ -259,82 +305,107 @@ def test_driver_serves_on_oracle_while_shape_warms(monkeypatch):
 
 
 class _RecordingConfig:
-    """Stand-in for jax.config: records update() calls; platform settable."""
+    """Stand-in for jax.config: records update() calls."""
 
-    def __init__(self, platforms):
-        self.jax_platforms = platforms
+    def __init__(self):
         self.updates = {}
 
     def update(self, key, value):
         self.updates[key] = value
 
+    @property
+    def jax_compilation_cache_dir(self):
+        return self.updates.get("jax_compilation_cache_dir")
 
-def _patched_enable(monkeypatch, platforms, env_platforms, cache_dir=None):
+
+def _patched_enable(monkeypatch, backend, env_dir=None, cache_dir=None):
+    """enable_compile_cache() with ``backend`` as the ELECTED JAX backend
+    and JAX_COMPILATION_CACHE_DIR set to ``env_dir`` (None = unset)."""
     import jax
 
     from janus_tpu.utils import jax_setup
 
-    rec = _RecordingConfig(platforms)
+    rec = _RecordingConfig()
     monkeypatch.setattr(jax, "config", rec)
-    monkeypatch.setenv("JAX_PLATFORMS", env_platforms)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
     return jax_setup.enable_compile_cache(cache_dir), rec
 
 
-def test_compile_cache_scopes_explicit_root_by_host_fingerprint(
+def test_compile_cache_env_dir_wins_and_sets_no_directory_in_code(
     monkeypatch, tmp_path
 ):
-    from janus_tpu.utils import jax_setup
-
-    enabled, rec = _patched_enable(
-        monkeypatch, "tpu", "tpu", cache_dir=str(tmp_path / "fleet-cache")
+    """JAX_COMPILATION_CACHE_DIR places the cache from outside: JAX reads
+    it itself, so the function sets NO directory — not the default and
+    not common.compile_cache_dir either."""
+    used, rec = _patched_enable(
+        monkeypatch,
+        "tpu",
+        env_dir=str(tmp_path / "outside"),
+        cache_dir=str(tmp_path / "fleet-cache"),
     )
-    assert enabled
-    path = rec.updates["jax_compilation_cache_dir"]
-    # under the configured root, but in a config-digest subdirectory: a
-    # shared volume never mixes executables across platform/host configs
-    assert path.startswith(str(tmp_path / "fleet-cache"))
-    assert path != str(tmp_path / "fleet-cache")
+    assert used == str(tmp_path / "outside")
+    assert "jax_compilation_cache_dir" not in rec.updates
     assert rec.updates["jax_persistent_cache_min_entry_size_bytes"] == 0
     assert rec.updates["jax_persistent_cache_min_compile_time_secs"] == 0
-    # a different XLA_FLAGS configuration resolves to a DIFFERENT subdir
+
+
+def test_compile_cache_default_is_fixed_path_in_checkout(monkeypatch):
+    """Unset, the cache is <repo>/.jax_cache with nothing derived from the
+    host, the platform string or XLA_FLAGS in it: two processes — on two
+    machines — resolve the same directory, so the second one loads what
+    the first compiled."""
+    import os
+
+    from janus_tpu.utils import jax_setup
+
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    used1, rec1 = _patched_enable(monkeypatch, "tpu")
     monkeypatch.setenv("XLA_FLAGS", "--xla_something_else")
-    assert jax_setup.resolve_cache_dir(str(tmp_path / "fleet-cache")) != path
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    used2, rec2 = _patched_enable(monkeypatch, "tpu")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert used1 == used2 == os.path.join(repo, ".jax_cache")
+    assert jax_setup.DEFAULT_CACHE_DIR == used1
+    assert rec1.updates["jax_compilation_cache_dir"] == used1
+    assert rec2.updates["jax_compilation_cache_dir"] == used1
 
 
-def test_compile_cache_restart_resolves_same_dir(monkeypatch, tmp_path):
-    """The restart contract: two processes with identical platform config
-    and host resolve the same cache dir, so the second replay-loads every
-    executable the first compiled (nothing recompiles on TPU platforms)."""
-    enabled1, rec1 = _patched_enable(
-        monkeypatch, "tpu", "tpu", cache_dir=str(tmp_path)
+def test_compile_cache_config_dir_used_as_given(monkeypatch, tmp_path):
+    """common.compile_cache_dir (a fleet-shared volume) is used as given —
+    no per-host sub-directory, so every replica that mounts it shares it."""
+    used, rec = _patched_enable(
+        monkeypatch, "tpu", cache_dir=str(tmp_path / "fleet-cache")
     )
-    enabled2, rec2 = _patched_enable(
-        monkeypatch, "tpu", "tpu", cache_dir=str(tmp_path)
-    )
-    assert enabled1 and enabled2
-    assert (
-        rec1.updates["jax_compilation_cache_dir"]
-        == rec2.updates["jax_compilation_cache_dir"]
-    )
+    assert used == str(tmp_path / "fleet-cache")
+    assert rec.updates["jax_compilation_cache_dir"] == used
 
 
-def test_compile_cache_cpu_guard_regression(monkeypatch, tmp_path):
+def test_compile_cache_cpu_guard_follows_elected_backend(monkeypatch, tmp_path):
     """XLA:CPU AOT loads are poisoned (see enable_compile_cache): the
-    guard must win even over an explicitly configured cache dir."""
-    enabled, rec = _patched_enable(
-        monkeypatch, "cpu", "cpu", cache_dir=str(tmp_path)
+    guard must win even over an explicitly placed cache — and it decides
+    from the backend JAX elected, not from JAX_PLATFORMS being empty (the
+    chip machine leaves it unset)."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    used, rec = _patched_enable(
+        monkeypatch, "cpu", env_dir=str(tmp_path), cache_dir=str(tmp_path)
     )
-    assert enabled is False
+    assert used is None
     assert rec.updates == {}
+    used, rec = _patched_enable(monkeypatch, "tpu")
+    assert used is not None  # same empty JAX_PLATFORMS, a chip was elected
 
 
-def test_bootstrap_wires_compile_cache_behind_common_config(monkeypatch, tmp_path):
+def test_bootstrap_wires_compile_cache_for_device_binaries(monkeypatch, tmp_path):
     from janus_tpu.binaries import main as binmain
 
     calls = []
     monkeypatch.setattr(
         "janus_tpu.utils.jax_setup.enable_compile_cache",
-        lambda d=None: calls.append(d) or True,
+        lambda d=None: calls.append(d) or "/somewhere",
     )
     monkeypatch.setenv(
         "DATASTORE_KEYS", "AAAAAAAAAAAAAAAAAAAAAA"
@@ -345,11 +416,16 @@ def test_bootstrap_wires_compile_cache_behind_common_config(monkeypatch, tmp_pat
         database=DbConfig(path=str(tmp_path / "db.sqlite3")),
         compile_cache_dir=str(tmp_path / "cache"),
     )
-    clock, datastore = binmain._bootstrap(cfg)
+    clock, datastore = binmain._bootstrap(cfg, device=True)
     assert calls == [str(tmp_path / "cache")]
-    # absent config -> no cache call
+    # absent config -> still on, at the function's own default
     calls.clear()
     cfg2 = CommonConfig(database=DbConfig(path=str(tmp_path / "db2.sqlite3")))
+    binmain._bootstrap(cfg2, device=True)
+    assert calls == [None]
+    # a binary that never launches on the device (creator, collection
+    # driver, oracle-backed aggregator) must not touch JAX at all
+    calls.clear()
     binmain._bootstrap(cfg2)
     assert calls == []
 

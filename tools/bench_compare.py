@@ -20,8 +20,10 @@ signal.  This tool turns it into one:
 * configs with no prior datapoint are BASELINES (recorded, not judged).
 
 Exit codes: 0 = pass (or fully neutral), 1 = regression, 2 = usage/IO.
-``./ci.sh benchdiff`` runs this against the checked-in rows and then
-proves the gate bites on a synthetic −20% fixture.
+(The repo no longer checks record files in — PR 21 removed the ones taken
+on a retired installation, and the ``./ci.sh benchdiff`` stage that read
+them; ``PERF_LEDGER.jsonl`` is the record now.  Point ``--dir`` at any
+directory of ``BENCH_rNN.json`` files.)
 """
 
 from __future__ import annotations

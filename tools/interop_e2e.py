@@ -69,7 +69,8 @@ def spawn(role: str, port: int, backend: str, logdir: str) -> subprocess.Popen:
     env = dict(os.environ)
     env["JANUS_TPU_VDAF_BACKEND"] = backend
     # Interop processes always run on the host CPU (virtual mesh for
-    # backend=mesh); the real chip is reserved for bench.
+    # backend=mesh): a chip belongs to ONE process, and this harness
+    # starts four.  The parent itself never imports JAX.
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
