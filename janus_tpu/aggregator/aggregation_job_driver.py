@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..core.retries import HttpRetryPolicy, retry_http_request
+from ..core.trace import trace_phase
 from ..datastore import (
     AggregationJob,
     AggregationJobState,
@@ -314,7 +315,8 @@ class AggregationJobDriver:
             )
             return task, job, ras
 
-        task, job, ras = await self.datastore.run_tx_async("step_agg_job_1", load)
+        with trace_phase("leader_step", "load_tx", "io"):
+            task, job, ras = await self.datastore.run_tx_async("step_agg_job_1", load)
         if task is None or job is None:
             raise JobStepError("job or task vanished", retryable=False)
         if job.state != AggregationJobState.IN_PROGRESS:
@@ -926,14 +928,15 @@ class AggregationJobDriver:
             loop (the loop must keep serving lease heartbeats and the
             coalescing gather timers)."""
             good, bad = [], []
-            for ra in start_ras:
-                try:
-                    public_parts = vdaf.decode_public_share(ra.public_share or b"")
-                    input_share = vdaf.decode_input_share(0, ra.leader_input_share)
-                except (VdafError, Exception):
-                    bad.append(ra.report_id.data)
-                    continue
-                good.append((ra, public_parts, input_share))
+            with trace_phase("leader_step", "decode_rows", "python", rows=len(start_ras)):
+                for ra in start_ras:
+                    try:
+                        public_parts = vdaf.decode_public_share(ra.public_share or b"")
+                        input_share = vdaf.decode_input_share(0, ra.leader_input_share)
+                    except (VdafError, Exception):
+                        bad.append(ra.report_id.data)
+                        continue
+                    good.append((ra, public_parts, input_share))
             return good, bad
 
         rows, bad_ids = await loop.run_in_executor(None, decode_rows)
@@ -957,29 +960,31 @@ class AggregationJobDriver:
                     task_ident=task.task_id.data,
                 )
             else:
-                prep_out = await self._coalesced_prep_init(
-                    backend,
-                    task.vdaf_verify_key,
-                    prep_in,
-                    # per-task fairness quota: the DRR accounting domain
-                    # WITHIN the shared shape bucket
-                    # (executor._pick_entry_locked)
-                    task_ident=task.task_id.data,
-                    vdaf=vdaf,
-                )
+                with trace_phase("leader_step", "prep_init", "queue", rows=len(prep_in)):
+                    prep_out = await self._coalesced_prep_init(
+                        backend,
+                        task.vdaf_verify_key,
+                        prep_in,
+                        # per-task fairness quota: the DRR accounting
+                        # domain WITHIN the shared shape bucket
+                        # (executor._pick_entry_locked)
+                        task_ident=task.task_id.data,
+                        vdaf=vdaf,
+                    )
 
             def wrap_outcomes():
                 out = {}
-                for (ra, _pub, _sh), outcome in zip(rows, prep_out):
-                    if isinstance(outcome, VdafError):
-                        out[ra.report_id.data] = PrepareError.VDAF_PREP_ERROR
-                        continue
-                    state, share = outcome
-                    msg = pp.PingPongMessage(
-                        pp.PingPongMessage.INITIALIZE,
-                        prep_share=vdaf.ping_pong_encode_prep_share(share),
-                    )
-                    out[ra.report_id.data] = (pp.PingPongContinued(state, 0), msg)
+                with trace_phase("leader_step", "wrap_outcomes", "python", rows=len(rows)):
+                    for (ra, _pub, _sh), outcome in zip(rows, prep_out):
+                        if isinstance(outcome, VdafError):
+                            out[ra.report_id.data] = PrepareError.VDAF_PREP_ERROR
+                            continue
+                        state, share = outcome
+                        msg = pp.PingPongMessage(
+                            pp.PingPongMessage.INITIALIZE,
+                            prep_share=vdaf.ping_pong_encode_prep_share(share),
+                        )
+                        out[ra.report_id.data] = (pp.PingPongContinued(state, 0), msg)
                 return out
 
             outcomes.update(await loop.run_in_executor(None, wrap_outcomes))
@@ -1059,41 +1064,43 @@ class AggregationJobDriver:
         prepare_inits = []
         states: Dict[bytes, pp.PingPongContinued] = {}
         failed: Dict[bytes, PrepareError] = {}
-        for ra in start_ras:
-            outcome = outcomes[ra.report_id.data]
-            if isinstance(outcome, PrepareError):
-                failed[ra.report_id.data] = outcome
-                continue
-            state, msg = outcome
-            states[ra.report_id.data] = state
-            prepare_inits.append(
-                PrepareInit(
-                    ReportShare(
-                        ReportMetadata(ra.report_id, ra.time),
-                        ra.public_share or b"",
-                        ra.helper_encrypted_input_share,
-                    ),
-                    msg,
+        with trace_phase("leader_step", "encode_req", "python", rows=len(start_ras)):
+            for ra in start_ras:
+                outcome = outcomes[ra.report_id.data]
+                if isinstance(outcome, PrepareError):
+                    failed[ra.report_id.data] = outcome
+                    continue
+                state, msg = outcome
+                states[ra.report_id.data] = state
+                prepare_inits.append(
+                    PrepareInit(
+                        ReportShare(
+                            ReportMetadata(ra.report_id, ra.time),
+                            ra.public_share or b"",
+                            ra.helper_encrypted_input_share,
+                        ),
+                        msg,
+                    )
                 )
-            )
 
-        if task.query_type.kind == "FixedSize":
-            pbs = PartialBatchSelector.new_fixed_size(job.partial_batch_identifier)
-        else:
-            pbs = PartialBatchSelector.new_time_interval()
-        req = AggregationJobInitializeReq(
-            aggregation_parameter=job.aggregation_parameter,
-            partial_batch_selector=pbs,
-            prepare_inits=prepare_inits,
-        )
-        resp = await self._send_to_helper(
-            task,
-            "PUT",
-            f"aggregation_jobs/{job.aggregation_job_id}",
-            req.get_encoded(),
-            AggregationJobInitializeReq.MEDIA_TYPE,
-            lease=lease,
-        )
+            if task.query_type.kind == "FixedSize":
+                pbs = PartialBatchSelector.new_fixed_size(job.partial_batch_identifier)
+            else:
+                pbs = PartialBatchSelector.new_time_interval()
+            body = AggregationJobInitializeReq(
+                aggregation_parameter=job.aggregation_parameter,
+                partial_batch_selector=pbs,
+                prepare_inits=prepare_inits,
+            ).get_encoded()
+        with trace_phase("leader_step", "helper_http", "io", bytes=len(body)):
+            resp = await self._send_to_helper(
+                task,
+                "PUT",
+                f"aggregation_jobs/{job.aggregation_job_id}",
+                body,
+                AggregationJobInitializeReq.MEDIA_TYPE,
+                lease=lease,
+            )
         await self._process_helper_resp(
             lease, task, vdaf, job, all_ras, states, failed, resp
         )
@@ -1171,126 +1178,128 @@ class AggregationJobDriver:
     ) -> None:
         """Merge helper PrepareResps into report aggregations
         (reference: :629-793 process_response_from_helper)."""
-        finished_now = finished_now or {}
-        by_id = {pr.report_id.data: pr for pr in resp.prepare_resps}
-        new_ras: List[ReportAggregation] = []
-        out_shares: Dict[bytes, Sequence[int]] = {}
-        # Multi-round deferred journaling (Poplar1): a report that will only
-        # FINISH at a later round must carry its StartLeader payload through
-        # every WAITING round — the payload is the journal's oracle-replay
-        # window, and with_state() clears it by default.  Costs storage only
-        # while the journal machinery is armed for this VDAF.
-        store_cfg = getattr(
-            self._executor.accumulator if self._executor is not None else None,
-            "config",
-            None,
-        )
-        retain_waiting_payload = (
-            store_cfg is not None
-            and getattr(store_cfg, "deferred", False)
-            and getattr(vdaf, "REQUIRES_AGG_PARAM", False)
-        )
-        for ra in all_ras:
-            rid = ra.report_id.data
-            if ra.state in (
-                ReportAggregationState.FINISHED,
-                ReportAggregationState.FAILED,
-            ):
-                continue  # already terminal; no update needed
-            if rid in failed:
-                new_ras.append(ra.failed(failed[rid]))
-                continue
-            pr = by_id.get(rid)
-            if pr is None:
-                new_ras.append(ra.failed(PrepareError.REPORT_DROPPED))
-                continue
-            if pr.result.variant == PrepareStepResult.REJECT:
-                new_ras.append(ra.failed(pr.result.error))
-                continue
-            if rid in finished_now:
-                if pr.result.variant != PrepareStepResult.FINISHED:
+        with trace_phase("leader_step", "process_resp", "python", rows=len(all_ras)):
+            finished_now = finished_now or {}
+            by_id = {pr.report_id.data: pr for pr in resp.prepare_resps}
+            new_ras: List[ReportAggregation] = []
+            out_shares: Dict[bytes, Sequence[int]] = {}
+            # Multi-round deferred journaling (Poplar1): a report that will only
+            # FINISH at a later round must carry its StartLeader payload through
+            # every WAITING round — the payload is the journal's oracle-replay
+            # window, and with_state() clears it by default.  Costs storage only
+            # while the journal machinery is armed for this VDAF.
+            store_cfg = getattr(
+                self._executor.accumulator if self._executor is not None else None,
+                "config",
+                None,
+            )
+            retain_waiting_payload = (
+                store_cfg is not None
+                and getattr(store_cfg, "deferred", False)
+                and getattr(vdaf, "REQUIRES_AGG_PARAM", False)
+            )
+            for ra in all_ras:
+                rid = ra.report_id.data
+                if ra.state in (
+                    ReportAggregationState.FINISHED,
+                    ReportAggregationState.FAILED,
+                ):
+                    continue  # already terminal; no update needed
+                if rid in failed:
+                    new_ras.append(ra.failed(failed[rid]))
+                    continue
+                pr = by_id.get(rid)
+                if pr is None:
+                    new_ras.append(ra.failed(PrepareError.REPORT_DROPPED))
+                    continue
+                if pr.result.variant == PrepareStepResult.REJECT:
+                    new_ras.append(ra.failed(pr.result.error))
+                    continue
+                if rid in finished_now:
+                    if pr.result.variant != PrepareStepResult.FINISHED:
+                        new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
+                        continue
+                    new_ras.append(ra.with_state(ReportAggregationState.FINISHED))
+                    out_shares[rid] = finished_now[rid]
+                    continue
+                if pr.result.variant != PrepareStepResult.CONTINUE:
                     new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
                     continue
-                new_ras.append(ra.with_state(ReportAggregationState.FINISHED))
-                out_shares[rid] = finished_now[rid]
-                continue
-            if pr.result.variant != PrepareStepResult.CONTINUE:
-                new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
-                continue
-            state = states.get(rid)
-            if state is None:
-                new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
-                continue
-            try:
-                value = pp.continued(
-                    vdaf, True, state, pr.result.message,
-                    vdaf.decode_agg_param(job.aggregation_parameter),
-                )
-            except (VdafError, pp.PingPongError):
-                new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
-                continue
-            if value.out_share is not None:
-                new_ras.append(ra.with_state(ReportAggregationState.FINISHED))
-                out_shares[rid] = value.out_share
-            else:
-                keep = (
-                    dict(
-                        public_share=ra.public_share,
-                        leader_input_share=ra.leader_input_share,
+                state = states.get(rid)
+                if state is None:
+                    new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
+                    continue
+                try:
+                    value = pp.continued(
+                        vdaf, True, state, pr.result.message,
+                        vdaf.decode_agg_param(job.aggregation_parameter),
                     )
-                    if retain_waiting_payload
-                    else {}
-                )
-                new_ras.append(
-                    ra.with_state(
-                        ReportAggregationState.WAITING_LEADER,
-                        leader_prep_transition=value.transition.encode(vdaf),
-                        **keep,
+                except (VdafError, pp.PingPongError):
+                    new_ras.append(ra.failed(PrepareError.VDAF_PREP_ERROR))
+                    continue
+                if value.out_share is not None:
+                    new_ras.append(ra.with_state(ReportAggregationState.FINISHED))
+                    out_shares[rid] = value.out_share
+                else:
+                    keep = (
+                        dict(
+                            public_share=ra.public_share,
+                            leader_input_share=ra.leader_input_share,
+                        )
+                        if retain_waiting_payload
+                        else {}
                     )
-                )
+                    new_ras.append(
+                        ra.with_state(
+                            ReportAggregationState.WAITING_LEADER,
+                            leader_prep_transition=value.transition.encode(vdaf),
+                            **keep,
+                        )
+                    )
 
-        any_waiting = any(
-            ra.state == ReportAggregationState.WAITING_LEADER for ra in new_ras
-        )
-        job = job.with_step(
-            next_step if next_step is not None else AggregationJobStep(int(job.step) + 1)
-        )
-        job = job.with_state(
-            AggregationJobState.IN_PROGRESS
-            if any_waiting
-            else AggregationJobState.FINISHED
-        )
+            any_waiting = any(
+                ra.state == ReportAggregationState.WAITING_LEADER for ra in new_ras
+            )
+            job = job.with_step(
+                next_step if next_step is not None else AggregationJobStep(int(job.step) + 1)
+            )
+            job = job.with_state(
+                AggregationJobState.IN_PROGRESS
+                if any_waiting
+                else AggregationJobState.FINISHED
+            )
 
-        # Device-resident out shares: commit the finished rows' ResidentRefs
-        # into per-batch resident accumulators BEFORE the transaction — a
-        # tx retry must never replay a device psum.  Drain-at-commit mode
-        # spills the delta NOW (one O(OUT) readback per batch bucket);
-        # deferred mode leaves it resident and persists a journal row in
-        # the tx instead (crash recovery replays from the datastore).
-        # finished-at-evaluate rows the helper rejected never reached
-        # out_shares: their device-resident refs (Poplar1) must release or
-        # the retained sketch matrix never frees
-        self._release_finished_refs(
-            {
-                rid: v
-                for rid, v in finished_now.items()
-                if rid not in out_shares
-            }
-        )
-        (
-            accumulator_deltas,
-            journal_entries,
-            touched_buckets,
-        ) = await self._commit_resident_shares(
-            task, vdaf, job, all_ras, states, out_shares,
-            # WAITING rows (multi-round VDAFs) keep their refs alive: the
-            # next step's transition evaluation finishes them
-            waiting_rids={
-                ra.report_id.data
-                for ra in new_ras
-                if ra.state == ReportAggregationState.WAITING_LEADER
-            },
-        )
+            # Device-resident out shares: commit the finished rows' ResidentRefs
+            # into per-batch resident accumulators BEFORE the transaction — a
+            # tx retry must never replay a device psum.  Drain-at-commit mode
+            # spills the delta NOW (one O(OUT) readback per batch bucket);
+            # deferred mode leaves it resident and persists a journal row in
+            # the tx instead (crash recovery replays from the datastore).
+            # finished-at-evaluate rows the helper rejected never reached
+            # out_shares: their device-resident refs (Poplar1) must release or
+            # the retained sketch matrix never frees
+            self._release_finished_refs(
+                {
+                    rid: v
+                    for rid, v in finished_now.items()
+                    if rid not in out_shares
+                }
+            )
+        with trace_phase("leader_step", "commit_shares", "queue"):
+            (
+                accumulator_deltas,
+                journal_entries,
+                touched_buckets,
+            ) = await self._commit_resident_shares(
+                task, vdaf, job, all_ras, states, out_shares,
+                # WAITING rows (multi-round VDAFs) keep their refs alive:
+                # the next step's transition evaluation finishes them
+                waiting_rids={
+                    ra.report_id.data
+                    for ra in new_ras
+                    if ra.state == ReportAggregationState.WAITING_LEADER
+                },
+            )
 
         if journal_entries:
             # Deferred drains retain the StartLeader payloads on the
@@ -1325,7 +1334,8 @@ class AggregationJobDriver:
         from ..executor.accumulator import StaleAccumulatorDelta
 
         try:
-            await self.datastore.run_tx_async("step_agg_job_2", tx_fn)
+            with trace_phase("leader_step", "write_tx", "io"):
+                await self.datastore.run_tx_async("step_agg_job_2", tx_fn)
         except StaleAccumulatorDelta as e:
             # A report was failed in-tx (batch collected under our feet)
             # AFTER its row was drained/journaled.  The tx aborted with

@@ -24,6 +24,7 @@ from ..core.auth_tokens import AuthenticationToken
 from ..core.dp import dp_strategy_from_dict
 from ..core.hpke import HpkeApplicationInfo, HpkeError, HpkeKeypair, Label, open_, seal
 from ..core.time import Clock, interval_merge, time_add, time_to_batch_interval
+from ..core.trace import trace_phase
 from ..datastore import (
     AggregateShareJob,
     AggregationJob,
@@ -622,14 +623,16 @@ class Aggregator:
         if task.role != Role.HELPER:
             raise UnrecognizedTask("aggregate-init on non-helper")
         ta.check_aggregator_auth(auth_token)
-        req = AggregationJobInitializeReq.get_decoded(body, ta.query_class)
-        request_hash = hashlib.sha256(body).digest()
+        with trace_phase("helper_init", "decode_req", "python", bytes=len(body)):
+            req = AggregationJobInitializeReq.get_decoded(body, ta.query_class)
+            request_hash = hashlib.sha256(body).digest()
 
         # replay/idempotency check (reference: aggregator.rs:1748,2173-2209)
-        existing = await self.datastore.run_tx_async(
-            "agg_init_replay",
-            lambda tx: tx.get_aggregation_job(task_id, aggregation_job_id),
-        )
+        with trace_phase("helper_init", "replay_tx", "io"):
+            existing = await self.datastore.run_tx_async(
+                "agg_init_replay",
+                lambda tx: tx.get_aggregation_job(task_id, aggregation_job_id),
+            )
         if existing is not None:
             if existing.last_request_hash == request_hash:
                 return await self._stored_job_resp(task_id, aggregation_job_id)
@@ -660,11 +663,13 @@ class Aggregator:
                         break
             return out
 
-        replay_ids = await self.datastore.run_tx_async(
-            "agg_init_conflicts", find_replays
-        )
+        with trace_phase("helper_init", "conflicts_tx", "io"):
+            replay_ids = await self.datastore.run_tx_async(
+                "agg_init_conflicts", find_replays
+            )
         replay_set = set(replay_ids)
         now = self.clock.now()
+        rows = len(req.prepare_inits)
         # Batched HPKE open (ROADMAP front-door follow-on): the helper's
         # aggregate-init report-share opens are the same embarrassingly-
         # batchable shape as upload — cheap per-report validation inline,
@@ -673,22 +678,26 @@ class Aggregator:
         # per-report inline fallback on any batch-LEVEL error.
         decoded: List[Tuple[int, tuple]] = []  # (idx, (nonce, public, share, msg))
         to_open: List[Tuple[int, object]] = []  # (idx, OpenRequest)
-        for idx, pi in enumerate(req.prepare_inits):
-            err = self._helper_validate_report_share(ta, pi, replay_set, now)
-            if err is not None:
-                failed[idx] = err
-                continue
-            prepared = self._helper_open_request(ta, pi)
-            if isinstance(prepared, PrepareError):
-                failed[idx] = prepared
-            else:
-                to_open.append((idx, prepared))
+        with trace_phase("helper_init", "validate", "python", rows=rows):
+            for idx, pi in enumerate(req.prepare_inits):
+                err = self._helper_validate_report_share(ta, pi, replay_set, now)
+                if err is not None:
+                    failed[idx] = err
+                    continue
+                prepared = self._helper_open_request(ta, pi)
+                if isinstance(prepared, PrepareError):
+                    failed[idx] = prepared
+                else:
+                    to_open.append((idx, prepared))
         if to_open:
             loop = asyncio.get_running_loop()
-            if self.config.upload_open_backend == "batched":
-                from ..core.hpke_batch import open_batch
+            from ..core.hpke_batch import _open_one, open_batch
 
-                def run_opens():
+            def run_opens():
+                # timed here, on the worker thread that opens
+                with trace_phase("helper_init", "hpke_open", "python", rows=len(to_open)):
+                    if self.config.upload_open_backend != "batched":
+                        return [_open_one(*r) for _i, r in to_open]
                     try:
                         return open_batch([r for _i, r in to_open])
                     except Exception:
@@ -699,28 +708,21 @@ class Aggregator:
                             "batched aggregate-init open failed; falling "
                             "back to per-report opens"
                         )
-                        from ..core.hpke_batch import _open_one
-
                         return [_open_one(*r) for _i, r in to_open]
 
-                opened = await loop.run_in_executor(None, run_opens)
-            else:
-                from ..core.hpke_batch import _open_one
-
-                opened = await loop.run_in_executor(
-                    None, lambda: [_open_one(*r) for _i, r in to_open]
-                )
-            for (idx, _req), plaintext in zip(to_open, opened):
-                if isinstance(plaintext, Exception) or plaintext is None:
-                    failed[idx] = PrepareError.HPKE_DECRYPT_ERROR
-                    continue
-                item = self._helper_decode_opened_share(
-                    ta, req.prepare_inits[idx], plaintext
-                )
-                if isinstance(item, PrepareError):
-                    failed[idx] = item
-                else:
-                    decoded.append((idx, item))
+            opened = await loop.run_in_executor(None, run_opens)
+            with trace_phase("helper_init", "decode_shares", "python", rows=len(to_open)):
+                for (idx, _req), plaintext in zip(to_open, opened):
+                    if isinstance(plaintext, Exception) or plaintext is None:
+                        failed[idx] = PrepareError.HPKE_DECRYPT_ERROR
+                        continue
+                    item = self._helper_decode_opened_share(
+                        ta, req.prepare_inits[idx], plaintext
+                    )
+                    if isinstance(item, PrepareError):
+                        failed[idx] = item
+                    else:
+                        decoded.append((idx, item))
 
         # Batched prepare: ONE device launch for the whole job (north star).
         try:
@@ -764,97 +766,99 @@ class Aggregator:
             )
 
         # Assemble responses + report aggregations in request order.
-        ras: List[ReportAggregation] = []
-        out_shares: Dict[bytes, Sequence[int]] = {}
-        resps: List[PrepareResp] = []
-        interval = Interval.EMPTY
-        for idx, pi in enumerate(req.prepare_inits):
-            rid = pi.report_share.metadata.report_id
-            t = pi.report_share.metadata.time
-            interval = interval_merge(
-                interval, time_to_batch_interval(t, task.time_precision)
-            )
-            base = dict(
+        with trace_phase("helper_init", "assemble", "python", rows=rows):
+            ras: List[ReportAggregation] = []
+            out_shares: Dict[bytes, Sequence[int]] = {}
+            resps: List[PrepareResp] = []
+            interval = Interval.EMPTY
+            for idx, pi in enumerate(req.prepare_inits):
+                rid = pi.report_share.metadata.report_id
+                t = pi.report_share.metadata.time
+                interval = interval_merge(
+                    interval, time_to_batch_interval(t, task.time_precision)
+                )
+                base = dict(
+                    task_id=task_id,
+                    aggregation_job_id=aggregation_job_id,
+                    report_id=rid,
+                    time=t,
+                    ord=idx,
+                )
+                if idx in failed:
+                    err = failed[idx]
+                    resp = PrepareResp(rid, PrepareStepResult.reject(err))
+                    ras.append(
+                        ReportAggregation(
+                            state=ReportAggregationState.FAILED, error=err,
+                            last_prep_resp=resp, **base
+                        )
+                    )
+                    resps.append(resp)
+                    continue
+                outcome = results[idx]
+                if isinstance(outcome, PrepareError):
+                    resp = PrepareResp(rid, PrepareStepResult.reject(outcome))
+                    ras.append(
+                        ReportAggregation(
+                            state=ReportAggregationState.FAILED, error=outcome,
+                            last_prep_resp=resp, **base
+                        )
+                    )
+                    resps.append(resp)
+                    continue
+                kind, payload, outbound = outcome
+                resp = PrepareResp(rid, PrepareStepResult.new_continue(outbound))
+                if kind == "finished":
+                    out_shares[rid.data] = payload
+                    ras.append(
+                        ReportAggregation(
+                            state=ReportAggregationState.FINISHED,
+                            last_prep_resp=resp, **base
+                        )
+                    )
+                else:  # continued (multi-round VDAF)
+                    ras.append(
+                        ReportAggregation(
+                            state=ReportAggregationState.WAITING_HELPER,
+                            helper_prep_state=payload,
+                            last_prep_resp=resp, **base
+                        )
+                    )
+                resps.append(resp)
+
+            from ..core.trace import current_trace
+
+            job = AggregationJob(
                 task_id=task_id,
                 aggregation_job_id=aggregation_job_id,
-                report_id=rid,
-                time=t,
-                ord=idx,
+                aggregation_parameter=req.aggregation_parameter,
+                partial_batch_identifier=req.partial_batch_selector.batch_identifier
+                if task.query_type.kind == "FixedSize"
+                else None,
+                client_timestamp_interval=interval,
+                state=AggregationJobState.FINISHED
+                if all(
+                    ra.state
+                    in (ReportAggregationState.FINISHED, ReportAggregationState.FAILED)
+                    for ra in ras
+                )
+                else AggregationJobState.IN_PROGRESS,
+                step=AggregationJobStep(0),
+                last_request_hash=request_hash,
+                # cross-process correlation: the leader driver's traceparent
+                # (bound by the HTTP layer) persists on the helper's job row
+                trace_id=current_trace().get("trace_id"),
             )
-            if idx in failed:
-                err = failed[idx]
-                resp = PrepareResp(rid, PrepareStepResult.reject(err))
-                ras.append(
-                    ReportAggregation(
-                        state=ReportAggregationState.FAILED, error=err,
-                        last_prep_resp=resp, **base
-                    )
-                )
-                resps.append(resp)
-                continue
-            outcome = results[idx]
-            if isinstance(outcome, PrepareError):
-                resp = PrepareResp(rid, PrepareStepResult.reject(outcome))
-                ras.append(
-                    ReportAggregation(
-                        state=ReportAggregationState.FAILED, error=outcome,
-                        last_prep_resp=resp, **base
-                    )
-                )
-                resps.append(resp)
-                continue
-            kind, payload, outbound = outcome
-            resp = PrepareResp(rid, PrepareStepResult.new_continue(outbound))
-            if kind == "finished":
-                out_shares[rid.data] = payload
-                ras.append(
-                    ReportAggregation(
-                        state=ReportAggregationState.FINISHED,
-                        last_prep_resp=resp, **base
-                    )
-                )
-            else:  # continued (multi-round VDAF)
-                ras.append(
-                    ReportAggregation(
-                        state=ReportAggregationState.WAITING_HELPER,
-                        helper_prep_state=payload,
-                        last_prep_resp=resp, **base
-                    )
-                )
-            resps.append(resp)
-
-        from ..core.trace import current_trace
-
-        job = AggregationJob(
-            task_id=task_id,
-            aggregation_job_id=aggregation_job_id,
-            aggregation_parameter=req.aggregation_parameter,
-            partial_batch_identifier=req.partial_batch_selector.batch_identifier
-            if task.query_type.kind == "FixedSize"
-            else None,
-            client_timestamp_interval=interval,
-            state=AggregationJobState.FINISHED
-            if all(
-                ra.state
-                in (ReportAggregationState.FINISHED, ReportAggregationState.FAILED)
-                for ra in ras
-            )
-            else AggregationJobState.IN_PROGRESS,
-            step=AggregationJobStep(0),
-            last_request_hash=request_hash,
-            # cross-process correlation: the leader driver's traceparent
-            # (bound by the HTTP layer) persists on the helper's job row
-            trace_id=current_trace().get("trace_id"),
-        )
 
         # Helper-side retention (ISSUE 4 satellite): finished rows carrying
         # ResidentRefs psum into per-batch device accumulators and drain to
         # ONE vector per batch here, BEFORE the tx — closing the PR 3 gap
         # where the helper read its out shares back per flush.
         decoded_by_rid = {item[0]: item for _idx, item in decoded}
-        accumulator_deltas = await self._commit_helper_resident_shares(
-            ta, job, ras, out_shares, decoded_by_rid
-        )
+        with trace_phase("helper_init", "commit_shares", "queue"):
+            accumulator_deltas = await self._commit_helper_resident_shares(
+                ta, job, ras, out_shares, decoded_by_rid
+            )
 
         from ..executor.accumulator import ResidentRef, StaleAccumulatorDelta
 
@@ -872,7 +876,8 @@ class Aggregator:
             return writer.write(tx)
 
         try:
-            failures = await self.datastore.run_tx_async("agg_init_write", tx_fn)
+            with trace_phase("helper_init", "write_tx", "io"):
+                failures = await self.datastore.run_tx_async("agg_init_write", tx_fn)
         except TxConflict:
             # racing identical request: return the stored response
             return await self._stored_job_resp(task_id, aggregation_job_id)
@@ -1304,31 +1309,36 @@ class Aggregator:
             # request behind XLA (the breaker never sees compile-wait)
             return await loop.run_in_executor(None, oracle_path)
 
-        results, rows = await loop.run_in_executor(
-            None, lambda: self._helper_decode_leader_shares(vdaf, decoded)
-        )
+        def decode_leader_shares():
+            with trace_phase(
+                "helper_init", "decode_leader_shares", "python", rows=len(decoded)
+            ):
+                return self._helper_decode_leader_shares(vdaf, decoded)
+
+        results, rows = await loop.run_in_executor(None, decode_leader_shares)
         if not rows:
             return results
         prep_in = [(nonce, public, share) for (_, nonce, public, share, _) in rows]
         prep_out = None
         try:
-            prep_out = await self._executor.submit(
-                shape_key,
-                KIND_PREP_INIT,
-                # canonical backends take 3-tuple requests: the task's
-                # actual vdaf rides along for bucket-shape marshal
-                (ta.task.vdaf_verify_key, prep_in, vdaf)
-                if canonical
-                else (ta.task.vdaf_verify_key, prep_in),
-                backend=backend,
-                agg_id=1,
-                # Helper-side retention (ISSUE 4 satellite): with the
-                # accumulator store attached, the helper's out shares stay
-                # ON DEVICE and the writer consumes a drained delta
-                # instead of reading every row back.
-                retain_out_shares=self._executor.accumulator is not None,
-                task_ident=task_ident,
-            )
+            with trace_phase("helper_init", "prep_init", "queue", rows=len(prep_in)):
+                prep_out = await self._executor.submit(
+                    shape_key,
+                    KIND_PREP_INIT,
+                    # canonical backends take 3-tuple requests: the task's
+                    # actual vdaf rides along for bucket-shape marshal
+                    (ta.task.vdaf_verify_key, prep_in, vdaf)
+                    if canonical
+                    else (ta.task.vdaf_verify_key, prep_in),
+                    backend=backend,
+                    agg_id=1,
+                    # Helper-side retention (ISSUE 4 satellite): with the
+                    # accumulator store attached, the helper's out shares
+                    # stay ON DEVICE and the writer consumes a drained
+                    # delta instead of reading every row back.
+                    retain_out_shares=self._executor.accumulator is not None,
+                    task_ident=task_ident,
+                )
             combine_rows = []
             for (idx, _n, _p, _s, leader_share), outcome in zip(rows, prep_out):
                 if isinstance(outcome, VdafError):
@@ -1336,18 +1346,21 @@ class Aggregator:
                     continue
                 state, helper_share = outcome
                 combine_rows.append((idx, state, leader_share, helper_share))
-            combined = await self._executor.submit(
-                shape_key,
-                KIND_COMBINE,
-                [[ls, hs] for (_, _, ls, hs) in combine_rows],
-                backend=backend,
-                agg_id=1,
-                task_ident=task_ident,
-            )
-            results = await loop.run_in_executor(
-                None,
-                lambda: self._helper_finish_prio3(vdaf, results, combine_rows, combined),
-            )
+            with trace_phase("helper_init", "combine", "queue", rows=len(combine_rows)):
+                combined = await self._executor.submit(
+                    shape_key,
+                    KIND_COMBINE,
+                    [[ls, hs] for (_, _, ls, hs) in combine_rows],
+                    backend=backend,
+                    agg_id=1,
+                    task_ident=task_ident,
+                )
+
+            def finish():
+                with trace_phase("helper_init", "finish", "python", rows=len(combine_rows)):
+                    return self._helper_finish_prio3(vdaf, results, combine_rows, combined)
+
+            results = await loop.run_in_executor(None, finish)
         except CircuitOpenError:
             # re-enter past the decode: (results, rows) are already built;
             # any refs the prep submission minted must free first

@@ -25,6 +25,7 @@ from typing import Optional
 from aiohttp import web
 
 from ..core.auth_tokens import DAP_AUTH_HEADER, AuthenticationToken
+from ..core.trace import trace_phase
 from ..datastore.datastore import DatastoreUnavailable
 from ..messages import (
     AggregateShare,
@@ -220,7 +221,9 @@ def aggregator_app(aggregator: Aggregator) -> web.Application:
         resp = await aggregator.handle_aggregate_init(
             task_id, job_id, body, _extract_auth(request)
         )
-        return _wire(resp.get_encoded(), AggregationJobResp.MEDIA_TYPE)
+        with trace_phase("helper_init", "encode_resp", "python"):
+            encoded = resp.get_encoded()
+        return _wire(encoded, AggregationJobResp.MEDIA_TYPE)
 
     @_route
     async def aggregation_job_post(request: web.Request, task_id) -> web.Response:

@@ -443,6 +443,24 @@ class Metrics:
             buckets=_LATENCY_BUCKETS,
             registry=self.registry,
         )
+        # The phase clock (core/trace.py trace_phase / PHASES): one
+        # measurement per boundary inside the served path, per flush, per
+        # job step, per helper request.
+        self.phase_seconds = Histogram(
+            "janus_phase_seconds",
+            "Wall seconds of one phase of the served path (core.trace.PHASES) "
+            "by scope (executor bucket, leader_step, helper_init), phase, kind",
+            ["scope", "phase", "kind"],
+            buckets=_LATENCY_BUCKETS,
+            registry=self.registry,
+        )
+        self.phase_offcpu_seconds = Counter(
+            "janus_phase_offcpu_seconds_total",
+            "Wall minus thread-CPU seconds of kind=python phases: what a "
+            "synchronous Python body waited for the GIL or the scheduler",
+            ["scope", "phase", "kind"],
+            registry=self.registry,
+        )
         self.executor_rejections = Counter(
             "janus_executor_rejections_total",
             "Backpressure rejections by bucket and reason",

@@ -17,6 +17,7 @@ import logging
 import os
 import secrets
 import sys
+import threading
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -230,8 +231,6 @@ class ChromeTracer:
     """
 
     def __init__(self, path: str):
-        import threading
-
         self.path = path
         self._lock = threading.Lock()
         self._closed = False
@@ -275,8 +274,6 @@ class ChromeTracer:
             self._f.flush()
 
     def emit(self, name: str, cat: str, start_s: float, dur_s: float, **args) -> None:
-        import threading
-
         # Concurrent spans must land on distinct tracks: same-track
         # overlapping "X" events render as bogus nesting in trace viewers.
         # Thread identity separates executor/launch spans; same-thread
@@ -448,6 +445,226 @@ def emit_span(name: str, cat: str, start_s: float, dur_s: float, **args) -> None
         t.emit(name, cat, start_s, dur_s, **args)
     elif _SPAN_SINKS:
         _sink_emit(name, cat, start_s, dur_s, dict(args))
+
+
+# -- the phase clock ----------------------------------------------------------
+# One measurement per boundary inside the served path, three outputs of it:
+# the ``janus_phase_*`` families (always), a ``jax.profiler.TraceAnnotation``
+# in the profiler's own clock (processes that already hold jax; recorded
+# only while a profiler session runs, in the same ``.xplane.pb`` as the
+# device ops), and the ``trace_span`` it would be anyway (when chrome/OTLP
+# tracing is configured).  Granularity: per flush, per job step, per helper
+# request — never per report.
+
+#: what a phase's thread is doing: ``python`` (a synchronous body: no
+#: ``await``, no device or socket wait inside — so wall minus thread CPU is
+#: time it waited for the GIL or the scheduler), ``device`` (blocked on the
+#: chip or a transfer), ``queue`` (waiting for a window, a thread, a lock),
+#: ``io`` (datastore tx, peer HTTP).  ``python`` and ``device`` bodies are
+#: synchronous by definition and get the profiler annotation; ``queue`` and
+#: ``io`` phases hold an ``await`` or span threads, and do not.
+PHASE_KINDS = ("python", "device", "queue", "io")
+
+#: THE table: group -> phase -> kind.  ``leader_step`` and ``helper_init``
+#: are their own ``scope`` label; an ``executor`` or ``backend`` phase
+#: carries the executor bucket's label (``Histogram/a0/prep_init#1a2b3c``:
+#: leader/helper and prep_init/combine stay apart), or the backend's own
+#: ``<circuit>/aggregate`` / ``<circuit>/accumulate`` outside a flush.  A
+#: phase that is not here raises.
+PHASES = {
+    "executor": {
+        "window_wait": "queue",  # oldest live submission's enqueue -> dispatch
+        "stage_queue": "queue",  # dispatch -> the stage body starts on janus-exec-stage
+        "stage_wake": "queue",  # stage body done -> the flush's task runs again
+        "launch_queue": "queue",  # stage done -> the launch body starts on janus-exec-launch
+        "launch_wake": "queue",  # launch body done -> the flush's task runs again
+        "resolve": "python",  # bookkeeping, cost attribution, every _resolve
+    },
+    "backend": {
+        "marshal": "python",  # rows -> numpy (limb packing, verify-key stack)
+        "place": "device",  # _place: commit the inputs to the device(s)
+        "dispatch": "python",  # the compiled program's call returns
+        "readback": "device",  # np.asarray of the outputs: device time + D2H
+        "unmarshal": "python",  # numpy -> per-row protocol objects
+        "launch": "device",  # a launch the backend does not split (Poplar1 sketch)
+    },
+    "leader_step": {
+        "load_tx": "io",
+        "decode_rows": "python",
+        "prep_init": "queue",  # executor submit -> result
+        "wrap_outcomes": "python",
+        "encode_req": "python",
+        "helper_http": "io",
+        "process_resp": "python",
+        "commit_shares": "queue",  # resident out shares -> accumulators
+        "write_tx": "io",
+    },
+    "helper_init": {
+        "decode_req": "python",
+        "replay_tx": "io",
+        "conflicts_tx": "io",
+        "validate": "python",
+        "hpke_open": "python",  # the open_batch worker-thread body
+        "decode_shares": "python",
+        "decode_leader_shares": "python",
+        "prep_init": "queue",
+        "combine": "queue",
+        "finish": "python",
+        "assemble": "python",
+        "commit_shares": "queue",
+        "write_tx": "io",
+        "encode_resp": "python",
+    },
+}
+
+_PHASE_LOCAL = threading.local()
+#: (scope, phase) -> seconds of thread CPU read beyond a phase's wall time,
+#: owed to the next observations.  Where the kernel accounts a thread's CPU
+#: by the scheduler's tick (milliseconds: the v5e hosts do), a phase shorter
+#: than a tick reads no CPU at all most of the time and a whole tick now and
+#: then; carrying the excess keeps the counter's long-run sum true, where
+#: clamping each observation at 0 would count short phases as all waiting.
+_OFFCPU_OWED: dict = {}
+_OFFCPU_LOCK = threading.Lock()
+
+
+def phase_group(scope: str, phase: str, kind: str) -> str:
+    """The ``PHASES`` group of one (scope, phase, kind); raises for a
+    triple the table does not hold."""
+    if scope in PHASES:
+        group = scope
+    else:
+        group = "executor" if phase in PHASES["executor"] else "backend"
+    if PHASES[group].get(phase) != kind:
+        raise ValueError(
+            f"phase ({scope!r}, {phase!r}, {kind!r}) is not in core.trace.PHASES"
+        )
+    return group
+
+
+@contextlib.contextmanager
+def phase_scope(scope: str, **args):
+    """Until exit, every phase of THIS thread takes ``scope`` (and carries
+    ``args``) in place of its caller's own, and its seconds add up by
+    phase in the yielded dict.  The executor binds its bucket label — and
+    the flush's ``seq`` — around the backend calls on its stage and launch
+    threads: the backend cannot know either."""
+    prev = getattr(_PHASE_LOCAL, "bound", None)
+    seconds: dict = {}
+    _PHASE_LOCAL.bound = (scope, args, seconds)
+    try:
+        yield seconds
+    finally:
+        _PHASE_LOCAL.bound = prev
+
+
+def _record_phase(group, scope, phase, kind, start_s, end_s, offcpu_s, args, ok=True):
+    """The outputs of one measured phase that are not the annotation."""
+    from .metrics import GLOBAL_METRICS
+
+    seconds = max(0.0, end_s - start_s)
+    if GLOBAL_METRICS.registry is not None:
+        GLOBAL_METRICS.phase_seconds.labels(scope=scope, phase=phase, kind=kind).observe(
+            seconds
+        )
+        if offcpu_s is not None:
+            with _OFFCPU_LOCK:
+                off = _OFFCPU_OWED.get((scope, phase), 0.0) + min(seconds, offcpu_s)
+                _OFFCPU_OWED[scope, phase] = min(off, 0.0)
+            if off > 0.0:
+                GLOBAL_METRICS.phase_offcpu_seconds.labels(
+                    scope=scope, phase=phase, kind=kind
+                ).inc(off)
+    if tracing_active():
+        emit_span(
+            f"janus.{group}.{phase}", "phase", start_s, seconds,
+            scope=scope, kind=kind, ok=ok, **args,
+        )
+    return seconds
+
+
+class _Phase:
+    """One timed phase: ``start``/``end`` (monotonic seconds) and
+    ``seconds`` are readable after exit, so a caller that owes the same
+    interval to an older metric or span feeds it from these stamps."""
+
+    __slots__ = ("scope", "phase", "kind", "args", "group", "start", "end",
+                 "seconds", "_cpu0", "_ann", "_into")
+
+    def __init__(self, scope, phase, kind, args):
+        bound = getattr(_PHASE_LOCAL, "bound", None)
+        self._into = None
+        if bound is not None:
+            scope, args, self._into = bound[0], {**bound[1], **args}, bound[2]
+        self.scope, self.phase, self.kind, self.args = scope, phase, kind, args
+        self.group = phase_group(scope, phase, kind)
+        self.start = self.end = self.seconds = 0.0
+
+    def __enter__(self):
+        self._ann = None
+        if self.kind in ("python", "device"):
+            jax = sys.modules.get("jax")
+            if jax is not None:
+                # ("#" ends the metadata of a profiler event's name, and a
+                # bucket's label holds one before its digest)
+                self._ann = jax.profiler.TraceAnnotation(
+                    f"janus.{self.group}.{self.phase}",
+                    scope=self.scope.replace("#", "@"),
+                    **self.args,
+                )
+                self._ann.__enter__()
+        # the CPU pair lies inside the wall pair: wall >= CPU by construction
+        self.start = time.monotonic()
+        self._cpu0 = time.thread_time() if self.kind == "python" else None
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        cpu = time.thread_time() - self._cpu0 if self._cpu0 is not None else None
+        self.end = time.monotonic()
+        offcpu = None if cpu is None else (self.end - self.start) - cpu
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self.seconds = _record_phase(
+            self.group, self.scope, self.phase, self.kind, self.start, self.end,
+            offcpu, self.args, ok=exc_type is None,
+        )
+        if self._into is not None:
+            self._into[self.phase] = self._into.get(self.phase, 0.0) + self.seconds
+        return False
+
+
+def trace_phase(scope: str, phase: str, kind: str, **args) -> _Phase:
+    """``with trace_phase(scope, phase, kind, rows=...):`` — time one phase
+    of ``PHASES`` on the thread that does the work: one ``time.monotonic()``
+    pair (for ``kind="python"`` one ``time.thread_time()`` pair besides),
+    observed once, also when the body raises.  ``args`` go to the
+    annotation and the span, never to a metric label."""
+    return _Phase(scope, phase, kind, args)
+
+
+def emit_phase(scope: str, phase: str, kind: str, start_s: float, end_s: float, **args) -> float:
+    """A phase whose two stamps were taken apart (a wait that starts on
+    the event loop and ends on a worker thread): the :func:`emit_span` of
+    phases.  Returns its seconds.  No annotation — the profiler takes no
+    interval after the fact; a reader places a wait from the start of the
+    annotation that follows it."""
+    return _record_phase(
+        phase_group(scope, phase, kind), scope, phase, kind, start_s, end_s, None, args
+    )
+
+
+def retire_phase_scope(scope: str) -> None:
+    """Drop every ``janus_phase_*`` series of one retired scope (an idle
+    executor bucket): cardinality follows live traffic, not history."""
+    from .metrics import GLOBAL_METRICS
+
+    if GLOBAL_METRICS.registry is None:
+        return
+    for group in ("executor", "backend"):
+        for phase, kind in PHASES[group].items():
+            _OFFCPU_OWED.pop((scope, phase), None)
+            for metric in (GLOBAL_METRICS.phase_seconds, GLOBAL_METRICS.phase_offcpu_seconds):
+                GLOBAL_METRICS.remove_series(metric, scope, phase, kind)
 
 
 def start_profiler_server(port: int) -> bool:

@@ -1,9 +1,10 @@
 """Executor flight recorder: a black box for the device plane (ISSUE 12).
 
 A bounded in-memory ring of per-flush records — bucket, rows vs padded
-rows, participating tasks, queue delay, stage/launch wall time, outcome,
-breaker state, whether an injected fault fired — kept cheap enough to run
-always-on.  Three read paths:
+rows, participating tasks, queue delay, stage/launch wall time, the flush's
+milliseconds by phase (``phases``: core.trace.PHASES) and its dispatch
+instant on the monotonic clock, outcome, breaker state, whether an injected
+fault fired — kept cheap enough to run always-on.  Three read paths:
 
 * the ``flights`` section of ``/statusz`` (the last N records, newest
   first) — what an operator curls when a soak wedges;
@@ -63,6 +64,13 @@ class FlightRecorder:
         self._last_slow_dump = 0.0
 
     # -- recording -------------------------------------------------------
+    def next_seq(self) -> int:
+        """The record number of a flush that is being dispatched, so that
+        what it stamps on the way (phase annotations) can carry it."""
+        with self._lock:
+            self._seq += 1
+            return self._seq
+
     def record(
         self,
         *,
@@ -79,15 +87,25 @@ class FlightRecorder:
         fault: bool,
         error: Optional[str] = None,
         layout: Optional[str] = None,
+        seq: Optional[int] = None,
+        t_dispatch_s: Optional[float] = None,
+        phases: Optional[Dict[str, float]] = None,
     ) -> Optional[dict]:
         """Append one flush record; returns the record.  Runs the
         slow-flush detector against the bucket's rolling p95 BEFORE this
         flush's own sample joins the window (a single huge flush must not
-        raise the bar it is judged by)."""
+        raise the bar it is judged by).
+
+        ``seq`` is the number :meth:`next_seq` gave the flush at its
+        dispatch (the ring then holds records in order of completion);
+        ``t_dispatch_s`` that instant on the monotonic clock; ``phases``
+        the flush's seconds by phase (core.trace.PHASES)."""
         with self._lock:
-            self._seq += 1
+            if seq is None:
+                self._seq += 1
+                seq = self._seq
             rec = {
-                "seq": self._seq,
+                "seq": seq,
                 "t": round(time.time(), 3),
                 "bucket": bucket,
                 "trigger": trigger,
@@ -101,6 +119,10 @@ class FlightRecorder:
                 "breaker": breaker_state,
                 "fault": fault,
             }
+            if t_dispatch_s is not None:
+                rec["t_dispatch_mono_ns"] = int(t_dispatch_s * 1e9)
+            if phases is not None:
+                rec["phases"] = {k: round(v * 1000.0, 3) for k, v in phases.items()}
             if error:
                 rec["error"] = str(error)[:200]
             if layout:

@@ -49,7 +49,15 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from ..core import costs, faults
-from ..core.trace import current_trace, emit_span
+from ..core.trace import (
+    current_trace,
+    emit_phase,
+    emit_span,
+    phase_scope,
+    retire_phase_scope,
+    trace_phase,
+    trace_span,
+)
 from .flight_recorder import FlightRecorder
 
 logger = logging.getLogger("janus_tpu.executor")
@@ -401,6 +409,21 @@ def mesh_label(backend) -> str:
     return "mesh[%d]#%s" % (len(devs), _shape_digest(devs))
 
 
+def _name_os_thread() -> None:
+    """Give this pool thread's OS name its Python name, as far as Linux
+    takes it (15 bytes: ``janus-exec-stag``, ``janus-exec-laun``).  A
+    profiler trace names a thread's line by the OS name, and Python sets
+    that itself only from 3.14 on: without this the phase annotations of
+    both threads sit on lines called ``python``."""
+    try:
+        import ctypes
+
+        name = threading.current_thread().name.encode()[:15]
+        ctypes.CDLL(None).prctl(15, name, 0, 0, 0)  # PR_SET_NAME
+    except Exception:  # no prctl (not Linux): the trace reads "python"
+        pass
+
+
 class DeviceExecutor:
     """The continuous batcher.  One per process (get_global_executor)."""
 
@@ -571,19 +594,12 @@ class DeviceExecutor:
 
     def _do_warmup(self, shape_key: tuple, backend) -> bool:
         from ..core.metrics import GLOBAL_METRICS
-        from ..core.trace import trace_span
 
         state = self._warmup_state[shape_key]
         label = shape_label(backend, shape_key)
         t0 = time.monotonic()
         try:
-            with trace_span(
-                "compile",
-                cat="executor",
-                shape=label,
-                rows=self.config.warmup_rows,
-            ):
-                n = self.warmup_backend(backend)
+            n = self.warmup_backend(backend)
             dt = time.monotonic() - t0
             state.update(
                 state="warm", compile_s=round(dt, 3), error=None,
@@ -607,6 +623,10 @@ class DeviceExecutor:
             )
             outcome = "error"
             logger.exception("executor warmup failed for %s (serving cold)", label)
+        emit_span(
+            "compile", "executor", t0, dt,
+            shape=label, rows=self.config.warmup_rows, ok=outcome == "ok",
+        )
         if GLOBAL_METRICS.registry is not None:
             GLOBAL_METRICS.executor_warmups.labels(outcome=outcome).inc()
             if outcome == "ok":
@@ -681,10 +701,10 @@ class DeviceExecutor:
         with self._lock:
             if self._stage_pool is None:
                 self._stage_pool = ThreadPoolExecutor(
-                    1, thread_name_prefix="janus-exec-stage"
+                    1, thread_name_prefix="janus-exec-stage", initializer=_name_os_thread
                 )
                 self._launch_pool = ThreadPoolExecutor(
-                    1, thread_name_prefix="janus-exec-launch"
+                    1, thread_name_prefix="janus-exec-launch", initializer=_name_os_thread
                 )
             return self._stage_pool, self._launch_pool
 
@@ -1064,12 +1084,39 @@ class DeviceExecutor:
             await asyncio.gather(*waiters, return_exceptions=True)
 
     # -- the flush -------------------------------------------------------
+    @staticmethod
+    async def _on_pool(pool, fn, scope, phases, half, t_from, note):
+        """Run ``fn`` — the ``half`` ("stage" | "launch") body of a flush —
+        on ``pool``'s one thread, inside the bucket's phase scope, and
+        stamp it ON that thread: ``<half>_queue`` is ``t_from`` -> the body
+        starts (the wait for the thread), ``<half>_wake`` the body's end ->
+        this task runs again, and what the backend timed in between joins
+        ``phases``.  Returns ``(fn's result, the stamp at which this task
+        ran again)``; the caller's own intervals end on that stamp."""
+
+        def body():
+            t_body = time.monotonic()
+            with phase_scope(scope, **note) as inner:
+                out = fn()
+            return out, inner, t_body, time.monotonic()
+
+        out, inner, t_body, t_end = await asyncio.get_running_loop().run_in_executor(
+            pool, body
+        )
+        t_back = time.monotonic()
+        phases[half + "_queue"] = emit_phase(
+            scope, half + "_queue", "queue", t_from, t_body, **note
+        )
+        phases[half + "_wake"] = emit_phase(
+            scope, half + "_wake", "queue", t_end, t_back, **note
+        )
+        for phase, seconds in inner.items():
+            phases[phase] = phases.get(phase, 0.0) + seconds
+        return out, t_back
+
     async def _run_flush(
         self, bucket: _Bucket, subs: List[_Submission], trigger: str
     ) -> None:
-        from ..core.trace import trace_span
-
-        loop = asyncio.get_running_loop()
         live = self._reject_expired(bucket, subs)
         if not live:
             if bucket.breaker is not None:
@@ -1087,6 +1134,29 @@ class DeviceExecutor:
             delay = max(0.0, t_dispatch - s.enqueued)
             queue_delay_max = max(queue_delay_max, delay)
             model.observe_queue_delay(s.task, delay)
+        # The flush's phases (core.trace.PHASES), seconds by name: the
+        # executor's own waits, and the backend's as its stage and launch
+        # bodies time them.  ``seq`` is taken here so that the annotations
+        # on the stage and launch threads carry the flight record's own.
+        seq = self.flight_recorder.next_seq()
+        note = {"seq": seq, "queue_delay_max_ms": round(queue_delay_max * 1000.0, 3)}
+        phases: Dict[str, float] = {
+            "window_wait": emit_phase(
+                bucket.label, "window_wait", "queue",
+                t_dispatch - queue_delay_max, t_dispatch, **note,
+            )
+        }
+        #: what every flight record of this flush says, whatever its outcome
+        flight = dict(
+            seq=seq,
+            t_dispatch_s=t_dispatch,
+            phases=phases,
+            bucket=bucket.label,
+            trigger=trigger,
+            rows=rows,
+            tasks=[model.label_for(s.task) for s in live],
+            queue_delay_max_s=queue_delay_max,
+        )
         stage_s = 0.0
         padded_rows = 0
         layout = None
@@ -1126,13 +1196,13 @@ class DeviceExecutor:
                         retain = self.accumulator
                     t_stage = time.monotonic()
                     warm_pad = self._warm_pad(bucket.key[0])
-                    staged = await loop.run_in_executor(
+                    staged, t_launch = await self._on_pool(
                         stage_pool,
                         lambda: bucket.backend.stage_prep_init_multi(
                             bucket.agg_id, requests, pad_to=warm_pad
                         ),
+                        bucket.label, phases, "stage", t_stage, note,
                     )
-                    t_launch = time.monotonic()
                     stage_s = t_launch - t_stage
                     # pad waste: rows the compiled executable computes and
                     # masks away (pow2 canonicalization + mesh-tail
@@ -1171,7 +1241,9 @@ class DeviceExecutor:
                             still,
                         )
 
-                    outs, still = await loop.run_in_executor(launch_pool, launch)
+                    (outs, still), done = await self._on_pool(
+                        launch_pool, launch, bucket.label, phases, "launch", t_launch, note
+                    )
                 elif bucket.kind == KIND_POPLAR_INIT:
                     # Poplar1 mega-batch: every submission's (verify_key,
                     # agg_param, reports) payload IS a request row for the
@@ -1197,13 +1269,13 @@ class DeviceExecutor:
                     ):
                         retain = self.accumulator
                     t_stage = time.monotonic()
-                    staged = await loop.run_in_executor(
+                    staged, t_launch = await self._on_pool(
                         stage_pool,
                         lambda: bucket.backend.stage_poplar_init_multi(
                             bucket.agg_id, [s.payload for s in live]
                         ),
+                        bucket.label, phases, "stage", t_stage, note,
                     )
-                    t_launch = time.monotonic()
                     stage_s = t_launch - t_stage
 
                     def launch():
@@ -1222,7 +1294,9 @@ class DeviceExecutor:
                             still,
                         )
 
-                    outs, still = await loop.run_in_executor(launch_pool, launch)
+                    (outs, still), done = await self._on_pool(
+                        launch_pool, launch, bucket.label, phases, "launch", t_launch, note
+                    )
                 else:  # KIND_COMBINE: concatenate rows, launch once, slice
                     concat = [row for s in live for row in s.payload]
                     t_launch = time.monotonic()
@@ -1238,19 +1312,17 @@ class DeviceExecutor:
                             start += s.rows
                         return outs, still
 
-                    outs, still = await loop.run_in_executor(launch_pool, launch)
+                    (outs, still), done = await self._on_pool(
+                        launch_pool, launch, bucket.label, phases, "launch", t_launch, note
+                    )
             if outs is None:
                 if bucket.breaker is not None:
                     bucket.breaker.probe_aborted()
                 # every submission expired at the launch dequeue: nothing
                 # touched the device, but the black box still records it
                 self.flight_recorder.record(
-                    bucket=bucket.label,
-                    trigger=trigger,
-                    rows=rows,
+                    **flight,
                     padded_rows=padded_rows,
-                    tasks=[model.label_for(s.task) for s in live],
-                    queue_delay_max_s=queue_delay_max,
                     stage_s=stage_s,
                     launch_s=0.0,
                     outcome="expired",
@@ -1258,62 +1330,63 @@ class DeviceExecutor:
                     fault=False,
                 )
                 return
-            if bucket.breaker is not None:
-                bucket.breaker.record_success()
-            self._note_launch_success(bucket)
-            launch_ok = True
-            done = time.monotonic()
+            # ``done`` is where this task ran again after the launch body:
+            # launch_s ends there, and the resolve phase starts
             launch_s = done - t_launch
-            bucket.flushes += 1
-            bucket.flushed_rows += rows
-            bucket.flushed_jobs += len(live)
-            self._observe_flush(bucket, rows, launch_s)
-            self._observe_pad(bucket, padded_rows)
-            # Per-task cost attribution (ISSUE 12): split the measured
-            # stage/launch durations across the flush's submissions
-            # proportionally by rows.  Conservation: the per-task shares
-            # sum to the measured totals; padding overhead rides with the
-            # rows that caused it.
-            model.attribute_flush(
-                [(s.task, s.rows) for s in live],
-                {"stage": stage_s, "launch": launch_s},
-                path="device",
-            )
-            still_set = set(id(s) for s in still)
-            for s, out in zip(live, outs):
-                if id(s) not in still_set:
-                    # rejected at launch dequeue: its result is dropped, so
-                    # any ResidentRefs minted for its rows must be released
-                    # or the retained flush matrix never frees
-                    if retain is not None and out:
-                        self._release_dropped_refs(retain, out)
-                    continue
-                self._finish(bucket, s, done)
-                self._observe_wait(bucket, done - s.enqueued)
-                model.observe_rows(s.task, "ok", s.rows)
-                # Per-submission CHILD span, stamped with the SUBMITTER's
-                # trace context: one job's merged Perfetto timeline shows
-                # its share of each mega-batch flush (rows of flush_rows),
-                # not just an anonymous executor_flush it cannot claim.
-                emit_span(
-                    "flush_share",
-                    "executor",
-                    t_launch,
-                    launch_s,
-                    bucket=bucket.label,
-                    rows=s.rows,
-                    flush_rows=rows,
-                    trigger=trigger,
-                    **(s.trace_ctx or {}),
+            with trace_phase(bucket.label, "resolve", "python", rows=rows, **note) as resolved:
+                if bucket.breaker is not None:
+                    bucket.breaker.record_success()
+                self._note_launch_success(bucket)
+                launch_ok = True
+                bucket.flushes += 1
+                bucket.flushed_rows += rows
+                bucket.flushed_jobs += len(live)
+                self._observe_flush(bucket, rows, launch_s)
+                self._observe_pad(bucket, padded_rows)
+                # Per-task cost attribution (ISSUE 12): split the measured
+                # stage/launch durations across the flush's submissions
+                # proportionally by rows.  Conservation: the per-task
+                # shares sum to the measured totals; padding overhead
+                # rides with the rows that caused it.
+                model.attribute_flush(
+                    [(s.task, s.rows) for s in live],
+                    {"stage": stage_s, "launch": launch_s},
+                    path="device",
                 )
-                self._resolve(s, result=out)
+                still_set = set(id(s) for s in still)
+                for s, out in zip(live, outs):
+                    if id(s) not in still_set:
+                        # rejected at launch dequeue: its result is
+                        # dropped, so any ResidentRefs minted for its rows
+                        # must be released or the retained flush matrix
+                        # never frees
+                        if retain is not None and out:
+                            self._release_dropped_refs(retain, out)
+                        continue
+                    self._finish(bucket, s, done)
+                    self._observe_wait(bucket, done - s.enqueued)
+                    model.observe_rows(s.task, "ok", s.rows)
+                    # Per-submission CHILD span, stamped with the
+                    # SUBMITTER's trace context: one job's merged Perfetto
+                    # timeline shows its share of each mega-batch flush
+                    # (rows of flush_rows), not just an anonymous
+                    # executor_flush it cannot claim.
+                    emit_span(
+                        "flush_share",
+                        "executor",
+                        t_launch,
+                        launch_s,
+                        bucket=bucket.label,
+                        rows=s.rows,
+                        flush_rows=rows,
+                        trigger=trigger,
+                        **(s.trace_ctx or {}),
+                    )
+                    self._resolve(s, result=out)
+            phases["resolve"] = resolved.seconds
             self.flight_recorder.record(
-                bucket=bucket.label,
-                trigger=trigger,
-                rows=rows,
+                **flight,
                 padded_rows=padded_rows,
-                tasks=[model.label_for(s.task) for s in live],
-                queue_delay_max_s=queue_delay_max,
                 stage_s=stage_s,
                 launch_s=launch_s,
                 outcome="ok",
@@ -1337,16 +1410,7 @@ class DeviceExecutor:
                 # assert transient faults heal via retry/breaker, and
                 # bisecting them would quarantine healthy reports.
                 if await self._bisect_failed_flush(
-                    bucket,
-                    live,
-                    e,
-                    trigger,
-                    rows,
-                    padded_rows,
-                    queue_delay_max,
-                    model,
-                    stage_s,
-                    t_launch,
+                    bucket, live, e, flight, padded_rows, model, stage_s, t_launch
                 ):
                     return
                 done = time.monotonic()
@@ -1367,12 +1431,8 @@ class DeviceExecutor:
                     if not s.finished:
                         model.observe_rows(s.task, "error", s.rows)
                 self.flight_recorder.record(
-                    bucket=bucket.label,
-                    trigger=trigger,
-                    rows=rows,
+                    **flight,
                     padded_rows=padded_rows,
-                    tasks=[model.label_for(s.task) for s in live],
-                    queue_delay_max_s=queue_delay_max,
                     stage_s=stage_s,
                     launch_s=launch_s,
                     outcome="error",
@@ -1397,10 +1457,8 @@ class DeviceExecutor:
         bucket: _Bucket,
         live: List[_Submission],
         exc: Exception,
-        trigger: str,
-        rows: int,
+        flight: dict,
         padded_rows: int,
-        queue_delay_max: float,
         model,
         stage_s: float,
         t_launch: float,
@@ -1425,6 +1483,7 @@ class DeviceExecutor:
         """
         from ..core import quarantine
 
+        rows = flight["rows"]
         items: List[tuple] = []
         if bucket.kind == KIND_PREP_INIT:
             for si, s in enumerate(live):
@@ -1542,12 +1601,8 @@ class DeviceExecutor:
             self._observe_wait(bucket, done - s.enqueued)
             self._resolve(s, result=per_sub[si])
         self.flight_recorder.record(
-            bucket=bucket.label,
-            trigger=trigger,
-            rows=rows,
+            **flight,
             padded_rows=padded_rows,
-            tasks=[model.label_for(s.task) for s in live],
-            queue_delay_max_s=queue_delay_max,
             stage_s=stage_s,
             launch_s=launch_s,
             outcome="bisected",
@@ -1870,6 +1925,7 @@ class DeviceExecutor:
                         GLOBAL_METRICS.remove_series(
                             GLOBAL_METRICS.executor_rejections, label, reason
                         )
+                    retire_phase_scope(label)
                 for label in retired_circuits:
                     GLOBAL_METRICS.remove_series(
                         GLOBAL_METRICS.circuit_state, label

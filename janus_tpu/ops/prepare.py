@@ -42,6 +42,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -69,6 +70,13 @@ from .keccak_jax import bytes_to_words, words_to_bytes, xof_turboshake128_batch
 from .xof_jax import xof_next_vec_batch
 
 _U32 = jnp.uint32
+
+
+#: Names on the device ops of the prepare programs: a profiler trace reads
+#: ``xof.query_rand/while.26`` where it read ``while.26``.  Metadata only:
+#: the lowered program is op for op what it is without them
+#: (tests/test_phases.py compares the text).
+_scope = jax.named_scope
 
 
 def limbs_to_bytes(limbs: jnp.ndarray) -> jnp.ndarray:
@@ -769,15 +777,17 @@ class BatchedPrio3:
         binder = jnp.broadcast_to(
             jnp.asarray(np.array([agg_id], dtype=np.uint8)), (B, 1)
         )
-        meas, ok1 = self._expand_vec(
-            share_seeds_u8, self._dst(USAGE_MEAS_SHARE), binder, self.flp.MEAS_LEN
-        )
-        proofs, ok2 = self._expand_vec(
-            share_seeds_u8,
-            self._dst(USAGE_PROOF_SHARE),
-            binder,
-            self.flp.PROOF_LEN * self.prio3.num_proofs,
-        )
+        with _scope("xof.expand_meas"):
+            meas, ok1 = self._expand_vec(
+                share_seeds_u8, self._dst(USAGE_MEAS_SHARE), binder, self.flp.MEAS_LEN
+            )
+        with _scope("xof.expand_proof"):
+            proofs, ok2 = self._expand_vec(
+                share_seeds_u8,
+                self._dst(USAGE_PROOF_SHARE),
+                binder,
+                self.flp.PROOF_LEN * self.prio3.num_proofs,
+            )
         return meas, proofs, ok1 & ok2
 
     def _lagrange_coeffs(self, t_m, gi: int = 0):
@@ -892,7 +902,8 @@ class BatchedPrio3:
             gpoly = proof_m[:, idx + plan.arity : idx + plan.arity + plan.glen]
             idx += plan.arity + plan.glen
 
-            gk = self._gadget_outputs(gpoly, B, gi=gi)  # (B, calls_g, n)
+            with _scope("flp.gadget_eval"):
+                gk = self._gadget_outputs(gpoly, B, gi=gi)  # (B, calls_g, n)
             cl = calls_live[gi] if calls_live is not None else None
             if cl is not None:
                 k = jnp.arange(plan.calls, dtype=jnp.int32)[None, :]
@@ -902,22 +913,27 @@ class BatchedPrio3:
             # Wire evaluations at t_g via barycentric Lagrange on the
             # gadget's own P-th roots.
             t_g = t_m[:, gi]
-            lag, t_ok = self._lagrange_coeffs(t_g, gi=gi)
+            with _scope("flp.wire_evals"):
+                lag, t_ok = self._lagrange_coeffs(t_g, gi=gi)
             ok = ok & t_ok
             if cl is not None:
                 k = jnp.arange(plan.calls + 1, dtype=jnp.int32)[None, :]
                 lag = jnp.where((k <= cl[:, None])[:, :, None], lag, 0)
-            wire_evals = circ.wire_evals_g(
-                gi, jf, meas_m, jr_m, lag, seeds, self.consts, ml=ml
-            )
-            gp_t = self._gpoly_at(gpoly, t_g)  # (B, n)
+            with _scope("flp.wire_evals"):
+                wire_evals = circ.wire_evals_g(
+                    gi, jf, meas_m, jr_m, lag, seeds, self.consts, ml=ml
+                )
+            with _scope("flp.gadget_eval"):
+                gp_t = self._gpoly_at(gpoly, t_g)  # (B, n)
             segs.append((wire_evals, gp_t))
 
-        v = circ.v_multi(jf, gks, meas_m, jr_m, self.consts, ml=ml)  # (B, n)
+        with _scope("flp.gadget_eval"):
+            v = circ.v_multi(jf, gks, meas_m, jr_m, self.consts, ml=ml)  # (B, n)
         parts = [v[:, None]]
         for wire_evals, gp_t in segs:
             parts.extend([wire_evals, gp_t[:, None]])
-        verifier = jnp.concatenate(parts, axis=1)  # (B, VERIFIER_LEN, n)
+        with _scope("verifier.pack"):
+            verifier = jnp.concatenate(parts, axis=1)  # (B, VERIFIER_LEN, n)
         return verifier, ok
 
     # -- prep init ------------------------------------------------------
@@ -973,59 +989,61 @@ class BatchedPrio3:
         if isinstance(verify_key, (bytes, bytearray)):
             verify_key = jnp.asarray(np.frombuffer(bytes(verify_key), dtype=np.uint8))
         vk = jnp.broadcast_to(verify_key, (B, verify_key.shape[-1]))
-        qr, ok_q = self._expand_vec(
-            vk,
-            self._dst(USAGE_QUERY_RANDOMNESS),
-            nonces_u8,
-            flp.QUERY_RAND_LEN * prio3.num_proofs,
-        )
+        with _scope("xof.query_rand"):
+            qr, ok_q = self._expand_vec(
+                vk,
+                self._dst(USAGE_QUERY_RANDOMNESS),
+                nonces_u8,
+                flp.QUERY_RAND_LEN * prio3.num_proofs,
+            )
         ok = ok & ok_q
 
         out: Dict[str, jnp.ndarray] = {}
         jr = None
         if flp.JOINT_RAND_LEN > 0:
-            # joint_rand_part = XOF(blind, dst, agg_id || nonce || enc(meas))
-            agg_b = jnp.broadcast_to(
-                jnp.asarray(np.array([agg_id], dtype=np.uint8)), (B, 1)
-            )
-            meas_bytes = limbs_to_bytes(meas)
-            part_binder = jnp.concatenate([agg_b, nonces_u8, meas_bytes], axis=-1)
-            if ml is None:
-                part = self._xof_seed(
-                    blinds_u8, self._dst(USAGE_JOINT_RAND_PART), part_binder
+            with _scope("xof.joint_rand"):
+                # joint_rand_part = XOF(blind, dst, agg_id || nonce || enc(meas))
+                agg_b = jnp.broadcast_to(
+                    jnp.asarray(np.array([agg_id], dtype=np.uint8)), (B, 1)
                 )
-            else:
-                # Canonical padding: the binder embeds enc(meas), whose true
-                # byte length is per-row — absorb with the length-selected
-                # sponge (the padded tail bytes are zero by the mask above,
-                # which the select absorb's pad construction requires).
-                from .keccak_jax import xof_turboshake128_batch_select
+                meas_bytes = limbs_to_bytes(meas)
+                part_binder = jnp.concatenate([agg_b, nonces_u8, meas_bytes], axis=-1)
+                if ml is None:
+                    part = self._xof_seed(
+                        blinds_u8, self._dst(USAGE_JOINT_RAND_PART), part_binder
+                    )
+                else:
+                    # Canonical padding: the binder embeds enc(meas), whose true
+                    # byte length is per-row — absorb with the length-selected
+                    # sponge (the padded tail bytes are zero by the mask above,
+                    # which the select absorb's pad construction requires).
+                    from .keccak_jax import xof_turboshake128_batch_select
 
-                binder_len = 1 + nonces_u8.shape[-1] + ml * (4 * jf.n)
-                part = xof_turboshake128_batch_select(
-                    blinds_u8,
-                    self._dst(USAGE_JOINT_RAND_PART),
-                    part_binder,
-                    prio3.xof.SEED_SIZE,
-                    binder_len,
+                    binder_len = 1 + nonces_u8.shape[-1] + ml * (4 * jf.n)
+                    part = xof_turboshake128_batch_select(
+                        blinds_u8,
+                        self._dst(USAGE_JOINT_RAND_PART),
+                        part_binder,
+                        prio3.xof.SEED_SIZE,
+                        binder_len,
+                    )
+                # corrected joint rand seed over parts with ours substituted.
+                S = prio3.num_shares
+                pieces = []
+                if agg_id > 0:
+                    pieces.append(public_parts_u8[:, :agg_id].reshape(B, -1))
+                pieces.append(part)
+                if agg_id < S - 1:
+                    pieces.append(public_parts_u8[:, agg_id + 1 :].reshape(B, -1))
+                seed_binder = jnp.concatenate(pieces, axis=-1)
+                zero_seed = jnp.zeros((B, prio3.xof.SEED_SIZE), dtype=jnp.uint8)
+                corrected = self._xof_seed(zero_seed, self._dst(USAGE_JOINT_RAND_SEED), seed_binder)
+                jr_vec, ok_j = self._expand_vec(
+                    corrected,
+                    self._dst(USAGE_JOINT_RANDOMNESS),
+                    jnp.zeros((B, 0), dtype=jnp.uint8),
+                    flp.JOINT_RAND_LEN * prio3.num_proofs,
                 )
-            # corrected joint rand seed over parts with ours substituted.
-            S = prio3.num_shares
-            pieces = []
-            if agg_id > 0:
-                pieces.append(public_parts_u8[:, :agg_id].reshape(B, -1))
-            pieces.append(part)
-            if agg_id < S - 1:
-                pieces.append(public_parts_u8[:, agg_id + 1 :].reshape(B, -1))
-            seed_binder = jnp.concatenate(pieces, axis=-1)
-            zero_seed = jnp.zeros((B, prio3.xof.SEED_SIZE), dtype=jnp.uint8)
-            corrected = self._xof_seed(zero_seed, self._dst(USAGE_JOINT_RAND_SEED), seed_binder)
-            jr_vec, ok_j = self._expand_vec(
-                corrected,
-                self._dst(USAGE_JOINT_RANDOMNESS),
-                jnp.zeros((B, 0), dtype=jnp.uint8),
-                flp.JOINT_RAND_LEN * prio3.num_proofs,
-            )
             ok = ok & ok_j
             jr = jr_vec
             out["joint_rand_part"] = part
@@ -1054,8 +1072,10 @@ class BatchedPrio3:
             ok = ok & t_ok
             verifiers.append(ver)
 
-        out["verifiers"] = jnp.concatenate(verifiers, axis=1)
-        out["out_share"] = self.circ.truncate(jf, meas, self.consts, ml=ml)
+        with _scope("verifier.pack"):
+            out["verifiers"] = jnp.concatenate(verifiers, axis=1)
+        with _scope("flp.truncate"):
+            out["out_share"] = self.circ.truncate(jf, meas, self.consts, ml=ml)
         out["ok"] = ok
         return out
 
@@ -1507,12 +1527,14 @@ class BatchedPrio3:
             binder = jnp.broadcast_to(
                 jnp.asarray(np.array([agg_id], dtype=np.uint8)), (B, 1)
             )
-            meas_st = xof_planes_pallas(
-                share_seeds_u8, self._dst(USAGE_MEAS_SHARE), binder, flp.MEAS_LEN * n
-            )  # (MEAS_LEN*n, R, 128)
-            proofs_st = xof_planes_pallas(
-                share_seeds_u8, self._dst(USAGE_PROOF_SHARE), binder, flp.PROOF_LEN * n
-            )
+            with _scope("xof.expand_meas"):
+                meas_st = xof_planes_pallas(
+                    share_seeds_u8, self._dst(USAGE_MEAS_SHARE), binder, flp.MEAS_LEN * n
+                )  # (MEAS_LEN*n, R, 128)
+            with _scope("xof.expand_proof"):
+                proofs_st = xof_planes_pallas(
+                    share_seeds_u8, self._dst(USAGE_PROOF_SHARE), binder, flp.PROOF_LEN * n
+                )
             ok = self._planar_ok(meas_st, flp.MEAS_LEN) & self._planar_ok(
                 proofs_st, flp.PROOF_LEN
             )
@@ -1531,33 +1553,35 @@ class BatchedPrio3:
             p_el[circ.arity :].transpose(2, 3, 0, 1).reshape(B, circ.glen, n)
         )  # small row-major
 
-        # Joint randomness: part from the in-plane absorb, the rest row-major.
-        part_planes = self._jr_part_planes(agg_id, blinds_u8, nonces_u8, meas_st)
-        from .keccak_pallas import planes_to_rows
+        with _scope("xof.joint_rand"):
+            # Joint randomness: part from the in-plane absorb, the rest row-major.
+            part_planes = self._jr_part_planes(agg_id, blinds_u8, nonces_u8, meas_st)
+            from .keccak_pallas import planes_to_rows
 
-        part = words_to_bytes(planes_to_rows(part_planes))  # (B, SEED)
-        S = prio3.num_shares
-        pieces = []
-        if agg_id > 0:
-            pieces.append(public_parts_u8[:, :agg_id].reshape(B, -1))
-        pieces.append(part)
-        if agg_id < S - 1:
-            pieces.append(public_parts_u8[:, agg_id + 1 :].reshape(B, -1))
-        seed_binder = jnp.concatenate(pieces, axis=-1)
-        zero_seed = jnp.zeros((B, prio3.xof.SEED_SIZE), dtype=jnp.uint8)
-        corrected = self._xof_seed(zero_seed, self._dst(USAGE_JOINT_RAND_SEED), seed_binder)
-        jr_vec, ok_j = self._expand_vec(
-            corrected,
-            self._dst(USAGE_JOINT_RANDOMNESS),
-            jnp.zeros((B, 0), dtype=jnp.uint8),
-            flp.JOINT_RAND_LEN,
-        )
+            part = words_to_bytes(planes_to_rows(part_planes))  # (B, SEED)
+            S = prio3.num_shares
+            pieces = []
+            if agg_id > 0:
+                pieces.append(public_parts_u8[:, :agg_id].reshape(B, -1))
+            pieces.append(part)
+            if agg_id < S - 1:
+                pieces.append(public_parts_u8[:, agg_id + 1 :].reshape(B, -1))
+            seed_binder = jnp.concatenate(pieces, axis=-1)
+            zero_seed = jnp.zeros((B, prio3.xof.SEED_SIZE), dtype=jnp.uint8)
+            corrected = self._xof_seed(zero_seed, self._dst(USAGE_JOINT_RAND_SEED), seed_binder)
+            jr_vec, ok_j = self._expand_vec(
+                corrected,
+                self._dst(USAGE_JOINT_RANDOMNESS),
+                jnp.zeros((B, 0), dtype=jnp.uint8),
+                flp.JOINT_RAND_LEN,
+            )
         if isinstance(verify_key, (bytes, bytearray)):
             verify_key = jnp.asarray(np.frombuffer(bytes(verify_key), dtype=np.uint8))
         vk = jnp.broadcast_to(verify_key, (B, verify_key.shape[-1]))
-        qr, ok_q = self._expand_vec(
-            vk, self._dst(USAGE_QUERY_RANDOMNESS), nonces_u8, flp.QUERY_RAND_LEN
-        )
+        with _scope("xof.query_rand"):
+            qr, ok_q = self._expand_vec(
+                vk, self._dst(USAGE_QUERY_RANDOMNESS), nonces_u8, flp.QUERY_RAND_LEN
+            )
         ok = ok & ok_j & ok_q
 
         jr_m = jf.to_mont(jr_vec)
@@ -1575,29 +1599,31 @@ class BatchedPrio3:
             rch_pl, kl_pl, lagk_pl, lag0_pl, ccorr_pl = self._histogram_coeff_planes(
                 jr_m, lag_pl, NJc * UCc
             )
-            ev_pl, od_pl = wire_evals_planar(
-                jf,
-                flp.MEAS_LEN,
-                circ.chunk,
-                m_lp,
-                p_lp,
-                rch_pl,
-                kl_pl,
-                lagk_pl,
-                lag0_pl,
-                ccorr_pl,
-                interpret=_pallas_interpret(),
-            )  # each (R, n, chunk, 128)
+            with _scope("flp.wire_evals"):
+                ev_pl, od_pl = wire_evals_planar(
+                    jf,
+                    flp.MEAS_LEN,
+                    circ.chunk,
+                    m_lp,
+                    p_lp,
+                    rch_pl,
+                    kl_pl,
+                    lagk_pl,
+                    lag0_pl,
+                    ccorr_pl,
+                    interpret=_pallas_interpret(),
+                )  # each (R, n, chunk, 128)
             # Gadget polynomial: planar direct-sum evaluation (no glen-step
             # row-major Horner chain); gk back to rows only for the tiny
             # (B, calls, n) v computation.
             gp = [p_lp[:, l, circ.arity :] for l in range(n)]  # (R, glen, 128)
-            gk = (
-                jnp.stack(self._gadget_outputs_planes(gp), axis=1)
-                .transpose(0, 3, 2, 1)
-                .reshape(B, circ.calls, n)
-            )
-            gpt_limbs = self._gpoly_at_planes(gp, t_pl)
+            with _scope("flp.gadget_eval"):
+                gk = (
+                    jnp.stack(self._gadget_outputs_planes(gp), axis=1)
+                    .transpose(0, 3, 2, 1)
+                    .reshape(B, circ.calls, n)
+                )
+                gpt_limbs = self._gpoly_at_planes(gp, t_pl)
             gp_t = (
                 jnp.stack(gpt_limbs, axis=1).transpose(0, 2, 1).reshape(B, n)
             )
@@ -1650,7 +1676,8 @@ class BatchedPrio3:
             return out
         if ev_pl is not None:
             wire = self._zip_planes_to_rows(ev_pl, od_pl)[:, : circ.arity]
-        out["verifiers"] = jnp.concatenate([v[:, None], wire, gp_t[:, None]], axis=1)
+        with _scope("verifier.pack"):
+            out["verifiers"] = jnp.concatenate([v[:, None], wire, gp_t[:, None]], axis=1)
         return out
 
     def _stream_to_limb_planes(self, stream, num_elems):
@@ -1702,12 +1729,14 @@ class BatchedPrio3:
             binder = jnp.broadcast_to(
                 jnp.asarray(np.array([agg_id], dtype=np.uint8)), (B, 1)
             )
-            meas_st = xof_planes_pallas(
-                share_seeds_u8, self._dst(USAGE_MEAS_SHARE), binder, flp.MEAS_LEN * n
-            )
-            proofs_st = xof_planes_pallas(
-                share_seeds_u8, self._dst(USAGE_PROOF_SHARE), binder, flp.PROOF_LEN * n
-            )
+            with _scope("xof.expand_meas"):
+                meas_st = xof_planes_pallas(
+                    share_seeds_u8, self._dst(USAGE_MEAS_SHARE), binder, flp.MEAS_LEN * n
+                )
+            with _scope("xof.expand_proof"):
+                proofs_st = xof_planes_pallas(
+                    share_seeds_u8, self._dst(USAGE_PROOF_SHARE), binder, flp.PROOF_LEN * n
+                )
             ok = self._planar_ok(meas_st, flp.MEAS_LEN) & self._planar_ok(
                 proofs_st, flp.PROOF_LEN
             )
@@ -1751,9 +1780,10 @@ class BatchedPrio3:
         if isinstance(verify_key, (bytes, bytearray)):
             verify_key = jnp.asarray(np.frombuffer(bytes(verify_key), dtype=np.uint8))
         vk = jnp.broadcast_to(verify_key, (B, verify_key.shape[-1]))
-        qr, ok_q = self._expand_vec(
-            vk, self._dst(USAGE_QUERY_RANDOMNESS), nonces_u8, flp.QUERY_RAND_LEN
-        )
+        with _scope("xof.query_rand"):
+            qr, ok_q = self._expand_vec(
+                vk, self._dst(USAGE_QUERY_RANDOMNESS), nonces_u8, flp.QUERY_RAND_LEN
+            )
         ok = ok & ok_q
         t_m = jf.to_mont(qr[:, 0])
         t_planes = self._rows_to_planes_small(t_m[:, None, :])[:, :, 0]
@@ -1763,78 +1793,83 @@ class BatchedPrio3:
         lag0 = [lag_pl[:, l, 0] for l in range(n)]
         lagk = [lag_pl[:, l, 1:] for l in range(n)]  # (R, calls, 128)
 
-        # gadget outputs gk at alpha^1..alpha^calls
-        if self._ntt is not None:
-            P = circ.P
-            folded = [
-                jf.add_limbs(
-                    [x[:, :P] for x in gp],
-                    [
-                        jnp.concatenate(
-                            [
-                                x[:, P:],
-                                jnp.zeros(
-                                    (R, 2 * P - circ.glen, 128), dtype=_U32
-                                ),
-                            ],
-                            axis=1,
-                        )
-                        for x in gp
-                    ],
-                )[l]
-                for l in range(n)
-            ]
-            evals = jf.ntt_eval_mont_limbs(folded, *self._ntt)
-            gk = [e[:, 1 : circ.calls + 1] for e in evals]
-        else:
-            gk = self._gadget_outputs_planes(gp)  # (R, calls, 128)
-
-        if isinstance(circ, _DCount):
-            # v = gk[0] - m[0]; wires w0 = w1 = sw_i*lag0 + m0*lag1
-            v = jf.sub_limbs(
-                [g[:, 0] for g in gk], [x[:, 0] for x in m]
-            )
-            m0lag1 = jf.mont_mul_limbs(
-                [x[:, 0] for x in m], [lk[:, 0] for lk in lagk]
-            )
-            wires = []
-            for i in range(2):
-                se = jf.mont_mul_limbs([x[:, i] for x in sw], lag0)
-                wires.append(jf.add_limbs(se, m0lag1))
-        else:  # _DSum
-            # v = sum_k r^(k+1) * gk[k]
-            r_pows = self._pow_range_planes(jr_pl, circ.calls)  # (R, calls, 128)
-            v = self._sum_planes(jf.mont_mul_limbs(r_pows, gk))
-            # single wire: sw0*lag0 + sum_k m[k]*lag_{k+1}
-            se = jf.mont_mul_limbs([x[:, 0] for x in sw], lag0)
-            wires = [
-                jf.add_limbs(se, self._sum_planes(jf.mont_mul_limbs(m, lagk)))
-            ]
-
-        gpt = self._gpoly_at_planes(gp, t_pl)
-
-        # verifier rows (B, VERIFIER_LEN, n): tiny stack + transpose
-        cols = [v] + wires + [gpt]  # each: n x (R, 128)
-        ver_pl = jnp.stack(
-            [jnp.stack([col[l] for col in cols], axis=1) for l in range(n)],
-            axis=1,
-        )  # (R, n, VER, 128)
-        out["verifiers"] = ver_pl.transpose(0, 3, 2, 1).reshape(B, len(cols), n)
-
-        # out_share planar (R, n, OUTPUT_LEN, 128)
-        if isinstance(circ, _DCount):
-            osh = [x[:, 0:1] for x in m]
-        else:
-            w = self.consts["pow2_m"]  # (bits, n) Montgomery
-            terms = jf.mont_mul_limbs(
-                m,
-                [
-                    jnp.broadcast_to(w[:, l][None, :, None], (R, circ.calls, 128))
+        with _scope("flp.gadget_eval"):
+            # gadget outputs gk at alpha^1..alpha^calls
+            if self._ntt is not None:
+                P = circ.P
+                folded = [
+                    jf.add_limbs(
+                        [x[:, :P] for x in gp],
+                        [
+                            jnp.concatenate(
+                                [
+                                    x[:, P:],
+                                    jnp.zeros(
+                                        (R, 2 * P - circ.glen, 128), dtype=_U32
+                                    ),
+                                ],
+                                axis=1,
+                            )
+                            for x in gp
+                        ],
+                    )[l]
                     for l in range(n)
-                ],
-            )
-            osh = [a[:, None, :] for a in self._sum_planes(terms)]
-        out["out_share"] = jnp.stack(osh, axis=1)  # (R, n, OUT, 128)
+                ]
+                evals = jf.ntt_eval_mont_limbs(folded, *self._ntt)
+                gk = [e[:, 1 : circ.calls + 1] for e in evals]
+            else:
+                gk = self._gadget_outputs_planes(gp)  # (R, calls, 128)
+
+        with _scope("flp.wire_evals"):
+            if isinstance(circ, _DCount):
+                # v = gk[0] - m[0]; wires w0 = w1 = sw_i*lag0 + m0*lag1
+                v = jf.sub_limbs(
+                    [g[:, 0] for g in gk], [x[:, 0] for x in m]
+                )
+                m0lag1 = jf.mont_mul_limbs(
+                    [x[:, 0] for x in m], [lk[:, 0] for lk in lagk]
+                )
+                wires = []
+                for i in range(2):
+                    se = jf.mont_mul_limbs([x[:, i] for x in sw], lag0)
+                    wires.append(jf.add_limbs(se, m0lag1))
+            else:  # _DSum
+                # v = sum_k r^(k+1) * gk[k]
+                r_pows = self._pow_range_planes(jr_pl, circ.calls)  # (R, calls, 128)
+                v = self._sum_planes(jf.mont_mul_limbs(r_pows, gk))
+                # single wire: sw0*lag0 + sum_k m[k]*lag_{k+1}
+                se = jf.mont_mul_limbs([x[:, 0] for x in sw], lag0)
+                wires = [
+                    jf.add_limbs(se, self._sum_planes(jf.mont_mul_limbs(m, lagk)))
+                ]
+
+        with _scope("flp.gadget_eval"):
+            gpt = self._gpoly_at_planes(gp, t_pl)
+
+        with _scope("verifier.pack"):
+            # verifier rows (B, VERIFIER_LEN, n): tiny stack + transpose
+            cols = [v] + wires + [gpt]  # each: n x (R, 128)
+            ver_pl = jnp.stack(
+                [jnp.stack([col[l] for col in cols], axis=1) for l in range(n)],
+                axis=1,
+            )  # (R, n, VER, 128)
+            out["verifiers"] = ver_pl.transpose(0, 3, 2, 1).reshape(B, len(cols), n)
+
+        with _scope("flp.truncate"):
+            # out_share planar (R, n, OUTPUT_LEN, 128)
+            if isinstance(circ, _DCount):
+                osh = [x[:, 0:1] for x in m]
+            else:
+                w = self.consts["pow2_m"]  # (bits, n) Montgomery
+                terms = jf.mont_mul_limbs(
+                    m,
+                    [
+                        jnp.broadcast_to(w[:, l][None, :, None], (R, circ.calls, 128))
+                        for l in range(n)
+                    ],
+                )
+                osh = [a[:, None, :] for a in self._sum_planes(terms)]
+            out["out_share"] = jnp.stack(osh, axis=1)  # (R, n, OUT, 128)
         out["ok"] = ok
         return out
 
@@ -1951,30 +1986,32 @@ class BatchedPrio3:
         Oracle twin: Prio3.prep_shares_to_prep.
         """
         prio3, flp, jf, circ = self.prio3, self.flp, self.jf, self.circ
-        combined = verifier_shares[0]
-        for vs in verifier_shares[1:]:
-            combined = jf.add(combined, vs)
-        B = combined.shape[0]
-        decide = jnp.ones((B,), dtype=bool)
-        for i in range(prio3.num_proofs):
-            ver = combined[:, i * flp.VERIFIER_LEN : (i + 1) * flp.VERIFIER_LEN]
-            decide = decide & jf.is_zero(ver[:, 0])
-            idx = 1
-            for gi, plan in enumerate(circ.plans):
-                x = ver[:, idx : idx + plan.arity]  # canonical wire evals
-                # Compare g*R^-1 == y*R^-1 (R invertible => same predicate
-                # as g == y) to skip the to_mont pass over the arity wires.
-                y_scaled = jf.from_mont(ver[:, idx + plan.arity])
-                g = circ.gadget_eval_scaled_g(gi, jf, x)
-                decide = decide & jf.eq(g, y_scaled)
-                idx += plan.arity + 1
+        with _scope("combine.decide"):
+            combined = verifier_shares[0]
+            for vs in verifier_shares[1:]:
+                combined = jf.add(combined, vs)
+            B = combined.shape[0]
+            decide = jnp.ones((B,), dtype=bool)
+            for i in range(prio3.num_proofs):
+                ver = combined[:, i * flp.VERIFIER_LEN : (i + 1) * flp.VERIFIER_LEN]
+                decide = decide & jf.is_zero(ver[:, 0])
+                idx = 1
+                for gi, plan in enumerate(circ.plans):
+                    x = ver[:, idx : idx + plan.arity]  # canonical wire evals
+                    # Compare g*R^-1 == y*R^-1 (R invertible => same predicate
+                    # as g == y) to skip the to_mont pass over the arity wires.
+                    y_scaled = jf.from_mont(ver[:, idx + plan.arity])
+                    g = circ.gadget_eval_scaled_g(gi, jf, x)
+                    decide = decide & jf.eq(g, y_scaled)
+                    idx += plan.arity + 1
         out: Dict[str, jnp.ndarray] = {"decide": decide}
         if flp.JOINT_RAND_LEN > 0:
             binder = jnp.concatenate(list(joint_rand_parts_u8), axis=-1)
             zero_seed = jnp.zeros((B, prio3.xof.SEED_SIZE), dtype=jnp.uint8)
-            out["prep_msg_seed"] = self._xof_seed(
-                zero_seed, self._dst(USAGE_JOINT_RAND_SEED), binder
-            )
+            with _scope("xof.joint_rand"):
+                out["prep_msg_seed"] = self._xof_seed(
+                    zero_seed, self._dst(USAGE_JOINT_RAND_SEED), binder
+                )
         return out
 
     def prep_shares_to_prep_planar(
@@ -2003,10 +2040,11 @@ class BatchedPrio3:
         # One transpose puts the peer's whole verifier in plane layout; the
         # kernel de-interleaves its zipped wires in-register.
         pv_pl = self._rows_to_planes_small(peer_verifiers)
-        g_parts = combine_decide_planar(
-            jf, circ.chunk, ev_pl, od_pl, pv_pl,
-            interpret=_pallas_interpret(),
-        )  # (R, n, 8, 128) partial sums
+        with _scope("combine.decide"):
+            g_parts = combine_decide_planar(
+                jf, circ.chunk, ev_pl, od_pl, pv_pl,
+                interpret=_pallas_interpret(),
+            )  # (R, n, 8, 128) partial sums
         R, n, S8, _ = g_parts.shape
         g = jf.sum(g_parts.transpose(0, 3, 2, 1).reshape(B, S8, n), axis=1)
 
@@ -2019,9 +2057,10 @@ class BatchedPrio3:
         if flp.JOINT_RAND_LEN > 0:
             binder = jnp.concatenate(list(joint_rand_parts_u8), axis=-1)
             zero_seed = jnp.zeros((B, prio3.xof.SEED_SIZE), dtype=jnp.uint8)
-            out["prep_msg_seed"] = self._xof_seed(
-                zero_seed, self._dst(USAGE_JOINT_RAND_SEED), binder
-            )
+            with _scope("xof.joint_rand"):
+                out["prep_msg_seed"] = self._xof_seed(
+                    zero_seed, self._dst(USAGE_JOINT_RAND_SEED), binder
+                )
         return out
 
     # -- aggregation -----------------------------------------------------
@@ -2035,13 +2074,15 @@ class BatchedPrio3:
         aggregator/src/aggregator/aggregation_job_writer.rs:591-698).
         """
         if out_shares.ndim == 4:  # planar (R, n, L, 128): lazy u16 lane reduce
-            R, n, L, _ = out_shares.shape
-            maskp = mask.reshape(R, 128)
-            masked = jnp.where(
-                maskp[:, None, None], out_shares, jnp.zeros_like(out_shares)
-            )
-            slo = jnp.sum(masked & np.uint32(0xFFFF), axis=(0, 3))  # (n, L)
-            shi = jnp.sum(masked >> 16, axis=(0, 3))
-            return self.jf.lazy_fold(slo.T, shi.T)
-        masked = jnp.where(mask[:, None, None], out_shares, jnp.zeros_like(out_shares))
-        return self.jf.sum(masked, axis=0)
+            with _scope("aggregate.sum"):
+                R, n, L, _ = out_shares.shape
+                maskp = mask.reshape(R, 128)
+                masked = jnp.where(
+                    maskp[:, None, None], out_shares, jnp.zeros_like(out_shares)
+                )
+                slo = jnp.sum(masked & np.uint32(0xFFFF), axis=(0, 3))  # (n, L)
+                shi = jnp.sum(masked >> 16, axis=(0, 3))
+                return self.jf.lazy_fold(slo.T, shi.T)
+        with _scope("aggregate.sum"):
+            masked = jnp.where(mask[:, None, None], out_shares, jnp.zeros_like(out_shares))
+            return self.jf.sum(masked, axis=0)

@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..core import faults
+from ..core.trace import emit_span, trace_phase
 from ..fields import next_power_of_2
 from ..xof import XofTurboShake128
 from .prio3 import (
@@ -73,6 +74,18 @@ def _observe_prepare(backend: str, phase: str, reports: int, seconds: float) -> 
     if GLOBAL_METRICS.registry is not None:
         GLOBAL_METRICS.observe_prepare(backend, phase, reports, seconds)
     costs.attribute_prepare(backend, phase, seconds)
+
+
+def _observe_launch(backend: str, phase: str, reports: int, first, last) -> None:
+    """One device launch, from the stamps of the phases that timed it
+    (``first.start`` .. ``last.end``): the ``prep_launch`` span (a stage
+    of ``tools/trace_merge.py --stats``) and :func:`_observe_prepare`."""
+    seconds = last.end - first.start
+    emit_span(
+        "prep_launch", "device", first.start, seconds,
+        backend=backend, batch=reports, ok=True,
+    )
+    _observe_prepare(backend, phase, reports, seconds)
 
 
 class OracleBackend:
@@ -222,6 +235,12 @@ class TpuBackend:
         if o is None:
             o = self._oracles[key] = OracleBackend(vdaf)
         return o
+
+    def _scope(self, tail: str) -> str:
+        """This backend's own phase scope (``Histogram/a0/prep_init``,
+        ``Histogram/aggregate``); inside an executor flush the bucket's
+        label is bound on the thread and wins (core.trace.phase_scope)."""
+        return f"{type(self.vdaf.flp.valid).__name__}/{tail}"
 
     # -- jit caches ------------------------------------------------------
     #: Gate for the limb-planar fast path.  Pallas custom calls do not
@@ -465,43 +484,52 @@ class TpuBackend:
         B = len(prep_shares)
         pad_to = self._pad_to(B)
         has_jr = flp.JOINT_RAND_LEN > 0
+        scope = self._scope("combine")
 
-        ver_len = flp.VERIFIER_LEN * vdaf.num_proofs
-        vs = []
-        parts = []
-        for a in range(S):
-            limbs = jf.to_limbs(
-                [x for row in prep_shares for x in row[a].verifiers_share]
-            ).reshape(B, ver_len, jf.n)
-            vs.append(
-                self._place_batch(
-                    np.concatenate([limbs, np.repeat(limbs[-1:], pad_to - B, axis=0)])
-                )
-            )
-            if has_jr:
-                arr = np.frombuffer(
-                    b"".join(row[a].joint_rand_part for row in prep_shares), dtype=np.uint8
-                ).reshape(B, vdaf.xof.SEED_SIZE)
-                parts.append(
+        with trace_phase(scope, "marshal", "python", rows=B):
+            ver_len = flp.VERIFIER_LEN * vdaf.num_proofs
+            vs = []
+            parts = []
+            for a in range(S):
+                limbs = jf.to_limbs(
+                    [x for row in prep_shares for x in row[a].verifiers_share]
+                ).reshape(B, ver_len, jf.n)
+                vs.append(
                     self._place_batch(
-                        np.concatenate([arr, np.repeat(arr[-1:], pad_to - B, axis=0)])
+                        np.concatenate(
+                            [limbs, np.repeat(limbs[-1:], pad_to - B, axis=0)]
+                        )
                     )
                 )
+                if has_jr:
+                    arr = np.frombuffer(
+                        b"".join(row[a].joint_rand_part for row in prep_shares),
+                        dtype=np.uint8,
+                    ).reshape(B, vdaf.xof.SEED_SIZE)
+                    parts.append(
+                        self._place_batch(
+                            np.concatenate(
+                                [arr, np.repeat(arr[-1:], pad_to - B, axis=0)]
+                            )
+                        )
+                    )
 
-        t0 = time.monotonic()
-        out = self._combine()(vs, parts)
-        decide = np.asarray(out["decide"])[:B]
-        seeds = np.asarray(out["prep_msg_seed"])[:B] if has_jr else None
-        _observe_prepare(self.name, "combine", B, time.monotonic() - t0)
+        with trace_phase(scope, "dispatch", "python", rows=B) as dispatched:
+            out = self._combine()(vs, parts)
+        with trace_phase(scope, "readback", "device", rows=B) as read:
+            decide = np.asarray(out["decide"])[:B]
+            seeds = np.asarray(out["prep_msg_seed"])[:B] if has_jr else None
+        _observe_prepare(self.name, "combine", B, read.end - dispatched.start)
 
-        results: List[Union[Optional[bytes], VdafError]] = []
-        for b in range(B):
-            if not decide[b]:
-                results.append(VdafError("proof verification failed"))
-            elif has_jr:
-                results.append(seeds[b].tobytes())
-            else:
-                results.append(None)
+        with trace_phase(scope, "unmarshal", "python", rows=B):
+            results: List[Union[Optional[bytes], VdafError]] = []
+            for b in range(B):
+                if not decide[b]:
+                    results.append(VdafError("proof verification failed"))
+                elif has_jr:
+                    results.append(seeds[b].tobytes())
+                else:
+                    results.append(None)
         return results
 
     def stage_prep_init_multi(
@@ -518,31 +546,34 @@ class TpuBackend:
         ``pad_to`` overrides the power-of-two bucket (the executor's warmup
         uses it to compile a target mega-batch shape from a handful of
         synthetic rows)."""
-        flat: List = []
-        vk_rows: List[np.ndarray] = []
-        segments: Optional[List] = [] if self.canonical else None
-        for req in requests:
-            verify_key, reports, actual = _req_parts(req)
-            flat.extend(reports)
-            vk = np.frombuffer(verify_key, dtype=np.uint8)
-            vk_rows.extend([vk] * len(reports))
-            if segments is not None and reports:
-                # a 2-tuple request (warmup's synthetic rows) is shaped for
-                # the canonical twin itself: its mask is the full width
-                mlen = (actual or self.vdaf).flp.MEAS_LEN
-                segments.append((len(reports), mlen))
-        if not flat:
+        B = sum(len(_req_parts(req)[1]) for req in requests)
+        if not B:
             return None
-        B = len(flat)
+        scope = self._scope(f"a{agg_id}/prep_init")
         pad_to = self._align_pad(max(pad_to or 0, self._pad_to(B)))
-        kw = self._marshal(agg_id, flat, pad_to, segments=segments)
-        vk_mat = np.stack(vk_rows)
-        kw["verify_key_u8"] = np.concatenate(
-            [vk_mat, np.repeat(vk_mat[-1:], pad_to - B, axis=0)]
-        )
-        return StagedPrepInit(
-            agg_id=agg_id, placed=self._place(kw), pad_to=pad_to, rows=B
-        )
+        with trace_phase(scope, "marshal", "python", rows=B):
+            flat: List = []
+            vk_rows: List[np.ndarray] = []
+            segments: Optional[List] = [] if self.canonical else None
+            for req in requests:
+                verify_key, reports, actual = _req_parts(req)
+                flat.extend(reports)
+                vk = np.frombuffer(verify_key, dtype=np.uint8)
+                vk_rows.extend([vk] * len(reports))
+                if segments is not None and reports:
+                    # a 2-tuple request (warmup's synthetic rows) is shaped
+                    # for the canonical twin itself: its mask is the full
+                    # width
+                    mlen = (actual or self.vdaf).flp.MEAS_LEN
+                    segments.append((len(reports), mlen))
+            kw = self._marshal(agg_id, flat, pad_to, segments=segments)
+            vk_mat = np.stack(vk_rows)
+            kw["verify_key_u8"] = np.concatenate(
+                [vk_mat, np.repeat(vk_mat[-1:], pad_to - B, axis=0)]
+            )
+        with trace_phase(scope, "place", "device", rows=B):
+            placed = self._place(kw)
+        return StagedPrepInit(agg_id=agg_id, placed=placed, pad_to=pad_to, rows=B)
 
     def launch_prep_init_multi(
         self,
@@ -576,12 +607,10 @@ class TpuBackend:
         if GLOBAL_METRICS.registry is not None:
             GLOBAL_METRICS.device_launches.labels(backend=self.name).inc()
             GLOBAL_METRICS.device_reports.labels(backend=self.name).inc(B)
-        from ..core.trace import trace_span
-
-        t0 = time.monotonic()
+        scope = self._scope(f"a{agg_id}/prep_init")
         resident = None
         try:
-            with trace_span("prep_launch", cat="device", backend=self.name, batch=B):
+            with trace_phase(scope, "dispatch", "python", rows=B) as dispatched:
                 out = dict(self._prep_fn(agg_id)(staged.placed))
                 if retain_store is not None:
                     matrix = out.pop("out_share")
@@ -590,28 +619,30 @@ class TpuBackend:
                     resident = (flush_id, 0)
                 else:
                     self.outshare_readback_rows += B
-                # One readback for the whole launch, then slice per request.
+            # One readback for the whole launch, then slice per request.
+            with trace_phase(scope, "readback", "device", rows=B) as read:
                 outputs = {k: np.asarray(v)[:B] for k, v in out.items()}
-            _observe_prepare(self.name, "init", B, time.monotonic() - t0)
-            start = 0
-            results: List[List[PrepOutcome]] = []
-            for req in requests:
-                verify_key, reports, actual = _req_parts(req)
-                n = len(reports)
-                view = {k: v[start : start + n] for k, v in outputs.items()}
-                results.append(
-                    self._unmarshal_prep(
-                        verify_key,
-                        agg_id,
-                        reports,
-                        view,
-                        resident=None
-                        if resident is None
-                        else (resident[0], start),
-                        actual_vdaf=actual,
+            _observe_launch(self.name, "init", B, dispatched, read)
+            with trace_phase(scope, "unmarshal", "python", rows=B):
+                start = 0
+                results: List[List[PrepOutcome]] = []
+                for req in requests:
+                    verify_key, reports, actual = _req_parts(req)
+                    n = len(reports)
+                    view = {k: v[start : start + n] for k, v in outputs.items()}
+                    results.append(
+                        self._unmarshal_prep(
+                            verify_key,
+                            agg_id,
+                            reports,
+                            view,
+                            resident=None
+                            if resident is None
+                            else (resident[0], start),
+                            actual_vdaf=actual,
+                        )
                     )
-                )
-                start += n
+                    start += n
         except Exception:
             if resident is not None:
                 # a failure after the store adopted the matrix (verdict
@@ -676,11 +707,14 @@ class TpuBackend:
         if buffer is None:
             jf = self.bp.jf
             buffer = np.zeros((self.vdaf.flp.OUTPUT_LEN, jf.n), dtype=np.uint32)
-        return self._accum_fn(buffer, matrix, mask)
+        with trace_phase(self._scope("accumulate"), "dispatch", "python"):
+            return self._accum_fn(buffer, matrix, mask)
 
     def read_accum_buffer(self, buffer) -> List[int]:
         """Spill readback: ONE (OUT,) field vector — the commit-time drain."""
-        return self.bp.jf.from_limbs(np.asarray(buffer))
+        with trace_phase(self._scope("accumulate"), "readback", "device"):
+            limbs = np.asarray(buffer)
+        return self.bp.jf.from_limbs(limbs)
 
     def aggregate_batch(self, out_shares_limbs, mask) -> List[int]:
         """Masked out-share aggregation on-device.
@@ -703,9 +737,12 @@ class TpuBackend:
                 [shares, np.zeros((pad_to - B,) + shares.shape[1:], shares.dtype)]
             )
             m = np.concatenate([m, np.zeros(pad_to - B, dtype=bool)])
-        return self.bp.jf.from_limbs(
-            np.asarray(self._agg_fn(self._place_batch(shares), self._place_batch(m)))
-        )
+        scope = self._scope("aggregate")
+        with trace_phase(scope, "dispatch", "python", rows=B):
+            out = self._agg_fn(self._place_batch(shares), self._place_batch(m))
+        with trace_phase(scope, "readback", "device", rows=B):
+            out = np.asarray(out)
+        return self.bp.jf.from_limbs(out)
 
 
 class MeshBackend(TpuBackend):
@@ -864,7 +901,8 @@ class MeshBackend(TpuBackend):
                 ),
                 self._batch_sharding,
             )
-        return self._accum_fn(buffer, matrix, np.asarray(mask))
+        with trace_phase(self._scope("accumulate"), "dispatch", "python"):
+            return self._accum_fn(buffer, matrix, np.asarray(mask))
 
     def read_accum_buffer(self, buffer) -> List[int]:
         """Spill readback: the one point where the accumulated shards
@@ -875,7 +913,9 @@ class MeshBackend(TpuBackend):
         if self._accum_read_fn is None:
             jf = self.bp.jf
             self._accum_read_fn = self._jax.jit(lambda b: jf.sum(b, axis=0))
-        return self.bp.jf.from_limbs(np.asarray(self._accum_read_fn(buffer)))
+        with trace_phase(self._scope("accumulate"), "readback", "device"):
+            limbs = np.asarray(self._accum_read_fn(buffer))
+        return self.bp.jf.from_limbs(limbs)
 
 
 class HybridXofBackend:
@@ -1157,12 +1197,9 @@ class Poplar1Backend:
         if GLOBAL_METRICS.registry is not None:
             GLOBAL_METRICS.device_launches.labels(backend=self.name).inc()
             GLOBAL_METRICS.device_reports.labels(backend=self.name).inc(rows)
-        from ..core.trace import trace_span
-
-        t0 = time.monotonic()
-        with trace_span("prep_launch", cat="device", backend=self.name, batch=rows):
+        with trace_phase("Poplar1/poplar_init", "launch", "device", rows=rows) as launched:
             out = self.bp.launch_init_multi(staged, retain_store=retain_store)
-        _observe_prepare(self.name, "init", rows, time.monotonic() - t0)
+        _observe_launch(self.name, "init", rows, launched, launched)
         return out
 
     def prep_init_multi_poplar(self, agg_id, requests, retain_store=None):
