@@ -16,6 +16,7 @@ job is abandoned with a best-effort DELETE to the helper (reference
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import logging
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -32,6 +33,7 @@ from ..datastore import (
 )
 from ..datastore.datastore import DatastoreError, DatastoreUnavailable
 from ..datastore.task import AggregatorTask
+from ..executor import narrow_arrival, withdraw_arrival
 from ..messages import (
     AggregationJobContinueReq,
     AggregationJobInitializeReq,
@@ -223,7 +225,11 @@ class AggregationJobDriver:
         outcome = "success"
         with Timer() as timer:
             try:
-                await self._step(lease)
+                # announced here, before the load and the decode, so that
+                # every lease of one discovery pass is on the executor's
+                # books before the first of them submits
+                with self._announce_prep_init():
+                    await self._step(lease)
             except JobStepError as e:
                 # Partition pressure (peer suspect) releases WITHOUT
                 # consuming the retryable budget: the failure is the
@@ -304,6 +310,15 @@ class AggregationJobDriver:
             if outcome != "success":
                 GLOBAL_METRICS.step_failures.labels(type=outcome).inc()
 
+    def _announce_prep_init(self):
+        """This step's leader ``prep_init`` rows, announced to the executor
+        (executor.announce): the bucket they are bound for flushes when the
+        last announced step has joined it, not when its window runs out.
+        Whatever ends the step closes the announcement."""
+        if self._executor is None:
+            return contextlib.nullcontext()
+        return self._executor.announce("prep_init", agg_id=0)
+
     async def _step(self, lease: Lease) -> None:
         acq = lease.leased
         # tx1: load task, job, report aggregations (reference :169-220)
@@ -338,6 +353,7 @@ class AggregationJobDriver:
         if start_ras:
             await self._step_init(lease, task, vdaf, job, ras, start_ras)
         elif waiting_ras:
+            withdraw_arrival()  # a continue step has no prep_init
             await self._step_continue(lease, task, vdaf, job, ras, waiting_ras)
         else:
             # nothing to do; close the job out
@@ -766,6 +782,7 @@ class AggregationJobDriver:
         from ..core import costs
         from ..vdaf.backend import oracle_backend_for
 
+        withdraw_arrival()  # the oracle takes long, and no bucket gets these rows
         oracle = oracle_backend_for(backend, vdaf)
         if oracle is None:
             raise JobStepError(f"device unavailable: {cause}", retryable=True)
@@ -921,6 +938,15 @@ class AggregationJobDriver:
             }
         outcomes: Dict[bytes, object] = {}  # report_id -> (state, msg) | PrepareError
         loop = asyncio.get_running_loop()
+        # resolved before the decode: for its length a bucket of another
+        # shape need not wait for this step
+        backend = self._backend_for(task, vdaf)
+        if self._executor is not None and hasattr(backend, "stage_prep_init_multi"):
+            from ..vdaf.canonical import backend_shape_key
+
+            narrow_arrival(backend_shape_key(backend))
+        else:
+            withdraw_arrival()
 
         def decode_rows():
             """Per-report wire decoding is pure-Python field parsing —
@@ -943,7 +969,6 @@ class AggregationJobDriver:
         for rid in bad_ids:
             outcomes[rid] = PrepareError.INVALID_MESSAGE
 
-        backend = self._backend_for(task, vdaf)
         if backend is not None:
             prep_in = [
                 (ra.report_id.data, public, share) for ra, public, share in rows
@@ -1013,7 +1038,10 @@ class AggregationJobDriver:
         return outcomes
 
     async def _step_init(self, lease, task, vdaf, job, all_ras, start_ras):
-        outcomes = await self._leader_prep_init(task, vdaf, job, start_ras)
+        try:
+            outcomes = await self._leader_prep_init(task, vdaf, job, start_ras)
+        finally:
+            withdraw_arrival()
         try:
             await self._step_init_with_outcomes(
                 lease, task, vdaf, job, all_ras, start_ras, outcomes

@@ -15,6 +15,7 @@ loop is never blocked (the analog of L0's tokio/rayon split, SURVEY.md §1).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import hashlib
 import logging
 from dataclasses import dataclass, field as dc_field
@@ -42,6 +43,7 @@ from ..datastore import (
 )
 from ..datastore.datastore import QUERY_TYPES
 from ..datastore.query_type import strategy_for
+from ..executor import narrow_arrival, withdraw_arrival
 from ..messages import (
     AggregateShare,
     AggregateShareAad,
@@ -618,11 +620,34 @@ class Aggregator:
         body: bytes,
         auth_token: Optional[AuthenticationToken],
     ) -> AggregationJobResp:
+        # Announced on entry, before the decode, the two replay lookups
+        # and the HPKE opens: the other requests of the leader's cohort
+        # arrive meanwhile, and the prep_init bucket flushes when the last
+        # of them has joined it, not when its window runs out; combine
+        # follows with the same cohort.  Whatever ends the request closes
+        # the announcement.
+        from ..executor import KIND_COMBINE, KIND_PREP_INIT
+
+        announced = (
+            self._executor.announce(KIND_PREP_INIT, agg_id=1, then=KIND_COMBINE)
+            if self._executor is not None
+            else contextlib.nullcontext()
+        )
+        with announced:
+            return await self._aggregate_init(
+                task_id, aggregation_job_id, body, auth_token
+            )
+
+    async def _aggregate_init(self, task_id, aggregation_job_id, body, auth_token):
         ta = await self.task_aggregator_for(task_id)
         task = ta.task
         if task.role != Role.HELPER:
             raise UnrecognizedTask("aggregate-init on non-helper")
         ta.check_aggregator_auth(auth_token)
+        if self._prio3_through_executor(ta):
+            narrow_arrival(self._executor_backend_for(ta)[0])
+        else:
+            withdraw_arrival()  # no prep_init bucket is this request's
         with trace_phase("helper_init", "decode_req", "python", bytes=len(body)):
             req = AggregationJobInitializeReq.get_decoded(body, ta.query_class)
             request_hash = hashlib.sha256(body).digest()
@@ -730,11 +755,7 @@ class Aggregator:
         except VdafError:
             raise InvalidMessage("bad aggregation parameter")
         loop = asyncio.get_running_loop()
-        if (
-            self._executor is not None
-            and isinstance(ta.vdaf, Prio3)
-            and hasattr(ta.backend, "stage_prep_init_multi")
-        ):
+        if self._prio3_through_executor(ta):
             # Helper-side executor routing (ROADMAP item): prep_init and
             # combine submit through the process-wide continuous batcher,
             # so helper requests coalesce with driver traffic and the
@@ -764,6 +785,10 @@ class Aggregator:
                     lambda: self._helper_prepare_batch(ta, decoded, agg_param),
                 ),
             )
+
+        # whatever path prepared them, nothing more of this request is on
+        # its way to the executor
+        withdraw_arrival()
 
         # Assemble responses + report aggregations in request order.
         with trace_phase("helper_init", "assemble", "python", rows=rows):
@@ -1222,6 +1247,15 @@ class Aggregator:
         )
         return self._helper_finish_prio3(vdaf, results, combine_rows, combined)
 
+    def _prio3_through_executor(self, ta: TaskAggregator) -> bool:
+        """Does this task's aggregate-init prepare through the executor's
+        ``prep_init`` and ``combine`` buckets?"""
+        return (
+            self._executor is not None
+            and isinstance(ta.vdaf, Prio3)
+            and hasattr(ta.backend, "stage_prep_init_multi")
+        )
+
     def _executor_backend_for(self, ta: TaskAggregator):
         """(shape key, backend) through the executor's shape-keyed cache:
         tasks sharing one VDAF shape share one backend + compiled graphs,
@@ -1301,12 +1335,12 @@ class Aggregator:
                 ),
             )
 
-        if self._executor.circuit_open(shape_key):
-            return await loop.run_in_executor(None, oracle_path)
-        if self._executor.warming(shape_key):
-            # executable still compiling on the warmup thread: the helper
-            # answers on the bit-exact oracle instead of queueing the
-            # request behind XLA (the breaker never sees compile-wait)
+        if self._executor.circuit_open(shape_key) or self._executor.warming(shape_key):
+            # circuit open, or the executable still compiling on the warmup
+            # thread: the helper answers on the bit-exact oracle instead of
+            # queueing the request behind XLA (the breaker never sees
+            # compile-wait), and no bucket waits for its rows
+            withdraw_arrival()
             return await loop.run_in_executor(None, oracle_path)
 
         def decode_leader_shares():

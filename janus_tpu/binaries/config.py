@@ -287,7 +287,10 @@ class DeviceExecutorConfig:
     mesh: bool = False
     #: flush a bucket once it holds this many rows (pow2-padded launch)
     flush_max_rows: int = 16384
-    #: deadline (ms) from a bucket's first pending submission to its flush
+    #: the longest (ms) a bucket waits, from its first pending submission,
+    #: for arrivals nobody announced to the executor; a bucket whose
+    #: announced arrivals have all joined flushes at once (the drivers and
+    #: the helper announce theirs), so this is not what every flush costs
     flush_window_ms: float = 5.0
     #: per-bucket queued+in-flight row bound; beyond it submits are
     #: rejected retryably (lease redelivery provides the retry)

@@ -428,6 +428,14 @@ class Metrics:
             buckets=(1, 4, 16, 64, 256, 1024, 4096, 16384, 65536),
             registry=self.registry,
         )
+        self.executor_flushes = Counter(
+            "janus_executor_flushes_total",
+            "Executor flushes by bucket and trigger: size (the bucket "
+            "filled), arrived (every announced arrival had joined it), "
+            "deadline (its flush window ran out), drain",
+            ["bucket", "trigger"],
+            registry=self.registry,
+        )
         self.executor_wait_seconds = Histogram(
             "janus_executor_wait_duration_seconds",
             "Submission wall time from enqueue to result by bucket",

@@ -25,9 +25,11 @@ from .service import (
     ExecutorOverloadedError,
     bucket_label,
     get_global_executor,
+    narrow_arrival,
     peek_global_executor,
     reset_global_executor,
     shape_label,
+    withdraw_arrival,
 )
 
 __all__ = [
@@ -47,7 +49,9 @@ __all__ = [
     "StaleAccumulatorDelta",
     "bucket_label",
     "get_global_executor",
+    "narrow_arrival",
     "peek_global_executor",
     "reset_global_executor",
     "shape_label",
+    "withdraw_arrival",
 ]

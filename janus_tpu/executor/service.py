@@ -13,8 +13,10 @@ kernel pool — shaped like an inference-serving continuous batcher:
   that owns the device.
 * **Bucketed continuous batching**: submissions are grouped per
   ``(vdaf_shape_key, kind, agg_id, agg_param_key)`` bucket and flushed as
-  ONE pow2-padded mega-batch when the bucket reaches ``flush_max_rows``
-  or its ``flush_window_s`` deadline expires — whichever comes first.
+  ONE pow2-padded mega-batch when the bucket reaches ``flush_max_rows``,
+  when the last arrival the executor was told of has joined it
+  (``announce``), or when its ``flush_window_s`` deadline expires —
+  whichever comes first.
   The agg-param key is an OPAQUE per-VDAF discriminant of the submission's
   aggregation parameter: Prio3 (no parameter) passes None, Poplar1 passes
   its IDPF tree level — so multi-round heavy-hitter rounds from different
@@ -41,6 +43,7 @@ concurrent submission).
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import logging
 import threading
 import time
@@ -72,6 +75,11 @@ KIND_COMBINE = "combine"
 #: agg-param key (the tree LEVEL), so different jobs at one level coalesce
 #: while levels never share a mega-batch.
 KIND_POPLAR_INIT = "poplar_init"
+
+#: What makes a bucket flush (the ``trigger`` of the flight record, of the
+#: ``executor_flush`` span and of ``janus_executor_flushes_total``): it is
+#: full; every announced arrival has joined it; its window ran out; drain().
+FLUSH_TRIGGERS = ("size", "arrived", "deadline", "drain")
 
 
 class ExecutorOverloadedError(Exception):
@@ -113,7 +121,9 @@ class ExecutorConfig:
     mesh: bool = False
     #: flush a bucket as soon as it holds this many rows
     flush_max_rows: int = 16384
-    #: deadline from a bucket's first pending submission to its flush
+    #: the longest a bucket waits, from its first pending submission, for
+    #: arrivals nobody announced; a bucket whose announced arrivals have all
+    #: joined (DeviceExecutor.announce) flushes at once
     flush_window_s: float = 0.005
     #: per-bucket bound on queued + in-flight rows; beyond it, submit rejects
     max_queue_rows: int = 131072
@@ -321,6 +331,82 @@ class _Submission:
     #: submit time so the flush can emit per-submission child spans — a
     #: job's merged timeline shows its share of each mega-batch flush
     trace_ctx: Optional[dict] = None
+    #: the announced arrival this submission joined its bucket as (None:
+    #: nobody announced it, and it waits the window out)
+    arrival: Optional["Arrival"] = None
+
+
+#: the arrival announced for the running task (``with executor.announce``);
+#: ``submit`` takes it from here, so a step's callees need not carry it
+_ARRIVAL: contextvars.ContextVar = contextvars.ContextVar(
+    "janus_executor_arrival", default=None
+)
+
+
+class Arrival:
+    """Rows on their way to a bucket before they exist (DeviceExecutor.
+    announce).  Open from the call on; as a context manager it also binds
+    to the running task, whose ``submit`` of the announced kind closes it
+    by joining a bucket, and whose leaving the block closes it whatever
+    happened.  ``close`` is idempotent and safe from any thread.  All
+    fields are the executor's, under its lock."""
+
+    __slots__ = (
+        "_executor", "kind", "agg_id", "shape_key", "then", "holds_until", "_token"
+    )
+
+    def __init__(self, executor, kind, agg_id, shape_key, then, holds_until):
+        self._executor = executor
+        self.kind = kind
+        self.agg_id = agg_id
+        #: None until the caller knows its shape: any bucket of the kind
+        #: and side waits for it meanwhile
+        self.shape_key = shape_key
+        #: the kind this caller submits next with the results of ``kind``;
+        #: the flush that resolves those opens it (KIND_COMBINE after a
+        #: helper's KIND_PREP_INIT)
+        self.then = then
+        #: one window after it was opened: a caller that takes longer (a
+        #: wedged step) holds no bucket back after that, this once or again
+        self.holds_until = holds_until
+
+    def could_reach(self, bucket: "_Bucket", now: float) -> bool:
+        return (
+            self.kind == bucket.kind
+            and self.agg_id == bucket.agg_id
+            and self.shape_key in (None, bucket.key[0])
+            and now < self.holds_until
+        )
+
+    def close(self) -> None:
+        self._executor._close_arrival(self)
+
+    def __enter__(self) -> "Arrival":
+        self._token = _ARRIVAL.set(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _ARRIVAL.reset(self._token)
+        self.close()
+
+
+def narrow_arrival(shape_key: tuple) -> None:
+    """The running task now knows its shape: the arrival it announced, if
+    any, can reach only buckets of ``shape_key``, and no bucket of another
+    shape waits for it."""
+    arrival = _ARRIVAL.get()
+    if arrival is not None:
+        arrival._executor._narrow_arrival(arrival, shape_key)
+
+
+def withdraw_arrival() -> None:
+    """The running task will submit nothing (more): close the arrival it
+    announced, if any.  For a caller that leaves the device path (the CPU
+    oracle, no rows, another kind of step) long before it leaves its
+    ``with executor.announce`` block."""
+    arrival = _ARRIVAL.get()
+    if arrival is not None:
+        arrival.close()
 
 
 class _Bucket:
@@ -340,6 +426,8 @@ class _Bucket:
         self.queued_rows = 0
         self.inflight_rows = 0
         self.timer: Optional[asyncio.TimerHandle] = None
+        #: the loop ``timer`` is armed on; an arrived flush runs there too
+        self.loop: Optional[asyncio.AbstractEventLoop] = None
         # plain-Python stats (usable without prometheus; bench reads these)
         self.flushes = 0
         self.flushed_rows = 0
@@ -455,6 +543,9 @@ class DeviceExecutor:
         # Strong refs to in-flight flush tasks: the event loop holds tasks
         # weakly, and a GC'd flush would strand its detached submissions.
         self._flush_tasks: set = set()
+        #: open announced arrivals: a bucket one of them could still reach
+        #: keeps waiting (up to its window) for it
+        self._arrivals: set = set()
         self._closed = False
         # Fair flush scheduler state: per-loop ready queues of detached
         # flushes, dispatched deficit-round-robin across buckets.
@@ -730,9 +821,11 @@ class DeviceExecutor:
         combine outcomes.  kind=KIND_POPLAR_INIT: payload is (verify_key,
         agg_param, report_rows) and the result is the per-row Poplar1
         (state, share) outcomes.  Raises ExecutorOverloadedError on
-        backpressure.  ``task_ident`` attributes the rows to a task for
-        the per-task fairness quota within the bucket (None =
-        unattributed).  ``agg_param_key`` is the opaque agg-param bucket
+        backpressure.  A submission of the kind the running task
+        announced (``announce``) joins its bucket as that arrival; any
+        other waits the bucket's window out.  ``task_ident`` attributes
+        the rows to a task for the per-task fairness quota within the
+        bucket (None = unattributed).  ``agg_param_key`` is the opaque agg-param bucket
         discriminant (None for parameter-less VDAFs; Poplar1 passes the
         tree level): submissions coalesce only within one value, so two
         rounds of one task can never share a mega-batch — but different
@@ -746,8 +839,40 @@ class DeviceExecutor:
             rows = len(payload[2])
         else:
             raise ValueError(f"unknown submission kind {kind!r}")
+        arrival = _ARRIVAL.get()
+        if arrival is not None and not (
+            arrival._executor is self
+            and arrival.kind == kind
+            and arrival.agg_id == agg_id
+        ):
+            arrival = None  # announced for something else: this one is not
         if rows == 0:
+            if arrival is not None:
+                arrival.close()
             return []
+        try:
+            sub, bucket, subs, complete = self._join(
+                shape_key, kind, payload, rows, backend, agg_id, deadline_s,
+                retain_out_shares, task_ident, agg_param_key, arrival,
+            )
+        except Exception:
+            # no rows of this caller will come (shut down, circuit open,
+            # queue full): nobody waits for them
+            if arrival is not None:
+                arrival.close()
+            raise
+        if subs:
+            self._enqueue_ready(bucket, subs, trigger="size")
+        self._flush_arrived(complete)
+        return await sub.future
+
+    def _join(
+        self, shape_key, kind, payload, rows, backend, agg_id, deadline_s,
+        retain_out_shares, task_ident, agg_param_key, arrival,
+    ):
+        """Put one submission into its bucket.  Returns ``(submission,
+        bucket, the pending set if this one filled the bucket, buckets
+        whose announced cohort this one completed)``."""
         if self._closed:
             raise ExecutorOverloadedError("executor is shut down")
         breaker = self._breaker_for(shape_key, backend)
@@ -805,6 +930,9 @@ class DeviceExecutor:
                 task=task_ident,
                 trace_ctx=current_trace() or None,
             )
+            if arrival in self._arrivals:
+                self._arrivals.discard(arrival)
+                sub.arrival = arrival
             bucket.last_activity = now
             bucket.pending.append(sub)
             bucket.queued_rows += rows
@@ -814,13 +942,109 @@ class DeviceExecutor:
             else:
                 subs = None
                 if bucket.timer is None:
+                    bucket.loop = loop
                     bucket.timer = loop.call_later(
                         self.config.flush_window_s,
                         lambda: self._spawn(self._deadline_flush(bucket)),
                     )
-        if subs:
-            self._enqueue_ready(bucket, subs, trigger="size")
-        return await sub.future
+            # an arrival that has joined no longer holds any bucket back
+            complete = self._arrived_locked() if sub.arrival is not None else []
+        return sub, bucket, subs, complete
+
+    # -- announced arrivals ----------------------------------------------
+    def announce(
+        self,
+        kind: str,
+        agg_id: int = 0,
+        *,
+        shape_key: Optional[tuple] = None,
+        then: Optional[str] = None,
+    ) -> Arrival:
+        """Tell the executor of a submission before its rows exist: every
+        bucket of ``kind`` and ``agg_id`` (of ``shape_key`` once known)
+        that holds announced rows waits for this one, and flushes —
+        trigger ``arrived`` — when the last such arrival has joined it or
+        has been closed, not when its window runs out.  Use as ``with
+        executor.announce(...)`` around the work that ends in ``submit``:
+        leaving the block closes the arrival, so zero rows, an oracle
+        fallback, an error or a cancelled step release the bucket.  An
+        arrival holds a bucket back for one window at most: one that is
+        never closed costs what an unannounced submission costs, once.
+        ``then`` names the kind the caller
+        submits next with this one's results: the flush that resolves
+        these opens that arrival for each of its submissions before any
+        of them runs again, so the cohort's next launch is one too."""
+        arrival = Arrival(
+            self, kind, agg_id, shape_key, then,
+            time.monotonic() + self.config.flush_window_s,
+        )
+        with self._lock:
+            self._arrivals.add(arrival)
+        return arrival
+
+    def _narrow_arrival(self, arrival: Arrival, shape_key: tuple) -> None:
+        with self._lock:
+            arrival.shape_key = shape_key
+            complete = self._arrived_locked() if arrival in self._arrivals else []
+        self._flush_arrived(complete)
+
+    def _close_arrival(self, arrival: Arrival) -> None:
+        with self._lock:
+            arrival.then = None
+            if arrival not in self._arrivals:
+                return
+            self._arrivals.discard(arrival)
+            complete = self._arrived_locked()
+        self._flush_arrived(complete)
+
+    def _cohort_complete_locked(self, bucket: _Bucket) -> bool:
+        """Pending rows, announced ones among them, and no open arrival
+        that could still reach this bucket.  Lock held."""
+        now = time.monotonic()
+        return (
+            any(s.arrival is not None for s in bucket.pending)
+            and not any(a.could_reach(bucket, now) for a in self._arrivals)
+        )
+
+    def _arrived_locked(self) -> List[_Bucket]:
+        return [b for b in self._buckets.values() if self._cohort_complete_locked(b)]
+
+    def _flush_arrived(self, buckets: List[_Bucket]) -> None:
+        """Flush each bucket on the loop that owns its timer; any thread."""
+        if not buckets:
+            return
+        try:
+            running = asyncio.get_running_loop()
+        except RuntimeError:
+            running = None
+        for bucket in buckets:
+            if bucket.loop is running:
+                self._arrived_flush(bucket)
+                continue
+            try:
+                bucket.loop.call_soon_threadsafe(self._arrived_flush, bucket)
+            except RuntimeError:  # that loop is closed: drain() is what is left
+                pass
+
+    def _arrived_flush(self, bucket: _Bucket) -> None:
+        with self._lock:
+            # judged again: an arrival may have been announced since
+            if not self._cohort_complete_locked(bucket):
+                return
+            subs = self._take_pending(bucket)
+        self._enqueue_ready(bucket, subs, trigger="arrived")
+
+    def _open_followers(self, subs: List[_Submission]) -> None:
+        """A flush resolves ``subs``: open the arrival each of them said
+        follows, all before the first waiter runs again — the first to
+        submit must find the others announced."""
+        with self._lock:
+            for s in subs:
+                a = s.arrival
+                if a is not None and a.then is not None:
+                    a.kind, a.then = a.then, None
+                    a.holds_until = time.monotonic() + self.config.flush_window_s
+                    self._arrivals.add(a)
 
     def _breaker_for(self, shape_key: tuple, backend) -> Optional[CircuitBreaker]:
         """One CircuitBreaker per failure DOMAIN (None when disabled).
@@ -1139,6 +1363,7 @@ class DeviceExecutor:
         # bodies time them.  ``seq`` is taken here so that the annotations
         # on the stage and launch threads carry the flight record's own.
         seq = self.flight_recorder.next_seq()
+        self._observe_trigger(bucket, trigger)
         note = {"seq": seq, "queue_delay_max_ms": round(queue_delay_max * 1000.0, 3)}
         phases: Dict[str, float] = {
             "window_wait": emit_phase(
@@ -1354,6 +1579,7 @@ class DeviceExecutor:
                     path="device",
                 )
                 still_set = set(id(s) for s in still)
+                self._open_followers(still)
                 for s, out in zip(live, outs):
                     if id(s) not in still_set:
                         # rejected at launch dequeue: its result is
@@ -1591,6 +1817,7 @@ class DeviceExecutor:
         for idx in poisoned:
             si = items[idx][0]
             offender_rows[si] = offender_rows.get(si, 0) + 1
+        self._open_followers(live)
         for si, s in enumerate(live):
             bad = offender_rows.get(si, 0)
             if s.rows - bad:
@@ -1925,6 +2152,10 @@ class DeviceExecutor:
                         GLOBAL_METRICS.remove_series(
                             GLOBAL_METRICS.executor_rejections, label, reason
                         )
+                    for trigger in FLUSH_TRIGGERS:
+                        GLOBAL_METRICS.remove_series(
+                            GLOBAL_METRICS.executor_flushes, label, trigger
+                        )
                     retire_phase_scope(label)
                 for label in retired_circuits:
                     GLOBAL_METRICS.remove_series(
@@ -2004,6 +2235,14 @@ class DeviceExecutor:
             GLOBAL_METRICS.executor_launch_seconds.labels(
                 bucket=bucket.label
             ).observe(launch_s)
+
+    def _observe_trigger(self, bucket: _Bucket, trigger: str) -> None:
+        from ..core.metrics import GLOBAL_METRICS
+
+        if GLOBAL_METRICS.registry is not None:
+            GLOBAL_METRICS.executor_flushes.labels(
+                bucket=bucket.label, trigger=trigger
+            ).inc()
 
     def _observe_pad(self, bucket: _Bucket, padded_rows: int) -> None:
         from ..core.metrics import GLOBAL_METRICS

@@ -423,3 +423,332 @@ def test_bucket_label_is_compact():
         bucket_label(OracleBackend(prio3_count()), "prep_init", 0)
         == "Count/a0/prep_init"
     )
+
+
+# -- announced arrivals: the "arrived" flush trigger --------------------------
+
+#: the served deployments' window (README "Running on a TPU host"): a test
+#: that passes well under it was not flushed by the timer
+WINDOW_S = 3.0
+#: for the tests that must see the window run out
+SHORT_WINDOW_S = 0.4
+
+
+def _triggers(ex):
+    """(bucket kind, trigger, rows) of every flush, oldest first."""
+    return [
+        (r["bucket"].split("/")[2].split("#")[0], r["trigger"], r["rows"])
+        for r in reversed(ex.flight_recorder.snapshot(64))
+    ]
+
+
+def _jobs(ex):
+    """Submissions in every flush of each bucket kind."""
+    return {
+        label.split("/")[2].split("#")[0]: (st["flushes"], st["flushed_jobs"])
+        for label, st in ex.stats().items()
+    }
+
+
+def _announcing_executor(window_s=WINDOW_S):
+    return DeviceExecutor(ExecutorConfig(flush_window_s=window_s, flush_max_rows=10_000))
+
+
+async def _gathered(*coros, return_exceptions=False):
+    return await asyncio.gather(*coros, return_exceptions=return_exceptions)
+
+
+def test_announced_submitters_flush_once_on_the_last_arrival():
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+    delays = [0.02, 0.08, 0.2]
+
+    async def job(i):
+        # announced first, as a discovery pass's leases are, then the
+        # load and the decode take each job its own time
+        with ex.announce("prep_init"):
+            await asyncio.sleep(delays[i])
+            return await ex.submit(
+                ("s",), "prep_init", (b"k%d" % i, list(range(i + 1))), backend=backend
+            )
+
+    t0 = time.monotonic()
+    outs = _run(_gathered(*(job(i) for i in range(3))))
+    elapsed = time.monotonic() - t0
+    ex.shutdown()
+    assert [len(o) for o in outs] == [1, 2, 3]
+    assert backend.launches == [[1, 2, 3]], "one flush, of every job's rows"
+    assert _triggers(ex) == [("prep_init", "arrived", 6)]
+    assert _jobs(ex) == {"prep_init": (1, 3)}
+    assert max(delays) <= elapsed < 1.5, "on the last arrival, not on the timer"
+    assert not ex._arrivals
+
+
+@pytest.mark.parametrize("how", ["exception", "cancelled", "zero_rows", "oracle"])
+def test_arrival_closed_without_rows_releases_the_bucket(how):
+    from janus_tpu.executor import withdraw_arrival
+
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+
+    async def stayer():
+        with ex.announce("prep_init"):
+            return await ex.submit(("s",), "prep_init", (b"k", [0, 1]), backend=backend)
+
+    async def leaver():
+        with ex.announce("prep_init"):
+            await asyncio.sleep(0.1)
+            if how == "exception":
+                raise RuntimeError("the step failed before it had rows")
+            if how == "zero_rows":
+                return await ex.submit(("s",), "prep_init", (b"k", []), backend=backend)
+            if how == "oracle":
+                withdraw_arrival()  # serves on the CPU oracle, for long
+            await asyncio.sleep(WINDOW_S * 2)
+
+    async def go():
+        left = asyncio.ensure_future(leaver())
+        stay = asyncio.ensure_future(stayer())
+        if how == "cancelled":
+            await asyncio.sleep(0.1)
+            left.cancel()
+        out = await stay
+        done_at = time.monotonic()
+        left.cancel()
+        await asyncio.gather(left, return_exceptions=True)
+        return out, done_at
+
+    t0 = time.monotonic()
+    out, done_at = _run(go())
+    ex.shutdown()
+    assert len(out) == 2
+    assert _triggers(ex) == [("prep_init", "arrived", 2)]
+    assert 0.1 <= done_at - t0 < 1.5
+    assert not ex._arrivals
+
+
+def test_arrival_never_closed_falls_to_the_deadline_with_every_row_resolved():
+    backend = _FakeBackend()
+    ex = _announcing_executor(SHORT_WINDOW_S)
+    wedged = ex.announce("prep_init")  # announced, and then nothing
+
+    async def job(i):
+        with ex.announce("prep_init"):
+            await asyncio.sleep(0.01)  # the load: both are announced by then
+            return await ex.submit(("s",), "prep_init", (b"k", [0] * (i + 1)), backend=backend)
+
+    async def go():
+        t0 = time.monotonic()
+        outs = await asyncio.gather(job(0), job(1))
+        t1 = time.monotonic()
+        # it held the bucket for its one window; the next cohort does not wait
+        again = await asyncio.gather(job(0), job(1))
+        return outs, t1 - t0, again, time.monotonic() - t1
+
+    outs, first, again, second = _run(go())
+    ex.shutdown()
+    assert [len(o) for o in outs] == [len(o) for o in again] == [1, 2]
+    assert _triggers(ex) == [("prep_init", "deadline", 3), ("prep_init", "arrived", 3)]
+    assert SHORT_WINDOW_S <= first < SHORT_WINDOW_S + 1.5
+    assert second < SHORT_WINDOW_S / 2
+    assert wedged in ex._arrivals
+
+
+def test_unannounced_submission_waits_the_window_as_before():
+    backend = _FakeBackend()
+    ex = _announcing_executor(SHORT_WINDOW_S)
+
+    async def go():
+        fut = asyncio.ensure_future(
+            ex.submit(("s",), "prep_init", (b"k", [0, 1]), backend=backend)
+        )
+        await asyncio.sleep(0.05)
+        # somebody else's announcement coming and going is not its business
+        with ex.announce("prep_init"):
+            pass
+        return await fut
+
+    t0 = time.monotonic()
+    out = _run(go())
+    elapsed = time.monotonic() - t0
+    ex.shutdown()
+    assert len(out) == 2
+    assert _triggers(ex) == [("prep_init", "deadline", 2)]
+    assert elapsed >= SHORT_WINDOW_S
+
+
+@pytest.mark.parametrize("one_drops_out", [False, True])
+def test_helper_cohort_combines_in_one_flush_without_a_window(one_drops_out):
+    """combine after an N-submission prep_init flush is one flush of N (of
+    those still there), not N of one: the executor announces it for every
+    submission before the first of them runs again."""
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+    n = 3
+
+    async def request(i):
+        with ex.announce("prep_init", agg_id=1, then="combine"):
+            await asyncio.sleep(0.03 * i)  # decode, replay lookups, HPKE open
+            prep = await ex.submit(
+                ("s",), "prep_init", (b"k", [0] * (i + 1)), backend=backend, agg_id=1
+            )
+            await asyncio.sleep(0.03 * (n - i))  # last to prepare, first to combine
+            if one_drops_out and i == 1:
+                raise RuntimeError("every row of this request failed")
+            return await ex.submit(
+                ("s",), "combine", [[p, p] for p in prep], backend=backend, agg_id=1
+            )
+
+    t0 = time.monotonic()
+    outs = _run(_gathered(*(request(i) for i in range(n)), return_exceptions=True))
+    elapsed = time.monotonic() - t0
+    ex.shutdown()
+    assert backend.launches == [[1, 2, 3]]
+    left = [(1, 1), (3, 2)] if one_drops_out else [(1, 1), (2, 1), (3, 1)]
+    rows = sum(r for r, _ in left)
+    assert backend.combine_batches == [rows], "one combine launch for the cohort"
+    assert _triggers(ex) == [("prep_init", "arrived", 6), ("combine", "arrived", rows)]
+    assert _jobs(ex) == {"prep_init": (1, 3), "combine": (1, len(left))}
+    assert [len(o) for o in outs if not isinstance(o, Exception)] == [r for r, _ in left]
+    assert elapsed < 1.5
+    assert not ex._arrivals
+
+
+@pytest.mark.parametrize("closer", ["thread", "loop"])
+def test_arrival_closed_elsewhere_fires_on_the_buckets_own_loop(closer):
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+    fired_on = []
+    arrived_flush = ex._arrived_flush
+
+    def spy(bucket):
+        fired_on.append((threading.current_thread(), asyncio.get_running_loop()))
+        arrived_flush(bucket)
+
+    ex._arrived_flush = spy
+
+    def close_from_thread(arrival):
+        time.sleep(0.1)
+        arrival.close()
+
+    def close_from_loop():
+        async def other():
+            with ex.announce("prep_init"):
+                await asyncio.sleep(0.1)
+
+        asyncio.run(other())
+
+    async def go():
+        if closer == "thread":
+            worker = threading.Thread(
+                target=close_from_thread, args=(ex.announce("prep_init"),)
+            )
+        else:
+            worker = threading.Thread(target=close_from_loop)
+        worker.start()
+        await asyncio.sleep(0.03)  # the other one is announced by now
+        with ex.announce("prep_init"):
+            out = await ex.submit(("s",), "prep_init", (b"k", [0, 1]), backend=backend)
+        worker.join(5)
+        assert not worker.is_alive()
+        return out, threading.current_thread(), asyncio.get_running_loop()
+
+    t0 = time.monotonic()
+    out, thread, loop = _run(go())
+    elapsed = time.monotonic() - t0
+    ex.shutdown()
+    assert len(out) == 2
+    assert _triggers(ex) == [("prep_init", "arrived", 2)]
+    assert fired_on == [(thread, loop)]
+    assert elapsed < 1.5
+
+
+def test_narrowed_arrival_holds_only_buckets_of_its_shape():
+    from janus_tpu.executor import narrow_arrival
+
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+
+    async def slow_other_shape():
+        with ex.announce("prep_init"):
+            narrow_arrival(("other",))
+            await asyncio.sleep(0.5)
+            return await ex.submit(("other",), "prep_init", (b"k", [0]), backend=backend)
+
+    async def go():
+        other = asyncio.ensure_future(slow_other_shape())
+        await asyncio.sleep(0)
+        t0 = time.monotonic()
+        with ex.announce("prep_init", shape_key=("s",)):
+            out = await ex.submit(("s",), "prep_init", (b"k", [0, 1]), backend=backend)
+        mine = time.monotonic() - t0
+        return out, mine, await other
+
+    out, mine, theirs = _run(go())
+    ex.shutdown()
+    assert len(out) == 2 and len(theirs) == 1
+    assert mine < 0.4, "a bucket of another shape does not wait for that caller"
+    assert [t[1] for t in _triggers(ex)] == ["arrived", "arrived"]
+
+
+def test_arrivals_closed_from_many_threads_strand_no_submission():
+    """More closers than cores, a short switch interval: every submission
+    resolves long before the window, and no arrival stays on the books."""
+    import sys
+
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+    n = 32
+
+    async def go():
+        strangers = [ex.announce("prep_init") for _ in range(n)]
+        workers = [threading.Thread(target=a.close) for a in strangers]
+
+        async def job(i):
+            with ex.announce("prep_init"):
+                await asyncio.sleep(0.001 * i)
+                return await ex.submit(("s",), "prep_init", (b"k", [i]), backend=backend)
+
+        jobs = [asyncio.ensure_future(job(i)) for i in range(n)]
+        for w in workers:
+            w.start()
+        outs = await asyncio.wait_for(asyncio.gather(*jobs), WINDOW_S - 0.5)
+        for w in workers:
+            w.join(5)
+            assert not w.is_alive()
+        return outs
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        outs = _run(go())
+    finally:
+        sys.setswitchinterval(interval)
+    ex.shutdown()
+    assert [len(o) for o in outs] == [1] * n
+    assert sum(len(l) for l in backend.launches) == n
+    assert {t[1] for t in _triggers(ex)} == {"arrived"}
+    assert not ex._arrivals
+
+
+def test_flush_trigger_counter_carries_the_arrived_label():
+    from janus_tpu.core.metrics import GLOBAL_METRICS
+
+    if GLOBAL_METRICS.registry is None:
+        pytest.skip("no metrics registry in this process")
+    backend = _FakeBackend()
+    ex = _announcing_executor()
+
+    async def go():
+        with ex.announce("prep_init"):
+            return await ex.submit(("s",), "prep_init", (b"k", [0]), backend=backend)
+
+    label = bucket_label(backend, "prep_init", 0, ("s",))
+    counter = GLOBAL_METRICS.executor_flushes.labels(bucket=label, trigger="arrived")
+    before = counter._value.get()
+    _run(go())
+    ex.shutdown()
+    assert counter._value.get() == before + 1
+    assert 'janus_executor_flushes_total{bucket="%s",trigger="arrived"}' % label in (
+        GLOBAL_METRICS.export().decode()
+    )
