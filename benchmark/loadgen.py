@@ -21,6 +21,8 @@ import random
 import sys
 import time
 
+from vdafs import family
+
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -67,12 +69,7 @@ def schedule(traffic, seconds, seed):
 
 def measurements(vdaf_desc, n, rng):
     """Measurements a client of this VDAF would send, drawn from ``rng``."""
-    kind = vdaf_desc["type"]
-    if kind == "Prio3Histogram":
-        return [rng.randrange(vdaf_desc["length"]) for _ in range(n)]
-    if kind == "Prio3Count":
-        return [rng.randrange(2) for _ in range(n)]
-    raise ValueError(f"no measurement generator for {kind}")
+    return family(vdaf_desc).measurements(vdaf_desc, n, rng)
 
 
 # -- worker process ----------------------------------------------------------

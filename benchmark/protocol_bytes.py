@@ -19,32 +19,16 @@ Per report, both aggregators' ``prep_init`` and the combine:
 
 from __future__ import annotations
 
+from vdafs import family
+
 NONCE = 16
 SEED = 16  # XofTurboShake128.SEED_SIZE
 
 
-def _next_pow2(n):
-    return 1 << (n - 1).bit_length()
-
-
 def flp_lengths(vdaf):
     """(field bytes, MEAS_LEN, OUTPUT_LEN, JOINT_RAND_LEN, PROOF_LEN,
-    VERIFIER_LEN) of a Prio3 instance described as the task's ``vdaf``."""
-    kind = vdaf["type"]
-    if kind == "Prio3Count":
-        # Mul gadget, arity 2, degree 2, one call
-        field, meas, out, jr, arity, degree, calls = 8, 1, 1, 0, 2, 2, 1
-    elif kind == "Prio3Histogram":
-        # ParallelSum(Mul, chunk): arity 2*chunk, degree 2
-        length, chunk = vdaf["length"], vdaf["chunk_length"]
-        field, meas, out, jr, arity, degree = 16, length, length, 2, 2 * chunk, 2
-        calls = -(-length // chunk)
-    else:
-        raise ValueError(f"no length table for {kind}")
-    p = _next_pow2(1 + calls)
-    proof = arity + degree * (p - 1) + 1
-    verifier = 1 + arity + 1
-    return field, meas, out, jr, proof, verifier
+    VERIFIER_LEN) of the instance described as the task's ``vdaf``."""
+    return family(vdaf).flp_lengths(vdaf)
 
 
 def prepare_bytes_per_report(vdaf):

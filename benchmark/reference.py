@@ -1,23 +1,17 @@
-"""The plain reference: count the measurements.
+"""The plain reference and the comparison.
 
 It imports nothing of the program and takes nothing that the program made.
-A Prio3Histogram aggregate is how many clients reported each bucket; a
-Prio3Count aggregate is how many reported 1.
+What an aggregate of the measurements is, the VDAF's family says
+(``vdafs/<type>.py``).
 """
 
 from __future__ import annotations
 
+from vdafs import family
+
 
 def plain_aggregate(vdaf, measurements):
-    kind = vdaf["type"]
-    if kind == "Prio3Histogram":
-        out = [0] * vdaf["length"]
-        for m in measurements:
-            out[m] += 1
-        return out
-    if kind == "Prio3Count":
-        return sum(measurements)
-    raise ValueError(f"no plain reference for {kind}")
+    return family(vdaf).plain_aggregate(vdaf, measurements)
 
 
 def mismatched_positions(got, want):

@@ -65,8 +65,8 @@ def test_every_phase_scope_and_kind_a_metric_names_is_in_the_programs_table():
 
 def test_a_rehearsal_reports_all_eight_phase_metrics():
     manifest = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    appended = [m["name"] for m in manifest["per_layer"][-len(PHASE_METRICS):]]
-    assert set(appended) == PHASE_METRICS  # at the end of the list, as added
+    # looked up by name: later PRs append their own metrics after these
+    assert PHASE_METRICS <= {m["name"] for m in manifest["per_layer"]}
     # the rehearsal as test_run_rehearsal.py runs it, read for its "leg" line;
     # 8 s, so that one whole pass of the creator (every 5 s) and its jobs'
     # steps lie inside the window wherever the pass falls
