@@ -286,9 +286,13 @@ class Fleet:
             # no device path (the oracle): the executor schedules no warmup
             return {"backend": type(backend).__name__, "ledger": {}}
         ex = peek_global_executor()
-        warm = await asyncio.get_running_loop().run_in_executor(
-            None, lambda: ex.wait_warm(backend_shape_key(backend))
-        )
+        loop, key = asyncio.get_running_loop(), backend_shape_key(backend)
+        # waited for a second at a time: a run that gives up in set-up then
+        # leaves no thread of the loop's pool waiting out a compile
+        while True:
+            warm = await loop.run_in_executor(None, ex.wait_warm, key, 1.0)
+            if warm or not any(s["state"] == "warming" for s in ex.compile_stats().values()):
+                break
         if not warm:
             raise FleetFailure(f"warmup of {name} failed: {ex.compile_stats()}")
         return {

@@ -4,8 +4,9 @@ The schedule and the arithmetic follow ``tools/loadgen.py`` (open loop,
 nearest-rank percentile); what differs is where the reports are made and
 sent.  Here each worker process makes its slice of the reports in set-up
 and later sends that slice itself at the due times, so a 19 KB report never
-crosses a pipe and neither sealing nor sending shares the fleet's GIL.  The
-workers never import JAX: the chip belongs to the parent.
+crosses a pipe and neither sealing nor sending shares the fleet's GIL.  (Only
+the bodies of set-up's probe batch, one a worker, go back to the parent, which
+sends them.)  The workers never import JAX: the chip belongs to the parent.
 
 Every upload is timed from its DUE time (``CLOCK_MONOTONIC`` is one clock
 for all processes of a host), not from when it was sent, so a stall of the
@@ -160,9 +161,20 @@ def _worker(conn):
             msg = conn.recv()
             if msg[0] == "stop":
                 return
-            if msg[0] == "make":
+            if msg[0] == "probe":
+                # set-up's probe: made here, sent by the parent, so the
+                # bodies go back (one report a worker, made before any other)
                 t0 = time.monotonic()
-                reports, url = _make_reports(msg[1]), msg[1]["url"]
+                made = _make_reports(msg[1])
+                conn.send(("probe_made", [m[3] for m in made], time.monotonic() - t0, os.getpid()))
+            elif msg[0] == "make":
+                t0 = time.monotonic()
+                job, url = msg[1], msg[1]["url"]
+                # the first report alone, and word of it: what one report
+                # costs here, long before the slice is done
+                reports = _make_reports({**job, "items": job["items"][:1]})
+                conn.send(("first", time.monotonic() - t0))
+                reports += _make_reports({**job, "items": job["items"][1:]})
                 conn.send(("made", len(reports), time.monotonic() - t0))
             elif msg[0] == "go":
                 records = asyncio.run(_send_all(url, reports, msg[1], msg[2]))
@@ -173,11 +185,15 @@ def _worker(conn):
 
 
 class Senders:
-    """A pool of sender processes.  ``make`` hands each its slice (round
-    robin, so every worker sends at an even share of the rate) and returns at
-    once; ``wait_made`` blocks until every report exists; ``go`` fixes the
+    """A pool of sender processes.  ``make_probe`` hands the first workers
+    one report each of set-up's probe batch, whose bodies ``wait_probe``
+    brings back; ``make`` hands each worker its slice (round robin, so every
+    worker sends at an even share of the rate) and returns at once;
+    ``wait_first`` blocks until every worker has made one report of its
+    slice; ``wait_made`` blocks until every report exists; ``go`` fixes the
     start of the lead-in on the shared monotonic clock; ``results`` blocks
-    until every upload was answered or timed out."""
+    until every upload was answered or timed out.  A worker answers in the
+    order it was asked, so the waits are called in that order."""
 
     def __init__(self, workers):
         # spawn, not fork: the parent has threads and holds the chip
@@ -190,19 +206,57 @@ class Senders:
             child.close()
             self._procs.append(proc)
             self._conns.append(parent)
-        self._busy = []
+        self._busy, self._probing = [], []
+        self.slice_sizes = []
+
+    def __len__(self):
+        return len(self._conns)
+
+    @staticmethod
+    def _next(conn, kind):
+        """The worker's next message of this kind.  An earlier "first" that
+        nobody waited for is passed over."""
+        while True:
+            msg = conn.recv()
+            if msg[0] == kind:
+                return msg
+            if msg[0] != "first":
+                raise RuntimeError(f"a sender answered {msg[0]!r} where {kind!r} was due")
+
+    def make_probe(self, job, items):
+        """One item a worker, to as many workers as there are items."""
+        self._probing = self._conns[: len(items)]
+        for conn, item in zip(self._probing, items):
+            conn.send(("probe", {**job, "items": [item]}))
+
+    def wait_probe(self):
+        """(the probe's report bodies, the slowest worker's seconds, the
+        pids that made them)."""
+        made = [self._next(conn, "probe_made") for conn in self._probing]
+        self._probing = []
+        return (
+            [body for m in made for body in m[1]],
+            max(m[2] for m in made),
+            [m[3] for m in made],
+        )
 
     def make(self, job, items):
-        self._busy = []
+        self._busy, self.slice_sizes = [], []
         for w, conn in enumerate(self._conns):
             mine = items[w :: len(self._conns)]
             if mine:
                 conn.send(("make", {**job, "items": mine}))
                 self._busy.append(conn)
+                self.slice_sizes.append(len(mine))
+
+    def wait_first(self):
+        """Seconds each worker took to its slice's first report, in the
+        order of ``slice_sizes``."""
+        return [self._next(conn, "first")[1] for conn in self._busy]
 
     def wait_made(self):
         """(reports made, the slowest worker's seconds)."""
-        made = [conn.recv() for conn in self._busy]
+        made = [self._next(conn, "made") for conn in self._busy]
         return sum(m[1] for m in made), max(m[2] for m in made)
 
     def go(self, t0, timeout_s=60.0):
@@ -218,8 +272,11 @@ class Senders:
                 conn.send(("stop",))
             except (BrokenPipeError, OSError):
                 pass
+        # a worker in the middle of its slice reads no "stop": one deadline
+        # for all of them, then the rest are killed
+        deadline = time.monotonic() + 10.0
         for proc in self._procs:
-            proc.join(timeout=10)
+            proc.join(timeout=max(0.0, deadline - time.monotonic()))
             if proc.is_alive():
                 proc.kill()
                 proc.join()

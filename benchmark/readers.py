@@ -6,7 +6,9 @@ arguments, and returns a number or ``None`` where it finds nothing to read —
 the harness then leaves the metric out of the line.  None returns 0 for a
 share of a peak.
 
-``rec`` holds: ``seconds`` (the window), ``setup_s``, ``uploads`` (one dict
+``rec`` holds: ``seconds`` (the window), ``setup_s``, ``setup_phases`` (seconds
+of set-up's phases on the harness's monotonic clock, ``run.py``
+``SETUP_PHASES``), ``uploads`` (one dict
 per upload due in the window: ``late_s``, ``ack_s`` or ``None``, ``lag_s`` or
 ``None``), ``finished_in_window`` (reports in leader jobs first seen FINISHED
 inside the window), ``reports_aggregated`` (all of the run), ``prom`` (snapshots at the window's
@@ -23,6 +25,12 @@ from loadgen import percentile
 
 def setup(rec, args):
     return rec["setup_s"]
+
+
+def setup_phase(rec, args):
+    """Seconds of one phase of set-up; nothing where the run had no such
+    phase."""
+    return rec.get("setup_phases", {}).get(args["phase"])
 
 
 def window_rate(rec, args):
@@ -108,6 +116,7 @@ def trace_ratio(rec, args):
 
 KINDS = {
     "setup": setup,
+    "setup_phase": setup_phase,
     "window_rate": window_rate,
     "upload_percentile": upload_percentile,
     "sender": sender,

@@ -42,8 +42,16 @@ sys.path[:0] = [HERE, ROOT]
 TRACE_SLICE_S = 20.0
 #: the drain gives up after this long; what is left then is ``failed``
 DRAIN_LIMIT_S = 120.0
+#: a run ends this long after its process started, or the driver stops it ...
+RUN_LIMIT_S = 360.0
+#: ... and this long where it compiled: the first run of a cell in a checkout
+RUN_LIMIT_COMPILED_S = 1200.0
+#: what the drain and the collection take after the window, as the fit rule counts them
+AFTER_WINDOW_S = 30.0
 #: an upload with no answer in this long has failed
 UPLOAD_TIMEOUT_S = 60.0
+#: set-up's phases, as the record carries their seconds (``setup_phases``)
+SETUP_PHASES = ("reports_made", "prep_warm", "probe", "programs_warm", "wait_made")
 #: idle gaps shorter than this lie between the ops of one device program
 SHORT_GAP_NS = 100_000.0
 MARK = "bench_slice_mark"
@@ -56,6 +64,23 @@ class RunFailure(Exception):
 def log(obj):
     """One JSON object on an earlier line of standard output."""
     print(json.dumps(obj), flush=True)
+
+
+def fits(elapsed_s, lead_in_s, seconds, compiled, phases, projected=False):
+    """``None`` where a run whose set-up takes ``elapsed_s`` ends inside the
+    limit the driver holds it to, else the reason why it cannot: the lead-in,
+    the window and ``AFTER_WINDOW_S`` of drain and collection still follow.
+    ``phases`` are the seconds that the reason names."""
+    limit = RUN_LIMIT_COMPILED_S if compiled else RUN_LIMIT_S
+    end = elapsed_s + lead_in_s + seconds + AFTER_WINDOW_S
+    if end <= limit:
+        return None
+    split = ", ".join(f"{name.replace('_', ' ')} {s:.0f}" for name, s in phases.items())
+    return (
+        f"set-up {'is projected to take' if projected else 'took'} {elapsed_s:.0f} s ({split}); "
+        f"lead-in, window and drain would end at {end:.0f} s, over the {limit:.0f} s a run"
+        f"{' that compiled' if compiled else ''} may last"
+    )
 
 
 def load_json(*parts):
@@ -287,23 +312,14 @@ async def helper_backend(fleet, task_id):
     return (await fleet.aggregators["helper"].task_aggregator_for(task_id)).backend
 
 
-async def warm_everything(ctx, name):
-    """The executor's own warmup of ``prep_init`` (running since the
-    backend was made), then one small batch through a throw-away task with
-    the probe on, then combine and the leader's aggregate at every size."""
-    from fleet import JobWatch
-    from loadgen import _make_reports, measurements
+def start_probe(ctx):
+    """Set-up's probe batch: a throw-away task, and one report of it asked of
+    every sender before anything else, so that it costs one client call's
+    time and the main process makes no report.  Two reports or more: one
+    finished job of that many is enough (the creator may cut the batch)."""
+    from loadgen import measurements
 
-    fleet, config = ctx["fleet"], ctx["config"]
-    loop = asyncio.get_running_loop()
-    t0 = time.monotonic()
-    info = await fleet.warm(name)
-    log({"warmup": "prep_init", "seconds": round(time.monotonic() - t0, 1), **info})
-    shared = fleet.backend(name)
-    if not hasattr(shared, "_combine"):
-        return
-    t0 = time.monotonic()
-    rows = 16
+    fleet, config, senders = ctx["fleet"], ctx["config"], ctx["senders"]
     task_id, leader_cfg, helper_cfg = fleet.add_task("probe")
     rng = random.Random("probe")
     job = {
@@ -312,20 +328,59 @@ async def warm_everything(ctx, name):
         "leader_cfg": leader_cfg.get_encoded(),
         "helper_cfg": helper_cfg.get_encoded(),
         "time_s": ctx["time_s"],
-        "items": [
-            (i, m, rng.getrandbits(64), 0.0)
-            for i, m in enumerate(measurements(config["vdaf"], rows, rng))
-        ],
     }
-    made = await loop.run_in_executor(None, _make_reports, job)
+    items = [
+        (i, m, rng.getrandbits(64), 0.0)
+        for i, m in enumerate(measurements(config["vdaf"], len(senders), rng))
+    ]
+    senders.make_probe(job, items)
+    made = asyncio.get_running_loop().run_in_executor(None, senders.wait_probe)
+    return {"task_id": task_id, "rows": len(items), "made": made}
+
+
+async def first_reports_fit(ctx, batch, t_make, lead_in, seconds):
+    """As soon as every sender has made one report of its slice: reports a
+    worker x that time is how long the making will take, and a run that this
+    alone carries past its limit ends here, minutes sooner.  No program taken
+    from the cache yet means the run may still be one that compiles."""
+    senders, events = ctx["senders"], ctx["cache_events"]
+    await asyncio.wait([batch["made"]])  # the senders answer in the order asked
+    firsts = await asyncio.get_running_loop().run_in_executor(None, senders.wait_first)
+    projected = max(n * s for n, s in zip(senders.slice_sizes, firsts))
+    log({"first_reports_s": [round(s, 3) for s in firsts], "reports_a_worker":
+         max(senders.slice_sizes), "reports_made_projected_s": round(projected, 1)})
+    reason = fits(t_make - T0 + projected, lead_in, seconds,
+                  events["misses"] > 0 or not events["hits"],
+                  {"reports_made": projected}, projected=True)
+    if reason:
+        raise RunFailure(reason)
+
+
+async def warm_everything(ctx, name, batch, phases):
+    """The executor's own warmup of ``prep_init`` (running since the
+    backend was made), then the probe batch that the senders made through its
+    throw-away task with the shape probe on, then combine and the leader's
+    aggregate at every size."""
+    from fleet import JobWatch
+
+    fleet, config = ctx["fleet"], ctx["config"]
+    loop = asyncio.get_running_loop()
+    t0 = time.monotonic()
+    info = await fleet.warm(name)
+    t1 = time.monotonic()
+    phases["prep_warm"] = t1 - t0
+    log({"warmup": "prep_init", "seconds": round(t1 - t0, 1), **info})
+    shared = fleet.backend(name)
+    if not hasattr(shared, "_combine"):
+        return
+    bodies, made_s, pids = await batch["made"]
+    task_id, rows = batch["task_id"], batch["rows"]
     probe = ShapeProbe(shared, await helper_backend(fleet, task_id))
     watch = JobWatch(fleet, task_id)
     watch.start()
     try:
-        await put_reports(f"{fleet.urls['leader']}tasks/{task_id}/reports", [m[3] for m in made])
+        await put_reports(f"{fleet.urls['leader']}tasks/{task_id}/reports", bodies)
         deadline = time.monotonic() + 300.0
-        # one finished job of two reports or more is enough (the creator may
-        # cut the batch into several)
         while not (watch.finished_at and probe.complete()):
             if time.monotonic() > deadline or watch.jobs_abandoned:
                 raise RunFailure(
@@ -336,6 +391,10 @@ async def warm_everything(ctx, name):
     finally:
         watch.stop()
         probe.remove()
+    t2 = time.monotonic()
+    phases["probe"] = t2 - t1
+    log({"warmup": "probe", "seconds": round(t2 - t1, 1), "rows": rows,
+         "slowest_worker_s": round(made_s, 2), "made_by": pids, "main_pid": os.getpid()})
     ctx["probe"] = probe
     ctx["combine_rows"] = pow2_up_to(1, config["device_executor"]["warmup_rows"])
     ctx["aggregate_rows"] = pow2_up_to(2, config["job_creator"]["max_aggregation_job_size"])
@@ -345,10 +404,11 @@ async def warm_everything(ctx, name):
             None, probe.warm_aggregate, "shared", shared, ctx["aggregate_rows"]
         ),
     }
-    log({"warmup": "combine+aggregate", "seconds": round(time.monotonic() - t0, 1), **done})
+    phases["programs_warm"] = time.monotonic() - t2
+    log({"warmup": "combine+aggregate", "seconds": round(phases["programs_warm"], 1), **done})
 
 
-async def warm_task(ctx, task_id):
+async def warm_task(ctx, task_id, phases):
     """The helper's aggregate program is jitted per task: compile this
     task's at every job size."""
     probe = ctx.get("probe")
@@ -359,6 +419,7 @@ async def warm_task(ctx, task_id):
     done = await asyncio.get_running_loop().run_in_executor(
         None, probe.warm_aggregate, "helper_own", own, ctx["aggregate_rows"]
     )
+    phases["programs_warm"] += time.monotonic() - t0
     log({"warmup": "helper aggregate", "seconds": round(time.monotonic() - t0, 1), "rows": done})
 
 
@@ -525,22 +586,46 @@ async def run_leg(ctx, index, seed, rate, seconds):
         "time_s": ctx["time_s"],
         "url": f"{fleet.urls['leader']}tasks/{task_id}/reports",
     }
+    phases = dict.fromkeys(SETUP_PHASES, 0.0)
+    # the first leg's probe goes to the senders before their slices
+    batch = start_probe(ctx) if index == 0 else None
+    t_make = time.monotonic()
     senders.make(job, [(i, meas[i], rng.getrandbits(64), dues[i]) for i in range(len(dues))])
-    if index == 0:
-        await warm_everything(ctx, name)
-        if args.fault in ("alter", "half_batch"):
-            ctx["fault"] = plant_fault(args.fault, backend)
-    if "fault" in ctx:
-        ctx["fault"]["calls"] = 0  # every leg gets its fault
-    await warm_task(ctx, task_id)
-    made, made_s = await loop.run_in_executor(None, senders.wait_made)
-    log({"reports_made": made, "slowest_worker_s": round(made_s, 1), "rate": rate})
+
+    async def main_path():
+        if index == 0:
+            await warm_everything(ctx, name, batch, phases)
+            if args.fault in ("alter", "half_batch"):
+                ctx["fault"] = plant_fault(args.fault, backend)
+        if "fault" in ctx:
+            ctx["fault"]["calls"] = 0  # every leg gets its fault
+        await warm_task(ctx, task_id, phases)
+
+    tasks = [asyncio.ensure_future(main_path())]
+    if index == 0:  # the run's limit is on its first leg; later ones are the harness's own
+        tasks.append(asyncio.ensure_future(first_reports_fit(ctx, batch, t_make, lead_in, seconds)))
+    try:
+        await asyncio.gather(*tasks)  # whichever fails first ends the leg
+    except BaseException:
+        for task in tasks:
+            task.cancel()
+        raise
+    t0 = time.monotonic()
+    made, phases["reports_made"] = await loop.run_in_executor(None, senders.wait_made)
+    phases["wait_made"] = time.monotonic() - t0
+    log({"reports_made": made, "slowest_worker_s": round(phases["reports_made"], 1), "rate": rate})
 
     snaps_run = {"start": prom.snapshot()}
     served0 = served_counters(snaps_run["start"])
     t_start = time.monotonic() + 0.5
     setup_s = t_start - T0
+    if index == 0:
+        reason = fits(setup_s, lead_in, seconds, ctx["cache_events"]["misses"] > 0, phases)
+        if reason:
+            raise RunFailure(reason)
     senders.go(t_start, UPLOAD_TIMEOUT_S)
+    log({"setup_s": round(setup_s, 2), "setup_phases": {k: round(v, 2) for k, v in phases.items()},
+         "before_warm_s": round(t_make - T0, 2), "cache_events": dict(ctx["cache_events"])})
     t_open, t_close = t_start + lead_in, t_start + lead_in + seconds
     watch = JobWatch(fleet, task_id)
     watch.start()
@@ -634,6 +719,7 @@ async def run_leg(ctx, index, seed, rate, seconds):
     rec = {
         "seconds": float(seconds),
         "setup_s": setup_s,
+        "setup_phases": phases,
         "uploads": uploads,
         # every report, of the lead-in's backlog too, in a job first seen
         # FINISHED inside the window
@@ -697,6 +783,8 @@ async def run_leg(ctx, index, seed, rate, seconds):
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # set-up's split needs no trace: on the line of either kind of run
+    result["setup_phases"] = phases
     if args.rehearse:
         result["rehearsal_values"] = {k: v["value"] for k, v in metrics.items()}
         result["metrics"] = {}
@@ -829,5 +917,17 @@ def main(argv=None):
     return 0
 
 
+def leave(code):
+    """Exit.  A run that gave up in set-up has stopped what it started, but
+    the program's warm-up may still compile on a thread that nobody can
+    stop, and an orderly exit would wait minutes for it: a failed run goes
+    at once."""
+    sys.stdout.flush()
+    sys.stderr.flush()
+    if code:
+        os._exit(code)
+    sys.exit(0)
+
+
 if __name__ == "__main__":
-    sys.exit(main())
+    leave(main())

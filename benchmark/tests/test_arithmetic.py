@@ -265,7 +265,7 @@ def test_protocol_lengths_agree_with_the_program():
     from test_vdafs import committed_vdafs
 
     descs = committed_vdafs()
-    assert {"type": "Prio3Histogram", "length": 8, "chunk_length": 3} in descs  # a rehearsal's
+    assert {"type": "Prio3Histogram", "length": 3, "chunk_length": 2} in descs  # a rehearsal's
     for desc in descs:
         flp = vdaf_from_instance(desc).flp
         assert protocol_bytes.flp_lengths(desc) == (
