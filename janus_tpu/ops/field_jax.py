@@ -168,23 +168,23 @@ class JField:
         )
 
     def to_limbs(self, values: Sequence[int]) -> np.ndarray:
-        """Host: python ints -> (..., n) u32 canonical limbs."""
-        flat = np.empty((len(values), self.n), dtype=np.uint32)
-        for i, v in enumerate(values):
-            for j in range(self.n):
-                flat[i, j] = (v >> (32 * j)) & 0xFFFFFFFF
-        return flat
+        """Host: python ints -> (len, n) u32 canonical limbs.
+
+        An element's ``4 n`` little-endian bytes ARE its limbs, so the whole
+        vector crosses in one byte buffer.  A value outside ``[0, 2**(32 n))``
+        is no field element and raises ``OverflowError``; it is not masked."""
+        size = 4 * self.n
+        buf = bytearray().join([v.to_bytes(size, "little") for v in values])
+        flat = np.frombuffer(buf, dtype="<u4").astype(np.uint32, copy=False)
+        return flat.reshape(len(values), self.n)
 
     def from_limbs(self, limbs: np.ndarray) -> List[int]:
         """Host: (..., n) u32 canonical limbs -> python ints (flattened)."""
-        arr = np.asarray(limbs, dtype=np.uint32).reshape(-1, self.n)
-        out = []
-        for row in arr:
-            v = 0
-            for j in range(self.n):
-                v |= int(row[j]) << (32 * j)
-            out.append(v)
-        return out
+        arr = np.ascontiguousarray(limbs, dtype="<u4").reshape(-1, self.n)
+        # numpy cuts out each element's bytes (a void cell's tolist())
+        cells = arr.view(f"V{4 * self.n}").ravel().tolist()
+        from_bytes = int.from_bytes  # looked up once, not once an element
+        return [from_bytes(c, "little") for c in cells]
 
     def const(self, value: int) -> jnp.ndarray:
         """Canonical constant as a device limb vector."""

@@ -427,14 +427,14 @@ class TpuBackend:
         oracle = self.oracle_for(actual_vdaf)
         B = len(reports)
         ok = np.asarray(out["ok"])[:B]
-        verifiers = np.asarray(out["verifiers"])[:B]
+        verifiers = jf.from_limbs(np.asarray(out["verifiers"])[:B])  # once a flush
         if resident is None:
-            out_shares = np.asarray(out["out_share"])[:B]
+            out_shares = jf.from_limbs(np.asarray(out["out_share"])[:B, :out_len])
         else:
             from ..executor.accumulator import ResidentRef
 
             flush_id, start_row = resident
-        has_jr = flp.JOINT_RAND_LEN > 0
+        has_jr, ver_len = flp.JOINT_RAND_LEN > 0, flp.VERIFIER_LEN * self.vdaf.num_proofs
         if has_jr:
             parts = np.asarray(out["joint_rand_part"])[:B]
             corrected = np.asarray(out["corrected_seed"])[:B]
@@ -448,13 +448,13 @@ class TpuBackend:
                 )
                 continue
             state = Prio3PrepareState(
-                out_share=jf.from_limbs(out_shares[b, :out_len])
+                out_share=out_shares[b * out_len : (b + 1) * out_len]
                 if resident is None
                 else ResidentRef(flush_id, start_row + b),
                 corrected_joint_rand_seed=corrected[b].tobytes() if has_jr else None,
             )
             share = Prio3PrepareShare(
-                verifiers_share=jf.from_limbs(verifiers[b]),
+                verifiers_share=verifiers[b * ver_len : (b + 1) * ver_len],
                 joint_rand_part=parts[b].tobytes() if has_jr else None,
             )
             results.append((state, share))
@@ -1005,9 +1005,9 @@ class HybridXofBackend:
             self._query_fn = self._jax.jit(self.bp.query_batch)
         out = self._query_fn(meas_l, proofs_l, jr_l, qr_l)
         ok = np.asarray(out["ok"])[:B]
-        verifiers = np.asarray(out["verifiers"])[:B]
-        out_shares = np.asarray(out["out_share"])[:B]
-
+        verifiers = jf.from_limbs(np.asarray(out["verifiers"])[:B])
+        out_shares = jf.from_limbs(np.asarray(out["out_share"])[:B])
+        ver_len, out_len = flp.VERIFIER_LEN * vdaf.num_proofs, flp.OUTPUT_LEN
         results: List[PrepOutcome] = []
         for b in range(B):
             if not ok[b]:
@@ -1029,11 +1029,11 @@ class HybridXofBackend:
                 )
                 continue
             state = Prio3PrepareState(
-                out_share=jf.from_limbs(out_shares[b]),
+                out_share=out_shares[b * out_len : (b + 1) * out_len],
                 corrected_joint_rand_seed=corrected[b],
             )
             share = Prio3PrepareShare(
-                verifiers_share=jf.from_limbs(verifiers[b]),
+                verifiers_share=verifiers[b * ver_len : (b + 1) * ver_len],
                 joint_rand_part=parts[b],
             )
             results.append((state, share))

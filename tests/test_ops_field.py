@@ -5,7 +5,7 @@ import random
 import numpy as np
 import pytest
 
-from janus_tpu.fields import Field64, Field128
+from janus_tpu.fields import Field64, Field128, Field255
 from janus_tpu.ops.field_jax import JField
 
 FIELDS = [Field64, Field128]
@@ -33,6 +33,71 @@ def test_limb_roundtrip(field):
     vals = _edge_values(field) + [12345678901234567890 % field.MODULUS]
     limbs = jf.to_limbs(vals)
     assert jf.from_limbs(limbs) == vals
+
+
+def _loop_to_limbs(values, n):
+    """The per-limb loops the byte-buffer conversion replaced: the reference."""
+    flat = np.empty((len(values), n), dtype=np.uint32)
+    for i, v in enumerate(values):
+        for j in range(n):
+            flat[i, j] = (v >> (32 * j)) & 0xFFFFFFFF
+    return flat
+
+
+def _loop_from_limbs(limbs, n):
+    out = []
+    for row in np.asarray(limbs, dtype=np.uint32).reshape(-1, n):
+        v = 0
+        for j in range(n):
+            v |= int(row[j]) << (32 * j)
+        out.append(v)
+    return out
+
+
+@pytest.mark.parametrize("field", FIELDS + [Field255])
+def test_limb_conversion_matches_per_limb_loops(field):
+    jf = JField(field)
+    n, p = jf.n, field.MODULUS
+    assert n == field.ENCODED_SIZE // 4
+    rng = random.Random(30)
+    bounds = [(1 << (32 * j)) + d for j in range(1, n) for d in (-1, 0, 1)]
+    vals = [0, 1, p - 1] + [b for b in bounds if b < p]
+    vals += [rng.randrange(p) for _ in range(1000)]
+    limbs = jf.to_limbs(vals)
+    assert limbs.dtype == np.uint32 and limbs.flags.c_contiguous
+    assert limbs.shape == (len(vals), n)
+    assert np.array_equal(limbs, _loop_to_limbs(vals, n))
+    assert jf.from_limbs(limbs) == vals == _loop_from_limbs(limbs, n)
+    # what the callers do next: write into it, or hand it to the device
+    limbs[0, 0] = 7
+    assert jf.from_limbs(jf.add(limbs[1:3], limbs[1:3])) == [2, (2 * (p - 1)) % p]
+
+    empty = jf.to_limbs([])
+    assert empty.shape == (0, n) and empty.dtype == np.uint32
+    assert jf.from_limbs(empty) == []
+
+    # a (B, L, n) matrix flattens row-major; a strided view reads its own rows
+    cube = _loop_to_limbs(vals[:24], n).reshape(4, 6, n)
+    assert jf.from_limbs(cube) == vals[:24]
+    strided = cube[::2, 1:4]
+    assert not strided.flags.c_contiguous
+    assert jf.from_limbs(strided) == _loop_from_limbs(
+        np.ascontiguousarray(strided), n
+    ) == [vals[r * 6 + c] for r in (0, 2) for c in (1, 2, 3)]
+    with pytest.raises(ValueError):
+        jf.from_limbs(np.zeros(n + 1, dtype=np.uint32))
+
+
+@pytest.mark.parametrize("field", FIELDS + [Field255])
+def test_to_limbs_refuses_what_it_used_to_mask(field):
+    """A value of 32 n bits or more, or a negative one, is no field element:
+    the per-limb loop masked the one and two's-complemented the other."""
+    jf = JField(field)
+    top = 1 << (32 * jf.n)
+    assert jf.from_limbs(jf.to_limbs([top - 1])) == [top - 1]
+    for bad in (top, -1):
+        with pytest.raises(OverflowError):
+            jf.to_limbs([0, bad])
 
 
 @pytest.mark.parametrize("field", FIELDS)

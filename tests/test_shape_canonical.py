@@ -155,12 +155,17 @@ def _assert_parity(backend, vdaf, meas_list, agg_id, seed="p"):
         backend.stage_prep_init_multi(agg_id, reqs), reqs
     )[0]
     want = OracleBackend(vdaf).prep_init_batch(vk, agg_id, rows)
+    _assert_same_outcomes(got, want, agg_id)
+    return got, want
+
+
+def _assert_same_outcomes(got, want, agg_id):
+    assert len(got) == len(want)
     for i, (g, w) in enumerate(zip(got, want)):
         assert g[0].out_share == w[0].out_share, (agg_id, i)
         assert g[0].corrected_joint_rand_seed == w[0].corrected_joint_rand_seed
         assert g[1].verifiers_share == w[1].verifiers_share, (agg_id, i)
         assert g[1].joint_rand_part == w[1].joint_rand_part, (agg_id, i)
-    return got, want
 
 
 @pytest.fixture(scope="module")
@@ -195,6 +200,35 @@ def test_histogram_padded_parity_and_mixed_batch(hist_canonical_backend):
                 assert g[1].joint_rand_part == w[1].joint_rand_part
             # out shares come back at the TASK's length, not the bucket's
             assert all(len(g[0].out_share) == vdaf.flp.OUTPUT_LEN for g in got)
+
+
+@pytest.mark.parametrize("agg_id", [0, 1])
+def test_unmarshal_slices_each_row_from_the_flush(hist_canonical_backend, agg_id):
+    """The out-share and verifier matrices become ints once a flush and are
+    sliced per row: with a bucket wider than the task and a row sent to the
+    oracle in the middle, every row still gets ITS OWN slice, in order."""
+    backend = hist_canonical_backend
+    h5 = prio3_histogram(5, 2)
+    assert backend.vdaf.flp.OUTPUT_LEN > h5.flp.OUTPUT_LEN
+    vk = b"\x07" * h5.VERIFY_KEY_SIZE
+    rows = _reports(h5, [0, 4, 2, 1, 3], "slice" + str(agg_id), agg_id)
+    staged = backend.stage_prep_init_multi(agg_id, [(vk, rows, h5)])
+    out = {
+        k: np.array(v)[: len(rows)]
+        for k, v in backend._prep_fn(agg_id)(staged.placed).items()
+    }
+    assert out["ok"].all()
+    # row 2 overflowed the device margin, says the device: its limbs are
+    # junk and the oracle serves it
+    out["ok"][2] = False
+    out["out_share"][2] = 0xFFFFFFFF
+    out["verifiers"][2] = 0xFFFFFFFF
+    got = backend._unmarshal_prep(vk, agg_id, rows, out, actual_vdaf=h5)
+    _assert_same_outcomes(
+        got, OracleBackend(h5).prep_init_batch(vk, agg_id, rows), agg_id
+    )
+    # distinct measurements: a row's share is no neighbour's
+    assert len({tuple(g[0].out_share) for g in got}) == len(rows)
 
 
 def test_combine_through_canonical_backend(hist_canonical_backend):
