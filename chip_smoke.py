@@ -2,10 +2,11 @@
 """chip_smoke.py — the quickest proof that janus_tpu still starts on the chip.
 
 Drives the served two-party aggregation path once, on one TPU chip, through
-the classes ``janus_tpu/binaries/main.py`` wires together: a leader and a
-helper ``Aggregator`` behind ``aggregator_app`` on loopback ports, the
-``AggregationJobCreator``, and the aggregation and collection drivers under
-the real ``JobDriver`` loop — real clock, real HTTP, sqlite datastores,
+the composition ``janus_tpu/binaries/main.py`` serves
+(``janus_tpu/binaries/compose.py``): a leader and a helper ``Aggregator``
+behind ``aggregator_app`` on loopback ports, the ``AggregationJobCreator``,
+and the aggregation and collection drivers under the real ``JobDriver``
+loop — real clock, real HTTP, sqlite datastores,
 ``vdaf_backend="tpu"`` and the process-wide device executor.  Clients are
 ``janus_tpu.client.prepare_report`` PUT to ``/tasks/<id>/reports``; the
 result comes back through ``janus_tpu.collector.Collector``.
@@ -191,9 +192,10 @@ def _device_counters():
 
 
 class Fleet:
-    """One leader and one helper, composed the way binaries/main.py
-    composes run_aggregator, run_aggregation_job_creator and
-    _run_job_driver_binary — in THIS process, which holds the chip."""
+    """One leader and one helper, built by the functions of
+    binaries/compose.py that run_aggregator, run_aggregation_job_creator
+    and _run_job_driver_binary of binaries/main.py call — in THIS process,
+    which holds the chip."""
 
     def __init__(self, workdir):
         from janus_tpu.binaries.config import (
@@ -208,6 +210,8 @@ class Fleet:
         from janus_tpu.datastore.crypter import generate_key
 
         self.clock = RealClock()
+        # A smoke's departures from the binaries' defaults, set on the
+        # binaries' own config objects; compose.* maps them as for main.py.
         self.agg_cfg = AggregatorConfig(vdaf_backend="tpu")
         self.drv_cfg = JobDriverBinaryConfig(vdaf_backend="tpu")
         self.creator_cfg = JobCreatorConfig()
@@ -215,7 +219,9 @@ class Fleet:
             cfg.device_executor.enabled = True
             cfg.device_executor.warmup_rows = WARMUP_ROWS
             cfg.device_executor.flush_window_ms = FLUSH_WINDOW_MS
-        self.exec_cfg = self.drv_cfg.device_executor.to_executor_config()
+        # the binaries' default is 10 s; a smoke has no idle fleet to spare,
+        # so it looks for work every second
+        self.drv_cfg.job_driver.job_discovery_interval_s = 1.0
         self.datastores = {
             role: Datastore(
                 os.path.join(workdir, f"{role}.sqlite3"),
@@ -234,47 +240,19 @@ class Fleet:
         self.tasks = {}
 
     async def start(self):
-        import aiohttp
         from aiohttp import web
 
         from janus_tpu.aggregator import (
             Aggregator,
             AggregationJobCreator,
-            AggregationJobDriver,
-            CollectionJobDriver,
-            Config,
-            CreatorConfig,
-            DriverConfig,
-            JobDriver,
             aggregator_app,
         )
-        from janus_tpu.aggregator.collection_job_driver import CollectionDriverConfig
-        from janus_tpu.aggregator.job_driver import acquisition_exclusions
+        from janus_tpu.binaries import compose
         from janus_tpu.core import peer_health
-        from janus_tpu.core.retries import HttpRetryPolicy
-        from janus_tpu.messages import Duration
 
-        a, d = self.agg_cfg, self.drv_cfg
         self.aggregators = {}
         for role, ds in self.datastores.items():
-            agg = Aggregator(
-                ds,
-                self.clock,
-                Config(
-                    max_upload_batch_size=a.max_upload_batch_size,
-                    max_upload_batch_write_delay=a.max_upload_batch_write_delay_ms / 1000.0,
-                    upload_open_backend=a.upload_open_backend,
-                    upload_open_batch_size=a.upload_open_batch_size,
-                    upload_open_batch_delay=a.upload_open_batch_delay_ms / 1000.0,
-                    upload_queue_max=a.upload_queue_max,
-                    upload_shed_delay_s=a.upload_shed_delay_s,
-                    batch_aggregation_shard_count=a.batch_aggregation_shard_count,
-                    task_counter_shard_count=a.task_counter_shard_count,
-                    vdaf_backend=a.vdaf_backend,
-                    field_backend=a.field_backend,
-                    device_executor=self.exec_cfg,
-                ),
-            )
+            agg = Aggregator(ds, self.clock, compose.aggregator_config(self.agg_cfg))
             runner = web.AppRunner(aggregator_app(agg))
             await runner.setup()
             site = web.TCPSite(runner, "127.0.0.1", 0)
@@ -285,92 +263,27 @@ class Fleet:
             self._runners.append(runner)
 
         leader_ds = self.datastores["leader"]
-        jd = d.job_driver
+        jd = self.drv_cfg.job_driver
         peer_health.tracker().configure(
             failure_threshold=jd.peer_failure_threshold,
             suspect_dwell_s=jd.peer_suspect_dwell_s,
         )
         self.creator = AggregationJobCreator(
-            leader_ds,
-            CreatorConfig(
-                min_aggregation_job_size=self.creator_cfg.min_aggregation_job_size,
-                max_aggregation_job_size=self.creator_cfg.max_aggregation_job_size,
-                batch_aggregation_shard_count=self.creator_cfg.batch_aggregation_shard_count,
-                journal_replay_min_age_s=self.creator_cfg.journal_replay_min_age_s,
-            ),
+            leader_ds, compose.creator_config(self.creator_cfg)
         )
-        retry = HttpRetryPolicy(attempt_timeout=jd.http_attempt_timeout_s)
-        self.agg_driver = AggregationJobDriver(
-            leader_ds,
-            aiohttp.ClientSession,
-            DriverConfig(
-                batch_aggregation_shard_count=d.batch_aggregation_shard_count,
-                maximum_attempts_before_failure=jd.maximum_attempts_before_failure,
-                max_step_attempts=jd.max_step_attempts,
-                retry_initial_delay_s=jd.retry_initial_delay_s,
-                retry_max_delay_s=jd.retry_max_delay_s,
-                vdaf_backend=d.vdaf_backend,
-                field_backend=d.field_backend,
-                device_executor=self.exec_cfg,
-                warmup_wait_s=d.warmup_wait_s,
-                http_retry=retry,
-            ),
-        )
-        self.col_driver = CollectionJobDriver(
-            leader_ds,
-            aiohttp.ClientSession,
-            CollectionDriverConfig(
-                maximum_attempts_before_failure=jd.maximum_attempts_before_failure,
-                max_step_attempts=jd.max_step_attempts,
-                batch_aggregation_shard_count=d.batch_aggregation_shard_count,
-                http_retry=retry,
-            ),
-        )
-
-        def job_driver(kind, stepper):
-            acquire = {
-                "aggregation": lambda tx, *a, **kw: tx.acquire_incomplete_aggregation_jobs(*a, **kw),
-                "collection": lambda tx, *a, **kw: tx.acquire_incomplete_collection_jobs(*a, **kw),
-            }[kind]
-
-            async def acquirer(duration, limit):
-                return await leader_ds.run_tx_async(
-                    f"acquire_{kind}",
-                    lambda tx: acquire(
-                        tx,
-                        duration,
-                        limit,
-                        exclude_task_ids=acquisition_exclusions(tx, kind),
-                    ),
-                )
-
-            return JobDriver(
-                self.clock,
-                acquirer,
-                stepper,
-                # the binaries' default is 10 s; a smoke has no idle fleet
-                # to spare, so it looks for work every second
-                job_discovery_interval=1.0,
-                max_concurrent_job_workers=jd.max_concurrent_job_workers,
-                worker_lease_duration=Duration(jd.worker_lease_duration_s),
-                worker_lease_clock_skew_allowance=Duration(
-                    jd.worker_lease_clock_skew_allowance_s
-                ),
-                job_type=kind,
-            )
-
+        self.agg_driver = compose.aggregation_driver(self.drv_cfg, leader_ds)
+        self.col_driver = compose.collection_driver(self.drv_cfg, leader_ds)
         self._stop = asyncio.Event()
         self._loops = [
             asyncio.ensure_future(
-                job_driver("aggregation", self.agg_driver.step_aggregation_job).run(
-                    self._stop
-                )
-            ),
-            asyncio.ensure_future(
-                job_driver("collection", self.col_driver.step_collection_job).run(
-                    self._stop
-                )
-            ),
+                compose.job_driver(
+                    kind, self.drv_cfg, leader_ds, self.clock, stepper
+                ).run(self._stop)
+            )
+            for kind, stepper in (
+                ("aggregation", self.agg_driver),
+                ("collection", self.col_driver),
+            )
         ]
 
     def add_task(self, name, vdaf_desc):

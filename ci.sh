@@ -125,8 +125,8 @@
 #                      device-seconds attribution (conservation proven for
 #                      multi-task / oracle-fallback / padded-tail flushes),
 #                      the executor flight recorder (ring bound, breaker-trip
-#                      + slow-flush dumps), the bench_compare / cost_report
-#                      tools, the jax-profiler-server wiring, the metric
+#                      + slow-flush dumps), the cost_report tool,
+#                      the jax-profiler-server wiring, the metric
 #                      help-text audit, and the golden metric-name/label
 #                      manifest (tests/metric_manifest.txt) that catches
 #                      silent metric renames.

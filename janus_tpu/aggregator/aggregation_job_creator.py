@@ -11,6 +11,7 @@ scrubs the client report.  Metadata-only: no VDAF compute happens here.
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import time
 from dataclasses import dataclass
@@ -111,6 +112,22 @@ class AggregationJobCreator:
             except Exception:
                 logger.exception("job creation failed for task %s", task.task_id)
         return created
+
+    async def run(self, stop: asyncio.Event, interval_s: float) -> None:
+        """Creation passes until ``stop`` is set: one at once, then one
+        ``interval_s`` after each pass has ended.  A failing pass is logged
+        and the loop goes on."""
+        while not stop.is_set():
+            try:
+                n = await self.run_once()
+                if n:
+                    logger.info("created %d aggregation jobs", n)
+            except Exception:
+                logger.exception("creation pass failed")
+            try:
+                await asyncio.wait_for(stop.wait(), timeout=interval_s)
+            except asyncio.TimeoutError:
+                pass
 
     # -- per-task creation (one transaction) ----------------------------
     def create_jobs_for_task(

@@ -400,36 +400,10 @@ def run_aggregator(config_path: Optional[str]) -> None:
 
     from aiohttp import web
 
-    from ..aggregator import Aggregator, Config, GarbageCollector, aggregator_app
+    from ..aggregator import Aggregator, GarbageCollector, aggregator_app
+    from .compose import aggregator_config
 
-    agg = Aggregator(
-        datastore,
-        clock,
-        Config(
-            max_upload_batch_size=cfg.max_upload_batch_size,
-            max_upload_batch_write_delay=cfg.max_upload_batch_write_delay_ms / 1000.0,
-            upload_open_backend=cfg.upload_open_backend,
-            upload_open_batch_size=cfg.upload_open_batch_size,
-            upload_open_batch_delay=cfg.upload_open_batch_delay_ms / 1000.0,
-            upload_queue_max=cfg.upload_queue_max,
-            upload_shed_delay_s=cfg.upload_shed_delay_s,
-            ingest_mode=cfg.ingest.mode,
-            ingest_journal_batch_size=cfg.ingest.journal_batch_size,
-            ingest_journal_write_delay=cfg.ingest.journal_write_delay_ms / 1000.0,
-            ingest_journal_queue_max=cfg.ingest.journal_queue_max,
-            ingest_stage_direct=cfg.ingest.stage_direct,
-            ingest_stage_max_reports=cfg.ingest.stage_max_reports,
-            batch_aggregation_shard_count=cfg.batch_aggregation_shard_count,
-            task_counter_shard_count=cfg.task_counter_shard_count,
-            vdaf_backend=cfg.vdaf_backend,
-            field_backend=cfg.field_backend,
-            poplar_backend=cfg.poplar_backend,
-            max_agg_param_job_size=cfg.max_agg_param_job_size,
-            device_executor=cfg.device_executor.to_executor_config()
-            if cfg.device_executor.enabled
-            else None,
-        ),
-    )
+    agg = Aggregator(datastore, clock, aggregator_config(cfg))
 
     async def main():
         loop = asyncio.get_running_loop()
@@ -649,17 +623,10 @@ def run_aggregation_job_creator(config_path: Optional[str]) -> None:
     cfg = load_config(JobCreatorConfig, config_path)
     clock, datastore = _bootstrap(cfg.common)
 
-    from ..aggregator import AggregationJobCreator, CreatorConfig
+    from ..aggregator import AggregationJobCreator
+    from .compose import creator_config
 
-    creator = AggregationJobCreator(
-        datastore,
-        CreatorConfig(
-            min_aggregation_job_size=cfg.min_aggregation_job_size,
-            max_aggregation_job_size=cfg.max_aggregation_job_size,
-            batch_aggregation_shard_count=cfg.batch_aggregation_shard_count,
-            journal_replay_min_age_s=cfg.journal_replay_min_age_s,
-        ),
-    )
+    creator = AggregationJobCreator(datastore, creator_config(cfg))
 
     async def main():
         loop = asyncio.get_running_loop()
@@ -668,19 +635,7 @@ def run_aggregation_job_creator(config_path: Optional[str]) -> None:
             cfg.common.health_check_listen_address, datastore=datastore
         )
         sampler = _start_status_sampler(stop, datastore, cfg.common)
-        while not stop.is_set():
-            try:
-                n = await creator.run_once()
-                if n:
-                    logger.info("created %d aggregation jobs", n)
-            except Exception:
-                logger.exception("creation pass failed")
-            try:
-                await asyncio.wait_for(
-                    stop.wait(), timeout=cfg.aggregation_job_creation_interval_s
-                )
-            except asyncio.TimeoutError:
-                pass
+        await creator.run(stop, cfg.aggregation_job_creation_interval_s)
         if sampler is not None:
             await asyncio.gather(sampler, return_exceptions=True)
         await health.cleanup()
@@ -730,43 +685,12 @@ def _run_job_driver_binary(config_path: Optional[str], kind: str) -> None:
             fc.heartbeat_ttl_s,
         )
 
-    import aiohttp
-
-    from ..aggregator import (
-        AggregationJobDriver,
-        CollectionJobDriver,
-        DriverConfig,
-        JobDriver,
-    )
+    from . import compose
 
     if kind == "aggregation":
-        exec_cfg = (
-            cfg.device_executor.to_executor_config()
-            if cfg.device_executor.enabled
-            else None
-        )
-        from ..core.retries import HttpRetryPolicy
-
-        stepper_impl = AggregationJobDriver(
-            datastore,
-            aiohttp.ClientSession,
-            DriverConfig(
-                batch_aggregation_shard_count=cfg.batch_aggregation_shard_count,
-                maximum_attempts_before_failure=cfg.job_driver.maximum_attempts_before_failure,
-                max_step_attempts=cfg.job_driver.max_step_attempts,
-                retry_initial_delay_s=cfg.job_driver.retry_initial_delay_s,
-                retry_max_delay_s=cfg.job_driver.retry_max_delay_s,
-                vdaf_backend=cfg.vdaf_backend,
-                field_backend=cfg.field_backend,
-                poplar_backend=cfg.poplar_backend,
-                device_executor=exec_cfg,
-                warmup_wait_s=cfg.warmup_wait_s,
-                http_retry=HttpRetryPolicy(
-                    attempt_timeout=cfg.job_driver.http_attempt_timeout_s
-                ),
-            ),
-        )
-        if exec_cfg is not None and exec_cfg.warmup_rows:
+        stepper_impl = compose.aggregation_driver(cfg, datastore)
+        dx = cfg.device_executor
+        if dx.enabled and dx.warmup_rows:
             # Registry-driven BACKGROUND warmup (ISSUE 8): walk the task
             # registry and resolve every task's backend — with canonical
             # shapes on, N tasks collapse to O(log N) distinct backends,
@@ -821,88 +745,9 @@ def _run_job_driver_binary(config_path: Optional[str], kind: str) -> None:
             threading.Thread(
                 target=_registry_warmup, name="janus-warmup-registry", daemon=True
             ).start()
-
-        async def acquirer(duration, limit):
-            from ..aggregator.job_driver import acquisition_exclusions
-
-            return await datastore.run_tx_async(
-                "acquire_agg",
-                # suspect-peer and fleet-routed tasks filter at the query
-                # (task -> peer index, same tx) instead of
-                # acquire-then-release churn
-                lambda tx: tx.acquire_incomplete_aggregation_jobs(
-                    duration,
-                    limit,
-                    exclude_task_ids=acquisition_exclusions(tx, "aggregation"),
-                ),
-            )
-
-        async def reaper():
-            return await datastore.run_tx_async(
-                "reap_agg_leases",
-                lambda tx: tx.reap_expired_aggregation_job_leases(),
-            )
-
-        stepper = stepper_impl.step_aggregation_job
-        job_type = "aggregation"
     else:
-        from ..aggregator.collection_job_driver import CollectionDriverConfig
-        from ..core.retries import HttpRetryPolicy
-
-        stepper_impl = CollectionJobDriver(
-            datastore,
-            aiohttp.ClientSession,
-            CollectionDriverConfig(
-                maximum_attempts_before_failure=cfg.job_driver.maximum_attempts_before_failure,
-                max_step_attempts=cfg.job_driver.max_step_attempts,
-                batch_aggregation_shard_count=cfg.batch_aggregation_shard_count,
-                # the shared retry knobs configure the FAILURE backoff; the
-                # readiness-poll curve keeps its own (reference) defaults
-                step_retry_initial_delay=Duration(
-                    max(1, int(cfg.job_driver.retry_initial_delay_s))
-                ),
-                step_retry_max_delay=Duration(int(cfg.job_driver.retry_max_delay_s)),
-                http_retry=HttpRetryPolicy(
-                    attempt_timeout=cfg.job_driver.http_attempt_timeout_s
-                ),
-            ),
-        )
-
-        async def acquirer(duration, limit):
-            from ..aggregator.job_driver import acquisition_exclusions
-
-            return await datastore.run_tx_async(
-                "acquire_coll",
-                lambda tx: tx.acquire_incomplete_collection_jobs(
-                    duration,
-                    limit,
-                    exclude_task_ids=acquisition_exclusions(tx, "collection"),
-                ),
-            )
-
-        async def reaper():
-            return await datastore.run_tx_async(
-                "reap_coll_leases",
-                lambda tx: tx.reap_expired_collection_job_leases(),
-            )
-
-        stepper = stepper_impl.step_collection_job
-        job_type = "collection"
-
-    driver = JobDriver(
-        clock,
-        acquirer,
-        stepper,
-        job_discovery_interval=cfg.job_driver.job_discovery_interval_s,
-        max_concurrent_job_workers=cfg.job_driver.max_concurrent_job_workers,
-        worker_lease_duration=Duration(cfg.job_driver.worker_lease_duration_s),
-        worker_lease_clock_skew_allowance=Duration(
-            cfg.job_driver.worker_lease_clock_skew_allowance_s
-        ),
-        reaper=reaper if cfg.job_driver.lease_reap_interval_s > 0 else None,
-        lease_reap_interval=cfg.job_driver.lease_reap_interval_s,
-        job_type=job_type,
-    )
+        stepper_impl = compose.collection_driver(cfg, datastore)
+    driver = compose.job_driver(kind, cfg, datastore, clock, stepper_impl)
 
     async def main():
         loop = asyncio.get_running_loop()

@@ -1,4 +1,4 @@
-"""Device-plane cost attribution + flight recorder + bench gate (ISSUE 12).
+"""Device-plane cost attribution + flight recorder (ISSUE 12).
 
 Fast by design: scheduling/attribution logic runs against fake backends
 (no jax, no compiles); the only real-VDAF piece is the pure-Python CPU
@@ -13,9 +13,8 @@ Covers the acceptance criteria directly:
 * the flight-recorder ring is O(N) bounded, records every flush shape,
   and dumps exactly once per breaker trip (+ rate-limited slow-flush
   anomalies);
-* ``tools/bench_compare.py`` gates the BENCH trajectory and treats
-  structured skips as neutral; ``tools/cost_report.py`` renders the
-  per-task rollup from a /statusz + /metrics pair.
+* ``tools/cost_report.py`` renders the per-task rollup from a /statusz +
+  /metrics pair.
 """
 
 import asyncio
@@ -561,154 +560,6 @@ def test_executor_config_threads_flight_recorder_knobs():
     assert ex.flight_recorder.size == 7
     assert ex.flight_recorder.slow_flush_p95_factor == 2.5
     ex.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# tools: bench_compare
-
-
-def _mk_run(n, rows, rc=0):
-    return {"n": n, "path": f"BENCH_r{n:02d}.json", "rc": rc, "rows": rows}
-
-
-def test_bench_compare_regression_detected():
-    from tools.bench_compare import compare
-
-    runs = [
-        _mk_run(1, {"histogram1024": {"value": 100.0, "unit": "reports/s"}}),
-        _mk_run(2, {"histogram1024": {"value": 120.0, "unit": "reports/s"}}),
-        _mk_run(3, {"histogram1024": {"value": 90.0, "unit": "reports/s"}}),
-    ]
-    v = compare(runs, tolerance=0.10)
-    assert not v["ok"]
-    (reg,) = v["regressions"]
-    assert reg["config"] == "histogram1024" and reg["best_prior"] == 120.0
-    # within the band: 110 vs best 120 passes at 10%
-    runs[-1]["rows"]["histogram1024"]["value"] = 110.0
-    assert compare(runs, tolerance=0.10)["ok"]
-
-
-def test_bench_compare_structured_skips_and_failures_are_neutral():
-    from tools.bench_compare import compare
-
-    runs = [
-        _mk_run(1, {"sum32": {"value": 50.0, "unit": "reports/s"}}),
-        _mk_run(
-            2,
-            {
-                "sum32": {"skipped": "platform unavailable"},
-                "coldtask": {"error": "runner died"},
-            },
-        ),
-    ]
-    v = compare(runs, tolerance=0.10)
-    assert v["ok"], "structured skips must be neutral, never a regression"
-    assert len(v["neutral"]) == 2
-    # the r05 mode: newest run has NO parsed payload at all
-    runs.append(_mk_run(3, None, rc=1))
-    v = compare(runs, tolerance=0.10)
-    assert v["ok"] and any("environmental" in n for n in v["neutral"])
-
-
-def test_bench_compare_gates_poplar_ab_row_on_headline_unit():
-    """The ISSUE 13 poplar1_hh row carries jax-vs-host A/B sub-fields
-    (jax_walk_reports_s, jax_resident, ...): the gate must compare ONLY
-    the headline (value, unit) pair — a regression in `value` is caught,
-    while the auxiliary fields never confuse row_value, and an error row
-    stays neutral."""
-    from tools.bench_compare import compare, row_value
-
-    ab_row = {
-        "value": 100.0,
-        "unit": "reports/s",
-        "host_walk_reports_s": 100.0,
-        "jax_walk_reports_s": 190.0,
-        "jax_vs_host_walk": 1.9,
-        "jax_resident": {"available": True, "sketch_readback_rows": 0},
-    }
-    assert row_value(ab_row) == (100.0, "reports/s")
-    assert row_value({"error": "parity broke", "jax_resident": {}}) is None
-    runs = [
-        _mk_run(1, {"poplar1_hh": dict(ab_row)}),
-        _mk_run(2, {"poplar1_hh": dict(ab_row, value=80.0)}),
-    ]
-    verdict = compare(runs, tolerance=0.10)
-    assert not verdict["ok"]
-    assert any(r["config"] == "poplar1_hh" for r in verdict["regressions"])
-    # within tolerance passes
-    runs[1] = _mk_run(2, {"poplar1_hh": dict(ab_row, value=95.0)})
-    assert compare(runs, tolerance=0.10)["ok"]
-
-
-def test_bench_compare_baseline_and_unit_mismatch():
-    from tools.bench_compare import compare
-
-    runs = [
-        _mk_run(1, {"sum32": {"value": 50.0, "unit": "reports/s"}}),
-        _mk_run(
-            2,
-            {
-                "sum32": {"value": 10.0, "unit": "ms"},  # unit changed: baseline
-                "newconfig": {"value": 1.0, "unit": "reports/s"},
-            },
-        ),
-    ]
-    v = compare(runs, tolerance=0.10)
-    assert v["ok"]
-    assert {e["config"]: e["status"] for e in v["results"]} == {
-        "sum32": "baseline",
-        "newconfig": "baseline",
-    }
-
-
-def test_bench_compare_loads_a_trajectory_from_record_files(tmp_path):
-    """load_runs reads the driver's record-file shape ({n, cmd, rc, tail,
-    parsed}) from disk: an empty parse and a failed run (rc != 0, parsed
-    null) load as row-less, multi-config payloads load per config, and
-    the runs come back ordered by ``n`` whatever order the files sort
-    in.  (The repo's own record files went with the installation they
-    were taken on — PR 21 — so the trajectory here is written to disk by
-    the test.)"""
-    import json
-
-    from tools.bench_compare import compare, load_runs
-
-    def record(n, rc, parsed):
-        doc = {"n": n, "cmd": "python bench.py", "rc": rc, "tail": "", "parsed": parsed}
-        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(doc))
-
-    def payload(value):
-        return {
-            "metric": "prepare_throughput_histogram1024",
-            "value": value,
-            "unit": "reports/s",
-            "configs": {
-                "histogram1024": {"value": value, "unit": "reports/s"},
-                "count": {"value": 10 * value, "unit": "reports/s"},
-            },
-        }
-
-    record(1, 0, None)
-    record(2, 0, {"metric": "prepare_throughput", "value": 50.0, "unit": "reports/s"})
-    record(3, 0, payload(100.0))
-    record(4, 0, payload(120.0))
-    record(5, 1, None)
-    paths = sorted(str(p) for p in tmp_path.glob("BENCH_r*.json"))
-    runs = load_runs(list(reversed(paths)))
-    assert [r["n"] for r in runs] == [1, 2, 3, 4, 5]
-    assert runs[0]["rows"] is None and runs[4]["rows"] is None and runs[4]["rc"] == 1
-    assert set(runs[1]["rows"]) == {"prepare_throughput"}
-    assert set(runs[3]["rows"]) == {"histogram1024", "count"}
-    # the newest run failed outright: neutral, never a regression
-    v = compare(runs, tolerance=0.10)
-    assert v["ok"] and v["neutral"], v
-    # and a real -20% newest run on the same files is caught
-    record(6, 0, payload(96.0))
-    v = compare(load_runs(paths + [str(tmp_path / "BENCH_r06.json")]), tolerance=0.10)
-    assert not v["ok"] and {r["config"] for r in v["regressions"]} == {
-        "histogram1024",
-        "count",
-    }
 
 
 # ---------------------------------------------------------------------------

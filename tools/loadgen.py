@@ -280,7 +280,7 @@ async def run_load(
     seal_workers: int = 2,
     now_fn=None,
 ) -> dict:
-    """The programmatic face (bench.py and the soak tests call this)."""
+    """The programmatic face (the soak tests call this)."""
     import aiohttp
 
     vdaf = vdaf_from_instance(vdaf_desc)
