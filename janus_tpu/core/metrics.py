@@ -493,6 +493,17 @@ class Metrics:
             ["outcome"],
             registry=self.registry,
         )
+        # The program store (vdaf/program_store.py): where each device
+        # program's executable came from — memory (this process already
+        # held it), disk (loaded, nothing traced), built (traced and
+        # compiled, or taken from XLA's cache), rejected (a stored file
+        # that did not load or failed warm-up's check, then built).
+        self.program_store = Counter(
+            "janus_program_store_total",
+            "Device executables asked of the program store, by program kind and outcome",
+            ["program", "outcome"],
+            registry=self.registry,
+        )
         # Per-shape circuit breaker (executor/service.py): a sick device
         # path must be visible the moment it trips, and again when the
         # half-open probe restores it.

@@ -694,17 +694,18 @@ class DeviceExecutor:
             dt = time.monotonic() - t0
             state.update(
                 state="warm", compile_s=round(dt, 3), error=None,
-                since=time.monotonic(),
+                since=time.monotonic(), source=_warm_source(backend),
             )
             outcome = "ok"
             if n:
                 logger.info(
-                    "warmed %d executable(s) for %s (%s) at %d rows in %.1fs",
+                    "warmed %d executable(s) for %s (%s) at %d rows in %.1fs (%s)",
                     n,
                     type(backend).__name__,
                     label,
                     self.config.warmup_rows,
                     dt,
+                    state["source"],
                 )
         except Exception as e:
             dt = time.monotonic() - t0
@@ -717,6 +718,7 @@ class DeviceExecutor:
         emit_span(
             "compile", "executor", t0, dt,
             shape=label, rows=self.config.warmup_rows, ok=outcome == "ok",
+            source=state.get("source"),
         )
         if GLOBAL_METRICS.registry is not None:
             GLOBAL_METRICS.executor_warmups.labels(outcome=outcome).inc()
@@ -767,7 +769,10 @@ class DeviceExecutor:
         with ``age_s``, the time the shape has sat in its current state
         (a warming age of minutes is a compile an operator should be
         watching; a warm age across a restart window proves the
-        persistent cache paid off)."""
+        persistent cache paid off).  ``source`` of a warm shape says where
+        its prepare executables came from: ``disk`` (the program store:
+        nothing traced), ``memory``, or ``built`` (traced and compiled
+        here, or taken from XLA's cache)."""
         now = time.monotonic()
         with self._lock:
             out = {}
@@ -779,6 +784,7 @@ class DeviceExecutor:
                 out[label] = {
                     "state": st["state"],
                     "compile_s": st["compile_s"],
+                    "source": st.get("source"),
                     "error": st["error"],
                     "age_s": round(now - st.get("since", now), 1),
                 }
@@ -2036,6 +2042,13 @@ class DeviceExecutor:
         replays a cached executable instead of paying XLA at peak traffic.
         Returns the number of executables compiled (0 when warmup is off
         or the backend has no device launch path).
+
+        An executable that came from the program store's DISK proves
+        itself on that launch before it serves: its outcome has to equal
+        the plain ``Prio3.prep_init`` of the same report (the VDAF object
+        itself, not the oracle backend: this is set-up, not a served row).
+        One that differs, or fails to run, is rejected and built anew — a
+        stale or damaged file costs a compile, never a wrong verdict.
         """
         pad_to = pad_to if pad_to is not None else self.config.warmup_rows
         if not pad_to or not hasattr(backend, "stage_prep_init_multi"):
@@ -2046,12 +2059,28 @@ class DeviceExecutor:
         public, shares = vdaf.shard(meas, nonce, b"\x00" * vdaf.RAND_SIZE)
         vk = b"\x00" * vdaf.VERIFY_KEY_SIZE
         compiled = 0
+        source = getattr(backend, "prep_program_source", lambda _staged: None)
         for agg_id in agg_ids:
             reports = [(nonce, public, shares[min(agg_id, len(shares) - 1)])]
             staged = backend.stage_prep_init_multi(
                 agg_id, [(vk, reports)], pad_to=pad_to
             )
-            backend.launch_prep_init_multi(staged, [(vk, reports)])
+            try:
+                got = backend.launch_prep_init_multi(staged, [(vk, reports)])[0][0]
+                proven = source(staged) != "disk" or got == vdaf.prep_init(
+                    vk, agg_id, *reports[0]
+                )
+            except Exception:
+                if source(staged) != "disk":
+                    raise
+                proven = False
+            if not proven:
+                logger.warning(
+                    "stored prepare program a%d of %s failed its check; building",
+                    agg_id, type(vdaf.flp.valid).__name__,
+                )
+                backend.reject_prep_program(staged)
+                backend.launch_prep_init_multi(staged, [(vk, reports)])
             compiled += 1
         return compiled
 
@@ -2267,6 +2296,13 @@ class DeviceExecutor:
             GLOBAL_METRICS.executor_rejections.labels(
                 bucket=bucket.label, reason=reason
             ).inc()
+
+
+def _warm_source(backend) -> str:
+    """Where a warmed backend's prepare executables came from: the one
+    source they share, else ``built`` (as for a backend with no store)."""
+    sources = getattr(backend, "prep_program_sources", set)()
+    return sources.pop() if len(sources) == 1 else "built"
 
 
 def _synthetic_measurement(vdaf):

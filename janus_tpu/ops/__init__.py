@@ -14,3 +14,25 @@ Montgomery form between boundary conversions.  All shapes are static per VDAF
 configuration; batching over reports is jax.vmap-style broadcasting over the
 leading axis.
 """
+
+import os
+
+
+def pallas_mode() -> str:
+    """'on' | 'off' | 'interpret' — resolved at trace time.
+
+    auto: real kernels when the default backend is TPU, else off (the CPU
+    test mesh and the oracle paths use the XLA graph version).  Here, not
+    beside the kernels, so that asking it (the program store's key does)
+    imports no Pallas.
+    """
+    import jax
+
+    mode = os.environ.get("JANUS_TPU_PALLAS", "auto")
+    if mode in ("0", "off"):
+        return "off"
+    if mode == "interpret":
+        return "interpret"
+    if mode in ("1", "on"):
+        return "on"
+    return "on" if jax.default_backend() == "tpu" else "off"

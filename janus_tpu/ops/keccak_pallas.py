@@ -21,7 +21,6 @@ loops to rayon; SURVEY.md §2.3 P1).  Bit-exact vs janus_tpu.xof.turboshake128
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import List, Tuple
 
@@ -32,27 +31,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..xof import ROUND_CONSTANTS, _RHO
+from . import pallas_mode as _pallas_mode
 
 RATE = 168
 RATE_WORDS = 42
 _ROUNDS = 12
 _RC = [(rc & 0xFFFFFFFF, rc >> 32) for rc in ROUND_CONSTANTS[24 - _ROUNDS :]]
-
-
-def _pallas_mode() -> str:
-    """'on' | 'off' | 'interpret' — resolved at trace time.
-
-    auto: real kernels when the default backend is TPU, else off (the CPU
-    test mesh and the oracle paths use the XLA graph version).
-    """
-    mode = os.environ.get("JANUS_TPU_PALLAS", "auto")
-    if mode in ("0", "off"):
-        return "off"
-    if mode == "interpret":
-        return "interpret"
-    if mode in ("1", "on"):
-        return "on"
-    return "on" if jax.default_backend() == "tpu" else "off"
 
 
 def pallas_enabled(batch: int) -> bool:

@@ -14,6 +14,17 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__f
 #: that was handed the same directory, hits what the last one compiled.
 DEFAULT_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
+#: what the last :func:`enable_compile_cache` returned (None before any)
+_cache_dir_in_use: Optional[str] = None
+
+
+def compile_cache_dir() -> Optional[str]:
+    """Where this process persists executables, as :func:`enable_compile_cache`
+    decided it; None where it decided on none, or nobody asked it.  The
+    program store (vdaf/program_store.py) lives in a subdirectory of it, so
+    it is found and cleared with the compile cache."""
+    return _cache_dir_in_use
+
 
 def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
     """Turn on XLA's persistent compilation cache; returns the directory
@@ -46,6 +57,8 @@ def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
     """
     import jax
 
+    global _cache_dir_in_use
+    _cache_dir_in_use = None
     if jax.default_backend() == "cpu":
         return None
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
@@ -56,4 +69,5 @@ def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
     # Persist only JAX's executable cache: XLA's own per-kernel AOT caches
     # embed host machine code and have hung when loaded on another host.
     jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-    return env_dir or jax.config.jax_compilation_cache_dir
+    _cache_dir_in_use = env_dir or jax.config.jax_compilation_cache_dir
+    return _cache_dir_in_use

@@ -297,24 +297,64 @@ class TpuBackend:
         """Compile a per-device program; the mesh backend shard_maps it."""
         return self._jax.jit(per_device)
 
+    #: Whether this backend's programs go through the program store
+    #: (vdaf/program_store.py).  The store takes single-device executables
+    #: only: the mesh backend's shard_maps stay on plain jit.
+    _stores_programs = True
+
+    def _program(self, kind: str, agg_id: Optional[int], jitted):
+        """One of the four program kinds (``prep_init``, ``combine``,
+        ``aggregate``, ``accumulate``), called as ``jitted`` is: its executables come from the program store where
+        the process has one (a compile cache directory, so never on
+        XLA:CPU), else ``jitted`` itself, which traces on first call."""
+        from . import program_store
+
+        store = program_store.active_store() if self._stores_programs else None
+        if store is None:
+            return jitted
+        return program_store.StoredProgram(store, kind, agg_id, self, jitted)
+
     def _prep_fn(self, agg_id: int):
         fn = self._prep_fns.get(agg_id)
         if fn is None:
-            fn = self._jit_per_device(partial(self._prep, agg_id))
+            fn = self._program(
+                "prep_init", agg_id, self._jit_per_device(partial(self._prep, agg_id))
+            )
             self._prep_fns[agg_id] = fn
         return fn
+
+    def prep_program_source(self, staged: StagedPrepInit) -> Optional[str]:
+        """Where the prepare executable that ran ``staged`` came from:
+        "disk" | "memory" | "built"; None for a plain jit."""
+        fn = self._prep_fn(staged.agg_id)
+        return fn.source(staged.placed) if hasattr(fn, "source") else None
+
+    def prep_program_sources(self) -> set:
+        """The sources of every prepare executable this backend has run
+        (empty on plain jit)."""
+        return {
+            source
+            for fn in self._prep_fns.values()
+            for source in getattr(fn, "sources", dict)().values()
+        }
+
+    def reject_prep_program(self, staged: StagedPrepInit) -> None:
+        """The stored prepare executable for ``staged``'s shape gave a
+        wrong answer: out of the store, and the next launch builds."""
+        self._prep_fn(staged.agg_id).reject(staged.placed)
 
     def _combine(self):
         if self._combine_fn is None:
             has_jr = self.vdaf.flp.JOINT_RAND_LEN > 0
             if has_jr:
-                self._combine_fn = self._jax.jit(
+                jitted = self._jax.jit(
                     lambda vs, parts: self.bp.prep_shares_to_prep(vs, parts)
                 )
             else:
-                self._combine_fn = self._jax.jit(
+                jitted = self._jax.jit(
                     lambda vs, parts: self.bp.prep_shares_to_prep(vs)
                 )
+            self._combine_fn = self._program("combine", None, jitted)
         return self._combine_fn
 
     # -- marshaling ------------------------------------------------------
@@ -703,7 +743,7 @@ class TpuBackend:
                 delta = jf.sum(masked, axis=0)
                 return jf.add(buf, delta)
 
-            self._accum_fn = self._jax.jit(accum)
+            self._accum_fn = self._program("accumulate", None, self._jax.jit(accum))
         if buffer is None:
             jf = self.bp.jf
             buffer = np.zeros((self.vdaf.flp.OUTPUT_LEN, jf.n), dtype=np.uint32)
@@ -727,7 +767,9 @@ class TpuBackend:
         (reference: aggregator/src/aggregator/aggregation_job_writer.rs:591-698).
         """
         if self._agg_fn is None:
-            self._agg_fn = self._jax.jit(self.bp.aggregate)
+            self._agg_fn = self._program(
+                "aggregate", None, self._jax.jit(self.bp.aggregate)
+            )
         shares = np.asarray(out_shares_limbs)
         m = np.asarray(mask)
         B = shares.shape[0]
@@ -762,6 +804,7 @@ class MeshBackend(TpuBackend):
     """
 
     name = "mesh"
+    _stores_programs = False
 
     def __init__(
         self,

@@ -246,7 +246,97 @@ def test_real_backend_background_warmup_compiles_executables():
     assert set(backend._prep_fns) == {0, 1}  # both agg sides precompiled
     st = ex.compile_stats()
     assert next(iter(st.values()))["state"] == "warm"
+    # no compile cache directory on XLA:CPU, so no program store: plain jit
+    assert next(iter(st.values()))["source"] == "built"
     ex.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the program store behind the warm-up (ISSUE 32; tests/test_program_store.py
+# has the store itself)
+
+
+def _with_store(monkeypatch, directory):
+    from janus_tpu.vdaf import program_store
+
+    store = program_store.ProgramStore(str(directory))
+    monkeypatch.setattr(program_store, "active_store", lambda: store)
+    return store
+
+
+@pytest.mark.parametrize("restarts, source", [(0, "built"), (1, "disk")])
+def test_compile_stats_carry_the_programs_source(monkeypatch, tmp_path, restarts, source):
+    """A process's first warm-up of a shape builds its prepare programs; a
+    restarted one (a new store object on the same directory) loads them,
+    and the ledger, the log line and the ``compile`` span say which."""
+    from janus_tpu.vdaf.backend import TpuBackend
+    from janus_tpu.vdaf.instances import prio3_count
+
+    spans = []
+    monkeypatch.setattr(
+        "janus_tpu.executor.service.emit_span",
+        lambda name, cat, t0, dt, **kw: spans.append((name, kw)),
+    )
+    for _ in range(restarts + 1):
+        _with_store(monkeypatch, tmp_path)
+        ex = DeviceExecutor(ExecutorConfig(warmup_rows=4, warmup_async=False))
+        ex.backend_for(("count",), lambda: TpuBackend(prio3_count()))
+        (entry,) = ex.compile_stats().values()
+        ex.shutdown()
+    assert entry["state"] == "warm" and entry["source"] == source
+    assert spans[-1][0] == "compile" and spans[-1][1]["source"] == source
+
+
+def test_cold_and_failed_shapes_have_no_source(monkeypatch):
+    ex = DeviceExecutor(ExecutorConfig(warmup_rows=0))
+    ex.backend_for(("shape",), _FakeBackend)
+    (entry,) = ex.compile_stats().values()
+    assert entry["state"] == "cold" and entry["source"] is None
+    ex.shutdown()
+
+
+def test_second_backend_of_a_loaded_shape_gets_aggregate_from_memory(monkeypatch, tmp_path):
+    """The helper's per-task backend is rebuilt when ``task_cache_ttl``
+    expires (ROADMAP S8): the memory front is keyed by shape, not by
+    backend object, so the rebuilt one does not trace ``aggregate`` again."""
+    import numpy as np
+
+    from janus_tpu.core.metrics import GLOBAL_METRICS
+    from janus_tpu.vdaf.backend import TpuBackend
+    from janus_tpu.vdaf.instances import prio3_count
+
+    store = _with_store(monkeypatch, tmp_path)
+    builds = []
+    inner = store.get
+
+    def spying_get(kind, key, build):
+        def spied():
+            builds.append(kind)
+            return build()
+
+        return inner(kind, key, spied)
+
+    monkeypatch.setattr(store, "get", spying_get)
+
+    def memory_hits():
+        return GLOBAL_METRICS.get_sample_value(
+            "janus_program_store_total", {"program": "aggregate", "outcome": "memory"}
+        ) or 0.0
+
+    shares = np.ones((4, 1, 2), dtype=np.uint32)
+    mask = np.ones(4, dtype=bool)
+    first, second = TpuBackend(prio3_count()), TpuBackend(prio3_count())
+    want = first.aggregate_batch(shares, mask)
+    assert builds == ["aggregate"]
+    before = memory_hits()
+    assert second.aggregate_batch(shares, mask) == want
+    assert builds == ["aggregate"]  # no second trace
+    assert memory_hits() == before + 1
+    assert set(second._agg_fn.sources().values()) == {"memory"}
+    # another job size is another program: built once, then shared too
+    second.aggregate_batch(np.ones((8, 1, 2), dtype=np.uint32), np.ones(8, dtype=bool))
+    first.aggregate_batch(np.ones((8, 1, 2), dtype=np.uint32), np.ones(8, dtype=bool))
+    assert builds == ["aggregate", "aggregate"]
 
 
 # ---------------------------------------------------------------------------
